@@ -110,8 +110,7 @@ def _spawn(arm, path, args):
            "--rows", str(args.rows), "--levels", str(args.levels),
            "--regions", str(args.regions),
            "--max-resident-regions", str(args.max_resident_regions)]
-    proc = subprocess.run(cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"},
-                          capture_output=True, text=True)
+    proc = subprocess.run(cmd, capture_output=True, text=True)
     assert proc.returncode == 0, \
         f"{arm} arm failed:\n{proc.stdout}\n{proc.stderr}"
     return json.loads(proc.stdout.strip().splitlines()[-1])

@@ -21,7 +21,9 @@ Two search modes (per the bench methodology):
   and the largest ``block_v`` whose two-phase tile fits the budget.
 * **measured** (a real TPU backend): the same candidate grid is timed on a
   synthetic region of the key's dimensions and the fastest wall-clock
-  candidate wins.  The winner is persisted like the analytic one.
+  candidate wins.  The winner is persisted like the analytic one.  The
+  region-resident fused Pallas kernel only runs interpreted, so on a TPU
+  a pallas key never takes the fused configuration.
 
 ``Solver.prepare``/``solve_many`` consume this through
 :func:`tuned_sweep_config` when ``SolverOptions.autotune`` is on; a
@@ -106,10 +108,16 @@ def _blocked_tile_bytes(bv: int, E: int, dtypes: _dt.KernelDtypes) -> int:
             + lb * (bv * E + 2 * bv))                # cross_lab, lab in/out
 
 
+def _on_tpu() -> bool:
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 def _analytic(V: int, E: int, backend: str, dtypes: _dt.KernelDtypes,
               budget: int) -> TunedConfig:
     bytes_fused = _pr.fused_region_vmem_bytes(V, E, dtypes)
-    fused = bytes_fused <= budget
+    fused = bytes_fused <= budget and not (backend == "pallas"
+                                           and _on_tpu())
     block_v = BLOCK_V_CANDIDATES[0]
     for bv in BLOCK_V_CANDIDATES:
         if bv <= max(V, BLOCK_V_CANDIDATES[0]) \
@@ -190,7 +198,7 @@ def tune(V: int, E: int, *, backend: str = "xla",
         except TypeError:
             pass                               # stale schema: re-tune
     if measure is None:
-        measure = _pr.dma_overlap_supported()
+        measure = _on_tpu()
     tc = (_measured if measure else _analytic)(V, E, backend, kd, budget)
     store[key] = tc.as_dict()
     _store_cache(path, store)

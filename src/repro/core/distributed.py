@@ -31,34 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.5: public top-level API
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: replication checking off on every JAX.
-
-    The checker kwarg was renamed (check_rep -> check_vma) across JAX
-    releases; we need it off because the sweep body mixes replicated
-    (cross-arc tables) and sharded (region) operands.
-    """
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
-def _axis_size(a):
-    """jax.lax.axis_size with a pre-0.5 fallback (psum of ones)."""
-    try:
-        return jax.lax.axis_size(a)
-    except AttributeError:
-        return jax.lax.psum(1, a)
-
 from repro.core import executor as _executor
 from repro.core import heuristics
 from repro.core import resilience as _res
@@ -69,6 +41,14 @@ from repro.core.prd import prd_discharge_batched
 from repro.core.sweep import SweepConfig
 
 _I32 = jnp.int32
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication checker off: the sweep body
+    mixes replicated (cross-arc tables) and sharded (region) operands."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 # bumped once per trace of the sharded one-sweep body — part of the session
 # front-end's combined compile-cache observable (Solver.cache_info)
@@ -120,7 +100,7 @@ def _one_sweep_local(meta: GraphMeta, cfg: SweepConfig, axes,
     # region offset of this shard (flat index over possibly-multiple axes)
     idx = jnp.zeros((), _I32)
     for a in axes:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     offset = idx * Kl
 
     src, dst = state.cross_src, state.cross_dst
@@ -299,6 +279,19 @@ def make_sharded_solve(meta: GraphMeta, mesh: Mesh, cfg: SweepConfig,
 
     fn = shard_map(chunk, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     return jax.jit(fn)
+
+
+def grid_like_meta(num_regions: int, region_size: int,
+                   degree: int) -> GraphMeta:
+    """A ``GraphMeta`` shaped like a square grid cut into square regions:
+    about ``4 * sqrt(V)`` cross arcs per region.  For AOT lowering (the
+    dry run, the TPU compile tests), where no instance is built."""
+    K, V = num_regions, region_size
+    X = int(4 * (V ** 0.5)) * K
+    return GraphMeta(num_regions=K, region_size=V, max_degree=degree,
+                     num_vertices=K * V, num_boundary=X // 2,
+                     num_cross_arcs=X, num_ghost_groups=X,
+                     d_inf_ard=X // 2, d_inf_prd=K * V)
 
 
 def maxflow_input_specs(meta: GraphMeta) -> FlowState:

@@ -30,9 +30,10 @@ function from the current state to ``(delta [V, 1+E], new_lab [V])``, and is
 selectable:
 
   "xla"    — dense-row jnp ops (``_phase_xla``), the original engine code;
-  "pallas" — the fused VMEM-tiled kernel ``repro.kernels.push_relabel``
-             (interpret mode off-TPU), sharing the exact int32 math of the
-             XLA phase, so the two backends are bit-identical.
+  "pallas" — the blocked VMEM-tiled kernel ``repro.kernels.push_relabel``
+             (compiled by Mosaic on a TPU, interpreted elsewhere), sharing
+             the exact integer math of the XLA phase, so the two backends
+             are bit-identical.
 
 Each iteration calls the phase twice: once on the pre-push state (the delta
 output drives the push) and once on the post-push state (the new_lab output
@@ -49,8 +50,10 @@ single ``fused_engine_run`` kernel launch with the whole region state in
 VMEM (push split, intra-region scatter and post-push relabel all in-kernel,
 early exit when no vertex is active); when the region exceeds the VMEM
 budget (``kernels.push_relabel.fused_region_fits_vmem``) the engine falls
-back to the blocked two-phase path.  For ``backend="xla"`` one trip is the
-symmetric single traced body (the shared
+back to the blocked two-phase path.  Mosaic refuses that kernel's body, so
+it runs only interpreted: on a TPU the fused pallas route raises
+``UnsupportedFeatureError`` when it is traced.  For ``backend="xla"`` one
+trip is the symmetric single traced body (the shared
 ``kernels.push_relabel.make_fused_iteration`` inside an inner bounded
 loop) — one compute+apply+relabel program per iteration instead of two
 phase calls.  All four paths (fused/unfused × xla/pallas) are bit-exact;
@@ -168,8 +171,7 @@ def make_phase(backend: str, *, nbr_local, intra, emask, vmask,
                 lab, cf, sink_cf, excess, nbr_local=nbr_local, intra=intra,
                 emask=emask, vmask=vmask, cross_pushable=cross_pushable,
                 cross_lab=cross_lab, d_inf=d_inf, sink_open=sink_open,
-                block_v=block_v, interpret=interpret, mode=mode,
-                mask_dtype=_mask_dtype(cf, lab))
+                block_v=block_v, interpret=interpret, mode=mode)
         return phase
 
     pushable = (cross_pushable | intra) & emask

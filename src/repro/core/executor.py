@@ -144,6 +144,8 @@ FEATURE_DOC = {
     "warm_start": "warm-started solves",
     "device_resident": "the device-resident multi-sweep driver",
     "host_loop": "the host-loop driver",
+    "fused_pallas": "the region-resident fused Pallas kernel "
+                    "(engine_backend='pallas' with engine_chunk_iters)",
 }
 
 _HINTS = {

@@ -898,6 +898,20 @@ def pack_built(builds, *, pad_batch: bool = True) -> list[PackedBatch]:
     return out
 
 
+def gather_at_nbr(values: jax.Array, nbr_region: jax.Array,
+                  nbr_local: jax.Array) -> jax.Array:
+    """``values[nbr_region, nbr_local]``: a per-vertex ``[K, V]`` array read
+    at every arc's destination, shaped ``[K, V, E]``.
+
+    The output takes the index arrays' sharding.  On region-sharded state
+    (the explicit-axis mesh ``jax.make_mesh`` builds) the gather then
+    all-gathers the small ``[K, V]`` operand; on unsharded state it is the
+    plain gather.
+    """
+    return values.at[nbr_region, nbr_local].get(
+        out_sharding=jax.typeof(nbr_region).sharding)
+
+
 def intra_mask(state: FlowState) -> jax.Array:
     """bool[K,V,E] — arc stays within its own region."""
     K = state.nbr_region.shape[0]

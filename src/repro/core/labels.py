@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import dtypes as _dt
-from repro.core.graph import FlowState, GraphMeta, INF_LABEL, intra_mask
+from repro.core.graph import (FlowState, GraphMeta, INF_LABEL, gather_at_nbr,
+                              intra_mask)
 
 _I32 = jnp.int32
 
@@ -46,7 +47,7 @@ def gather_ghost_labels(state: FlowState) -> jax.Array:
     In the distributed runtime this is the per-sweep boundary label exchange;
     under pjit it lowers to an all-gather of the (small) label array.
     """
-    return state.d[state.nbr_region, state.nbr_local]
+    return gather_at_nbr(state.d, state.nbr_region, state.nbr_local)
 
 
 def _region_relabel_one(cf, sink_cf, ghost_d, *, nbr_local, intra, emask,

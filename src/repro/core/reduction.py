@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import bfs_to_targets, push_relabel
-from repro.core.graph import FlowState, GraphMeta, intra_mask
+from repro.core.graph import FlowState, GraphMeta, gather_at_nbr, intra_mask
 
 _I32 = jnp.int32
 
@@ -80,7 +80,7 @@ def _reach_backward(state: FlowState, target: jax.Array, intra) -> jax.Array:
     """Vertices from which ``target`` is reachable through intra residuals."""
     def body(carry):
         reach, _ = carry
-        nbr_reach = reach[state.nbr_region, state.nbr_local]
+        nbr_reach = gather_at_nbr(reach, state.nbr_region, state.nbr_local)
         ok = (state.cf > 0) & state.emask & intra & nbr_reach
         new = (reach | ok.any(axis=2)) & state.vmask
         return new, (new != reach).any()

@@ -49,6 +49,7 @@ import hashlib
 from dataclasses import dataclass
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -472,11 +473,13 @@ class ProblemHandle:
         continue an interrupted solve bit-exactly; the checkpoint's flow
         offset is adopted (authoritative for a cross-process resume).
 
-        Kernel lowering/VMEM failures degrade the engine configuration
-        one ladder rung at a time (pallas-fused -> xla-fused ->
-        xla-unfused, ``resilience.degrade_config``) and re-run — every
-        rung is bit-exact, and each degradation is recorded in
-        ``stats.degraded``, never silent.
+        Accelerator resource exhaustion (``resilience.is_kernel_failure``)
+        degrades the engine configuration one ladder rung at a time
+        (pallas-fused -> xla-fused -> xla-unfused,
+        ``resilience.degrade_config``) and re-runs — every rung is
+        bit-exact, and each degradation is recorded in ``stats.degraded``,
+        never silent.  Any other failure, a kernel the compiler refuses
+        among them, raises.
 
         ``on_sweep(state, sweeps_done)`` — optional sweep-boundary hook
         (fires at every boundary on the host loop, at the
@@ -538,6 +541,10 @@ class ProblemHandle:
                     exchange=opts.exchange, return_stats=True,
                     checkpoint=checkpoint, resume_from=ckpt_obj, salt=salt,
                     on_sweep=on_sweep)
+                # back beside the handle's other arrays (state0, updates,
+                # cut extraction); the next sharded solve re-shards it
+                st = jax.device_put(
+                    st, jax.tree.map(lambda a: a.sharding, st_sh))
                 st = _narrow_state(st, self.meta)
                 _pb, msg_bytes = _sweep._page_and_msg_bytes(self.meta)
                 stats = _sweep.SweepStats(

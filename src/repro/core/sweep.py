@@ -51,7 +51,7 @@ from repro.core import heuristics
 from repro.core import resilience as _res
 from repro.core.ard import ard_discharge_batched, ard_discharge_one
 from repro.core.engine import ENGINE_BACKENDS
-from repro.core.graph import FlowState, GraphMeta, intra_mask
+from repro.core.graph import FlowState, GraphMeta, gather_at_nbr, intra_mask
 from repro.core.labels import (gather_ghost_labels, global_gap,
                                region_relabel)
 from repro.core.prd import prd_discharge_batched, prd_discharge_one
@@ -677,7 +677,8 @@ def extract_cut(meta: GraphMeta, state: FlowState) -> jax.Array:
     def run(state: FlowState):
         def body(carry):
             reach, _ = carry
-            nbr_reach = reach[state.nbr_region, state.nbr_local]
+            nbr_reach = gather_at_nbr(reach, state.nbr_region,
+                                      state.nbr_local)
             ok = (state.cf > 0) & state.emask & nbr_reach
             new = (state.sink_cf > 0) | ok.any(axis=2)
             new = (new | reach) & state.vmask
@@ -696,12 +697,16 @@ def cut_value(meta: GraphMeta, state0: FlowState, sink_side: jax.Array) -> jax.A
 
     cost = sum_{v in C̄} e(v) + sum_{v in C} sink_cap(v)
          + sum of cap(u,v) over arcs u in C, v in C̄.
+
+    ``sink_side`` moves to ``state0``'s devices first: a cut extracted
+    from region-sharded state is priced where the initial network lives.
     """
+    sink_side = jax.device_put(sink_side, state0.vmask.sharding)
     src_side = ~sink_side & state0.vmask
     e_term = jnp.sum(jnp.where(sink_side & state0.vmask, state0.excess, 0),
                      dtype=_I32)
     t_term = jnp.sum(jnp.where(src_side, state0.sink_cf, 0), dtype=_I32)
-    nbr_sink = sink_side[state0.nbr_region, state0.nbr_local]
+    nbr_sink = gather_at_nbr(sink_side, state0.nbr_region, state0.nbr_local)
     arc_cut = (src_side[:, :, None] & nbr_sink & state0.emask)
     c_term = jnp.sum(jnp.where(arc_cut, state0.cf, 0), dtype=_I32)
     return e_term + t_term + c_term

@@ -1,65 +1,44 @@
-"""Pallas TPU kernel: fused push/relabel compute phase on an ELL block.
+"""Pallas TPU kernels: the push/relabel compute phase on an ELL block.
 
 The hot spot of every region discharge is the per-vertex row scan over the
-padded adjacency: gather neighbour labels, test admissibility, split the
-vertex's excess over admissible arcs (exclusive cumsum), and compute the
-relabel minimum.  On TPU this is one VMEM-resident pass per vertex block:
+padded adjacency: test admissibility against the neighbour labels, split
+the vertex's excess over admissible arcs (exclusive prefix sum), and
+compute the relabel minimum.
 
-  * grid tiles the vertex dimension (rows); each program instance loads a
-    (BV, E) tile of cf/nbr/masks plus the full label vector (labels are
-    4B * V — a 64k-vertex region's labels are 256 KiB, VMEM-resident);
-  * the label gather, admissibility mask, cumsum split and relabel min all
-    happen in registers/VMEM — the only HBM traffic is the tile streams,
-    which is what makes the discharge memory-bound rather than gather-bound;
-  * scatter application of the deltas (reverse arcs, receiver excess) stays
-    outside the kernel in XLA — scatters are global (cross-tile) and XLA's
-    sort-based scatter on TPU handles them well.
+Blocked two-phase kernel (``push_relabel_phase``)
+-------------------------------------------------
+The grid tiles the vertex dimension: each program instance loads a
+``(BV, E)`` tile of residuals and of neighbour labels plus ``(BV, 1)``
+columns of the per-vertex values (BV = 256 rows by default).  The
+neighbour-label gather ``lab[nbr]`` and the scatter application of the
+deltas (reverse arcs, receiver excess) stay in XLA: Mosaic lowers neither
+a 1-D gather nor a scatter.  The excess split is an exclusive prefix
+unrolled over the E static lanes (Mosaic has no cumsum), and narrow int16
+storage computes in int32 inside the kernel (Mosaic reduces no int16
+vectors).  This is the kernel of the ``engine_backend="pallas"`` route; it
+compiles for TPU v5e (tests/test_tpu_compile.py) and is bit-exact against
+``kernels/ref.py`` and the XLA phase (tests/test_kernels.py).
 
-Block shapes: BV = 256 rows/tile by default (rows * (3 arcs arrays + 2
-outputs) * E * 4B ≈ 2.6 MiB at E = 256 — fits VMEM with double buffering);
-E is padded to the lane width (128) by the wrapper.
+``engine_phase`` is the engine-facing adapter that accepts core/engine.py's
+mask semantics (``cross_pushable``/``emask``/``vmask``/``sink_open``); the
+``backend="pallas"`` path of ``repro.core.engine.push_relabel`` calls it
+twice per iteration (pre-push for the deltas, post-push for the relabels).
 
-Validated against kernels/ref.py in interpret mode over a shape/dtype sweep
-(tests/test_kernels.py); on this CPU-only container the kernel always runs
-with interpret=True.
-
-``push_relabel_phase`` is the raw tiled kernel; ``engine_phase`` is the
-engine-facing adapter that accepts core/engine.py's mask semantics
-(``cross_pushable``/``emask``/``vmask``/``sink_open``) and is what the
-``backend="pallas"`` path of ``repro.core.engine.push_relabel`` calls twice
-per iteration (pre-push for the deltas, post-push for the relabels).
-
-Region-resident fused mode
---------------------------
-``fused_engine_run`` is the single-launch alternative: one ``pallas_call``
-whose block is the *whole region* (``block_v = V`` — regions are sized to
-fit memory, paper Sec. 5.3) advances up to ``iter_limit`` complete engine
-iterations with all state resident in VMEM.  Each in-kernel iteration does
-the push split, the intra-region scatter (reverse arcs via ``rev_slot``,
-receiver excess via ``nbr_local``) and the post-push relabel, accumulating
-``out_push``/``sink_pushed``/``relabel_sum`` in-kernel, with an early exit
-as soon as no vertex is active.  HBM traffic drops from four round trips of
-the ``[V, E]`` state per iteration (two phase launches + two scatters) to
-one per *k* iterations — the paper's "intra-region work is cheap because it
-stays local" premise, honored on the accelerator.  ``core.engine`` falls
-back to the blocked two-phase path when the region exceeds the VMEM budget
-(``fused_region_fits_vmem``).
-
-``fused_engine_run_batched`` is the grid-over-regions form: the same
-in-kernel loop as a ``grid=(K,)`` program, one launch discharging *all*
-regions of a parallel sweep — each program instance owns one region's
-``[V, E]`` tile, takes its own iteration budget from a per-region limit
-vector, and early-exits independently, so idle regions cost O(1) inside
-the shared launch.  ``core.engine.push_relabel_batched`` drives it.
-
-With ``[B, K, V, E]`` inputs the same entry point lowers to a
-``grid=(B, K)`` program — one launch advancing *every region of every
-instance of a solve batch*.  ``d_inf`` and ``iter_limit`` broadcast
-against the ``(B, K)`` lead, so each instance keeps its own label ceiling
-(mixed problem sizes share one bucket-shaped executable) and the driver's
-per-instance convergence flags arrive as zeroed iteration budgets; a
-converged instance's regions all take the O(1) early exit, exactly like
-idle regions of a single solve.  ``core.batch`` drives this form.
+Region-resident fused kernel (interpret mode only)
+--------------------------------------------------
+``fused_engine_run_batched`` is one ``grid=(K,)`` (or ``grid=(B, K)`` for a
+solve batch) ``pallas_call`` whose block is a *whole region*: each program
+instance advances its region up to ``iter_limit`` complete engine
+iterations — push split, intra-region scatter, post-push relabel — with
+the state resident and a per-region early exit.  ``fused_engine_run`` is
+its single-region form.  Its body gathers ``lab[nbr]``, takes a cumsum and
+scatter-adds inside the kernel, which Mosaic refuses
+(``FUSED_MOSAIC_REFUSAL``).  It therefore runs only under the Pallas
+interpreter, where it is bit-exact against the other engine routes; a
+compiled (``interpret=False``) request raises ``UnsupportedFeatureError``
+before anything is traced, and never steps down the degradation ladder.
+``core.engine`` also falls back to the blocked path when a region exceeds
+the VMEM budget (``fused_region_fits_vmem``).
 """
 
 from __future__ import annotations
@@ -85,61 +64,61 @@ def _inf_for(dtype) -> int:
     return _dt.inf_label_for(dtype)
 
 
-def _pr_kernel(lab_ref, cf_ref, sink_cf_ref, excess_ref, nbr_ref, intra_ref,
-               pushable_ref, cross_lab_ref, d_inf_ref,
-               delta_ref, new_lab_ref, *, mode: str):
-    """One vertex-block: push deltas (sink col 0) and/or relabel candidates.
+def _pr_kernel(lab_ref, cf_ref, sink_cf_ref, excess_ref, nlab_ref, d_inf_ref,
+               *out_refs, mode: str):
+    """One vertex-block: push deltas and/or relabel candidates.
 
-    ``mode`` ("both" | "push" | "relabel") statically drops the unneeded
-    output's compute — pallas_call is opaque to XLA DCE, and the engine
-    consumes only one output per call (deltas pre-push, relabels post-push).
-    The admissibility mask is shared; only the cumsum excess split resp. the
-    relabel min-reduction is skipped.  A skipped output ref is still written
-    (zero deltas / unchanged labels) so it stays well-defined.
+    Per-vertex values arrive as ``[BV, 1]`` columns beside the ``[BV, E]``
+    arc tiles, and ``nlab`` holds every arc's destination label, already
+    gathered and gated by the caller (Mosaic lowers no 1-D gather).
+    ``mode`` ("both" | "push" | "relabel") statically selects the outputs:
+    ``(d_sink [BV, 1], d_arc [BV, E])`` for a push, ``new_lab [BV, 1]``
+    for a relabel — pallas_call is opaque to XLA DCE, so the kernel
+    computes and writes only what the engine consumes.
     """
-    lab_full = lab_ref[...]                      # [V] whole-region labels
-    cf = cf_ref[...]                             # [BV, E]
-    nbr = nbr_ref[...]
-    intra = intra_ref[...] != 0
-    pushable = pushable_ref[...] != 0
-    cross_lab = cross_lab_ref[...]
-    excess = excess_ref[...]
-    sink_cf = sink_cf_ref[...]
-    inf = _inf_for(lab_full.dtype)
-    d_inf = d_inf_ref[0].astype(lab_full.dtype)  # ceiling fits the dtype
+    # narrow (int16) storage computes in int32: Mosaic reduces no int16
+    # vectors, and the build-time range check keeps every value and
+    # partial sum inside the narrow range, so the widened math is exact
+    wide = lambda ref: ref[...].astype(jnp.int32)
+    lab = wide(lab_ref)                          # [BV, 1]
+    cf = wide(cf_ref)                            # [BV, E]
+    sink_cf = wide(sink_cf_ref)                  # [BV, 1]
+    excess = wide(excess_ref)                    # [BV, 1]
+    nlab = wide(nlab_ref)                        # [BV, E]
+    d_inf = d_inf_ref[...]                       # [1, 1]
+    inf = _inf_for(lab_ref.dtype)
 
-    lab_rows = lab_full[nbr]                     # gather [BV, E]
-    nlab = jnp.where(intra, lab_rows, cross_lab)
-    nlab = jnp.where(pushable, nlab, inf)
-
-    bv = cf.shape[0]
-    row0 = pl.program_id(0) * bv
-    my_lab = jax.lax.dynamic_slice(lab_full, (row0,), (bv,))
-    act = (excess > 0) & (my_lab < d_inf)
-
-    adm = (cf > 0) & (my_lab[:, None] == nlab + 1) & act[:, None]
-    sink_adm = (sink_cf > 0) & (my_lab == 1) & act
+    act = (excess > 0) & (lab < d_inf)
+    adm = (cf > 0) & (lab == nlab + 1) & act
+    sink_adm = (sink_cf > 0) & (lab == 1) & act
 
     if mode in ("both", "push"):
+        d_sink_ref, d_arc_ref = out_refs[:2]
         sink_cap = jnp.where(sink_adm, sink_cf, 0)
         arc_cap = jnp.where(adm, cf, 0)
-        caps = jnp.concatenate([sink_cap[:, None], arc_cap], axis=1)
         avail = jnp.where(act, excess, 0)
-        # cumsum/sum must not promote (jnp defaults widen sub-int32 ints);
-        # the narrow range check bounds every partial sum
-        cum_excl = jnp.cumsum(caps, axis=1, dtype=caps.dtype) - caps
-        delta_ref[...] = jnp.clip(avail[:, None] - cum_excl, 0, caps)
-    else:
-        delta_ref[...] = jnp.zeros(delta_ref.shape, delta_ref.dtype)
+        # exclusive prefix over (sink, arc 0 .. arc E-1), unrolled over the
+        # E static lanes: Mosaic has no cumsum.  Integer sums wrap the same
+        # in any order, so this equals the XLA phase's cumsum bit for bit.
+        col = jax.lax.broadcasted_iota(jnp.int32, cf.shape, 1)
+        cum_excl = jnp.broadcast_to(sink_cap, cf.shape)
+        for j in range(cf.shape[1] - 1):
+            cum_excl = cum_excl + jnp.where(col > j, arc_cap[:, j:j + 1], 0)
+        d_sink_ref[...] = jnp.clip(avail, 0, sink_cap).astype(
+            d_sink_ref.dtype)
+        d_arc_ref[...] = jnp.clip(avail - cum_excl, 0, arc_cap).astype(
+            d_arc_ref.dtype)
 
     if mode in ("both", "relabel"):
-        no_adm = act & ~adm.any(axis=1) & ~sink_adm
-        cand = jnp.where(cf > 0, nlab + 1, inf).min(axis=1)
+        new_lab_ref = out_refs[-1]
+        any_adm = jnp.max(adm.astype(jnp.int32), axis=1, keepdims=True) > 0
+        no_adm = act & ~any_adm & ~sink_adm
+        cand = jnp.min(jnp.where(cf > 0, nlab + 1, inf), axis=1,
+                       keepdims=True)
         cand = jnp.where(sink_cf > 0, jnp.minimum(cand, 1), cand)
         new_lab_ref[...] = jnp.where(
-            no_adm, jnp.maximum(jnp.minimum(cand, d_inf), my_lab), my_lab)
-    else:
-        new_lab_ref[...] = my_lab
+            no_adm, jnp.maximum(jnp.minimum(cand, d_inf), lab),
+            lab).astype(new_lab_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_v", "interpret", "mode"))
@@ -149,52 +128,49 @@ def push_relabel_phase(lab, cf, sink_cf, excess, nbr, intra, pushable,
     """Pallas-tiled push/relabel compute phase.
 
     Returns (delta [V, 1+E] with the sink in column 0, new_lab [V]).
-    Masks are 0/1 integers (int32, or int8 under a narrow dtype policy) for
-    portable Pallas lowering; value dtypes follow the inputs.  ``mode``
-    statically prunes the unused output's compute ("push": zero new_lab
-    changes, "relabel": zero deltas); "both" computes everything.
+    Masks are 0/1 integers or bools; value dtypes follow the inputs.
+    ``mode`` statically prunes the unused output's compute ("push": zero
+    new_lab changes, "relabel": zero deltas); "both" computes everything.
+    The neighbour-label gather runs here in XLA; the kernel sees the
+    gathered ``[V, E]`` tile.
     """
     assert mode in ("both", "push", "relabel"), mode
     V, E = cf.shape
+    inf = _inf_for(lab.dtype)
+    nlab = jnp.where(intra != 0, lab[nbr], cross_lab)
+    nlab = jnp.where(pushable != 0, nlab, inf)
     bv = min(block_v, V)
-    if V % bv:                       # pad rows to a whole number of tiles
-        pad = bv - V % bv
-        padv = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        out_d, out_l = push_relabel_phase(
-            jnp.pad(lab, (0, pad), constant_values=_inf_for(lab.dtype)),
-            padv(cf), padv(sink_cf), padv(excess), padv(nbr), padv(intra),
-            padv(pushable), padv(cross_lab), d_inf, block_v=bv,
-            interpret=interpret, mode=mode)
-        return out_d[:V], out_l[:V]
-
-    grid = (V // bv,)
-    kernel = pl.pallas_call(
+    pad = -V % bv                    # pad rows to a whole number of tiles
+    rows = lambda a, fill=0: jnp.pad(
+        a, ((0, pad),) + ((0, 0),) * (a.ndim - 1), constant_values=fill)
+    column = lambda a, fill=0: rows(a, fill).reshape(V + pad, 1)
+    args = (column(lab, inf), rows(cf), column(sink_cf), column(excess),
+            rows(nlab, inf), jnp.reshape(jnp.asarray(d_inf, jnp.int32),
+                                          (1, 1)))
+    tile = lambda w: pl.BlockSpec((bv, w), lambda i: (i, 0))
+    out_specs, out_shape = [], []
+    if mode in ("both", "push"):
+        out_specs += [tile(1), tile(E)]
+        out_shape += [jax.ShapeDtypeStruct((V + pad, 1), cf.dtype),
+                      jax.ShapeDtypeStruct((V + pad, E), cf.dtype)]
+    if mode in ("both", "relabel"):
+        out_specs.append(tile(1))
+        out_shape.append(jax.ShapeDtypeStruct((V + pad, 1), lab.dtype))
+    outs = pl.pallas_call(
         functools.partial(_pr_kernel, mode=mode),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((V,), lambda i: (0,)),            # lab (full)
-            pl.BlockSpec((bv, E), lambda i: (i, 0)),       # cf
-            pl.BlockSpec((bv,), lambda i: (i,)),           # sink_cf
-            pl.BlockSpec((bv,), lambda i: (i,)),           # excess
-            pl.BlockSpec((bv, E), lambda i: (i, 0)),       # nbr
-            pl.BlockSpec((bv, E), lambda i: (i, 0)),       # intra
-            pl.BlockSpec((bv, E), lambda i: (i, 0)),       # pushable
-            pl.BlockSpec((bv, E), lambda i: (i, 0)),       # cross_lab
-            pl.BlockSpec((1,), lambda i: (0,)),            # d_inf
-        ],
-        out_specs=[
-            pl.BlockSpec((bv, 1 + E), lambda i: (i, 0)),   # delta
-            pl.BlockSpec((bv,), lambda i: (i,)),           # new_lab
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((V, 1 + E), cf.dtype),
-            jax.ShapeDtypeStruct((V,), lab.dtype),
-        ],
+        grid=((V + pad) // bv,),
+        in_specs=[tile(1), tile(E), tile(1), tile(1), tile(E),
+                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )
-    d_inf_arr = jnp.reshape(jnp.asarray(d_inf, jnp.int32), (1,))
-    return kernel(lab, cf, sink_cf, excess, nbr, intra, pushable, cross_lab,
-                  d_inf_arr)
+    )(*args)
+    if mode == "relabel":
+        delta = jnp.zeros((V, 1 + E), cf.dtype)
+    else:
+        delta = jnp.concatenate([outs[0], outs[1]], axis=1)[:V]
+    new_lab = lab if mode == "push" else outs[-1][:V, 0]
+    return delta, new_lab
 
 
 # --------------------------------------------------------------------------
@@ -209,6 +185,13 @@ def push_relabel_phase(lab, cf, sink_cf, excess, nbr, intra, pushable,
 # int32 scalar/misc words per row.  The budget leaves headroom under the
 # ~16 MiB/core of TPU VMEM for double buffering and the scalar plumbing.
 FUSED_VMEM_BUDGET_BYTES = 12 * 2**20
+
+# What Mosaic (jax 0.9, TPU v5e) says of the fused body, first refusal
+# first; each later one appears once the earlier construct is removed.
+FUSED_MOSAIC_REFUSAL = (
+    "'Only 2D gather is supported' for lab[nbr]; 'Unimplemented primitive "
+    "in Pallas TPU lowering for KernelType.TC: cumsum'; the same for "
+    "'scatter-add'")
 
 
 def fused_region_vmem_bytes(V: int, E: int,
@@ -351,26 +334,28 @@ def _fused_kernel_grid(lab_ref, cf_ref, sink_cf_ref, excess_ref, nbr_ref,
     (``grid=(B, K)``).
 
     Every ref carries ``nlead`` leading block dimensions of 1 (one region's
-    tile); ``scal_ref`` is this region's (d_inf, iter_limit) row.  The
-    in-kernel early exit makes an idle or already-converged region cost
-    O(1), so one launch can mix hot and idle regions — and converged and
-    running instances — freely.
+    tile); per-vertex ``[V]`` values ride as ``[1, V]`` rows and scalars as
+    ``[1, 1]`` so each block's last two dimensions are whole array
+    dimensions, as the TPU tiling requires.  ``scal_ref`` is this region's
+    (d_inf, iter_limit) row.  The in-kernel early exit makes an idle or
+    already-converged region cost O(1), so one launch can mix hot and idle
+    regions — and converged and running instances — freely.
     """
     z = (0,) * nlead
-    scal = scal_ref[z]
+    row = z + (0,)
+    scal = scal_ref[row]
     cf, sink_cf, excess, lab, out_push, sinkp, rls, it = _fused_region_loop(
-        lab_ref[z], cf_ref[z], sink_cf_ref[z], excess_ref[z],
+        lab_ref[row], cf_ref[z], sink_cf_ref[row], excess_ref[row],
         nbr_ref[z], rev_ref[z], intra_ref[z], pushable_ref[z],
-        cross_lab_ref[z], vmask_ref[z], scal[0], scal[1],
+        cross_lab_ref[z], vmask_ref[row], scal[0], scal[1],
         sink_open=sink_open)
     cf_out[z] = cf
-    sink_out[z] = sink_cf
-    exc_out[z] = excess
-    lab_out[z] = lab
+    sink_out[row] = sink_cf
+    exc_out[row] = excess
+    lab_out[row] = lab
     push_out[z] = out_push
-    sinkp_out[z] = sinkp
-    rls_out[z] = rls
-    it_out[z] = it
+    for ref, val in ((sinkp_out, sinkp), (rls_out, rls), (it_out, it)):
+        ref[...] = jnp.full(ref.shape, val, ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sink_open", "interpret"))
@@ -396,12 +381,9 @@ def fused_engine_run(lab, cf, sink_cf, excess, nbr, rev_slot, intra, pushable,
     return tuple(o[0] for o in outs)
 
 
-@functools.partial(jax.jit, static_argnames=("sink_open", "interpret",
-                                             "double_buffer"))
 def fused_engine_run_batched(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
                              pushable, cross_lab, vmask, d_inf, iter_limit, *,
-                             sink_open: bool = True, interpret: bool = True,
-                             double_buffer: bool | None = None):
+                             sink_open: bool = True, interpret: bool = True):
     """All regions of a sweep — or of a solve batch — in ONE kernel launch.
 
     The grid-over-regions variant of ``fused_engine_run``: with
@@ -418,17 +400,29 @@ def fused_engine_run_batched(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
     calls; what changes is the dispatch count: one launch instead of K
     (resp. B*K).
 
-    ``double_buffer`` selects the DMA-streamed variant on real TPUs
-    (regions staged HBM->VMEM one at a time with region k+1's copy in
-    flight while region k computes — ``None`` auto-selects it whenever
-    ``dma_overlap_supported()``); the grid form is the interpret-mode /
-    non-TPU fallback.  Both variants are bit-identical and count as one
-    launch.
+    ``interpret=False`` raises ``UnsupportedFeatureError``: Mosaic refuses
+    the body (``FUSED_MOSAIC_REFUSAL``).
 
     Returns ``(cf, sink_cf, excess, lab, out_push, sink_pushed [lead],
     relabel_sum [lead], iters [lead])`` where ``lead`` = ``(K,)`` or
     ``(B, K)``.
     """
+    if not interpret:
+        from repro.core.executor import UnsupportedFeatureError
+        raise UnsupportedFeatureError(
+            "TPU", "fused_pallas",
+            f"Mosaic refuses its body ({FUSED_MOSAIC_REFUSAL}); use "
+            f"engine_backend='xla' with engine_chunk_iters, or "
+            f"engine_backend='pallas' without it")
+    return _fused_grid_call(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
+                            pushable, cross_lab, vmask, d_inf, iter_limit,
+                            sink_open=sink_open)
+
+
+@functools.partial(jax.jit, static_argnames=("sink_open",))
+def _fused_grid_call(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
+                     pushable, cross_lab, vmask, d_inf, iter_limit, *,
+                     sink_open: bool):
     lead = cf.shape[:-2]
     V, E = cf.shape[-2:]
     nlead = len(lead)
@@ -437,175 +431,41 @@ def fused_engine_run_batched(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
         [jnp.broadcast_to(jnp.asarray(d_inf, jnp.int32), lead),
          jnp.broadcast_to(jnp.asarray(iter_limit, jnp.int32), lead)],
         axis=-1)                                           # [*lead, 2]
-    args = (lab, cf, sink_cf, excess, nbr, rev_slot, intra, pushable,
-            cross_lab, vmask)
-    if double_buffer is None:
-        double_buffer = dma_overlap_supported() and not interpret
-    if double_buffer and nlead == 1:
-        return _fused_streamed_call(args, scal, sink_open=sink_open)
     blk = lambda *tail: pl.BlockSpec(
         (1,) * nlead + tail, lambda *ids: ids + (0,) * len(tail))
-    vec = lambda: blk(V)
-    mat = lambda w: blk(V, w)
-    one = lambda: pl.BlockSpec((1,) * nlead, lambda *ids: ids)
+    vec, scl = blk(1, V), blk(1, 1)
+    mat = blk(V, E)
+    row = lambda a: a.reshape(lead + (1, V))
+    lab, sink_cf, excess, vmask = map(row, (lab, sink_cf, excess, vmask))
     outs = pl.pallas_call(
         functools.partial(_fused_kernel_grid, sink_open=sink_open,
                           nlead=nlead),
         grid=lead,
-        in_specs=[vec(), mat(E), vec(), vec(), mat(E), mat(E), mat(E),
-                  mat(E), mat(E), vec(), blk(2)],
-        out_specs=[mat(E), vec(), vec(), vec(), mat(E), one(), one(), one()],
+        in_specs=[vec, mat, vec, vec, mat, mat, mat, mat, mat, vec,
+                  blk(1, 2)],
+        out_specs=[mat, vec, vec, vec, mat, scl, scl, scl],
         out_shape=[
-            jax.ShapeDtypeStruct(lead + (V, E), cf.dtype),    # cf
-            jax.ShapeDtypeStruct(lead + (V,), sink_cf.dtype),  # sink_cf
-            jax.ShapeDtypeStruct(lead + (V,), excess.dtype),  # excess
-            jax.ShapeDtypeStruct(lead + (V,), lab.dtype),     # lab
-            jax.ShapeDtypeStruct(lead + (V, E), cf.dtype),    # out_push
-            jax.ShapeDtypeStruct(lead, jnp.int32),            # sink_pushed
-            jax.ShapeDtypeStruct(lead, jnp.int32),            # relabel_sum
-            jax.ShapeDtypeStruct(lead, jnp.int32),            # iters
+            jax.ShapeDtypeStruct(lead + (V, E), cf.dtype),       # cf
+            jax.ShapeDtypeStruct(lead + (1, V), sink_cf.dtype),  # sink_cf
+            jax.ShapeDtypeStruct(lead + (1, V), excess.dtype),   # excess
+            jax.ShapeDtypeStruct(lead + (1, V), lab.dtype),      # lab
+            jax.ShapeDtypeStruct(lead + (V, E), cf.dtype),       # out_push
+            jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # sink_pushed
+            jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # relabel_sum
+            jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # iters
         ],
-        interpret=interpret,
-    )(*args, scal)
-    return outs
-
-
-# --------------------------------------------------------------------------
-# DMA-streamed fused discharge: double-buffered region staging (TPU only).
-# --------------------------------------------------------------------------
-
-def dma_overlap_supported() -> bool:
-    """True when the DMA-streamed (double-buffered) fused variant can run:
-    manual ``pltpu.make_async_copy`` pipelines need a real TPU backend —
-    plain interpret mode executes the grid variant instead (bit-identical,
-    serial region staging)."""
-    return jax.default_backend() == "tpu"
-
-
-def _fused_kernel_streamed(lab_hbm, cf_hbm, sink_hbm, exc_hbm, nbr_hbm,
-                           rev_hbm, intra_hbm, push_hbm, clab_hbm, vmask_hbm,
-                           scal_smem, cf_out, sink_out, exc_out, lab_out,
-                           op_out, sinkp_out, rls_out, it_out, *,
-                           sink_open: bool, num_regions: int):
-    """Single-program streamed form of the grid kernel (pallas guide
-    "Double Buffering"): inputs stay in HBM/ANY; region k's ten blocks are
-    DMA'd into one of two VMEM slots while region k-1 computes, and each
-    region's results are DMA'd back out while the next region runs.  The
-    compute body is the same ``_fused_region_loop`` as the grid variant,
-    so results are bit-identical; what changes is that the K-region launch
-    no longer serializes loads with compute — the kernel-level
-    prerequisite for streaming regions that don't fit VMEM together.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    K = num_regions
-    ins = (cf_hbm, lab_hbm, sink_hbm, exc_hbm, nbr_hbm, rev_hbm, intra_hbm,
-           push_hbm, clab_hbm, vmask_hbm)
-
-    def scoped(in_s, out_s, in_sems, out_sems):
-        def in_dmas(slot, k):
-            return [pltpu.make_async_copy(src.at[k], dst.at[slot],
-                                          in_sems.at[slot, i])
-                    for i, (src, dst) in enumerate(zip(ins, in_s))]
-
-        outs_hbm = (cf_out, sink_out, exc_out, lab_out, op_out)
-
-        def out_dmas(slot, k):
-            return [pltpu.make_async_copy(src.at[slot], dst.at[k],
-                                          out_sems.at[slot, i])
-                    for i, (src, dst) in enumerate(zip(out_s, outs_hbm))]
-
-        for dma in in_dmas(0, 0):
-            dma.start()
-
-        def body(k, _):
-            slot = k % 2
-
-            @pl.when(k + 1 < K)
-            def _prefetch():            # stage region k+1 while k computes
-                for dma in in_dmas((k + 1) % 2, k + 1):
-                    dma.start()
-
-            for dma in in_dmas(slot, k):
-                dma.wait()
-
-            @pl.when(k >= 2)
-            def _drain():               # slot's previous writeback done?
-                for dma in out_dmas(slot, k - 2):
-                    dma.wait()
-
-            cf_s, lab_s, sink_s, exc_s, nbr_s, rev_s, intra_s, push_s, \
-                clab_s, vm_s = in_s
-            cf, sink_cf, excess, lab, out_push, sinkp, rls, it = \
-                _fused_region_loop(
-                    lab_s[slot], cf_s[slot], sink_s[slot], exc_s[slot],
-                    nbr_s[slot], rev_s[slot], intra_s[slot], push_s[slot],
-                    clab_s[slot], vm_s[slot], scal_smem[k, 0],
-                    scal_smem[k, 1], sink_open=sink_open)
-            cfo_s, sino_s, exco_s, labo_s, opo_s = out_s
-            cfo_s[slot] = cf
-            sino_s[slot] = sink_cf
-            exco_s[slot] = excess
-            labo_s[slot] = lab
-            opo_s[slot] = out_push
-            sinkp_out[k] = sinkp        # scalar accumulators live in SMEM
-            rls_out[k] = rls
-            it_out[k] = it
-            for dma in out_dmas(slot, k):
-                dma.start()
-            return 0
-
-        jax.lax.fori_loop(0, K, body, 0)
-
-        @pl.when(K >= 2)
-        def _():
-            for dma in out_dmas((K - 2) % 2, K - 2):
-                dma.wait()
-        for dma in out_dmas((K - 1) % 2, K - 1):
-            dma.wait()
-
-    V, E = cf_hbm.shape[-2:]
-    dbl = lambda ref, *tail: pltpu.VMEM((2,) + tail, ref.dtype)
-    pl.run_scoped(
-        scoped,
-        in_s=tuple(dbl(r, V, E) if r.ndim == 3 else dbl(r, V)
-                   for r in ins),
-        out_s=(dbl(cf_hbm, V, E), dbl(sink_hbm, V), dbl(exc_hbm, V),
-               dbl(lab_hbm, V), dbl(cf_hbm, V, E)),
-        in_sems=pltpu.SemaphoreType.DMA((2, 10)),
-        out_sems=pltpu.SemaphoreType.DMA((2, 5)),
-    )
-
-
-def _fused_streamed_call(args, scal, *, sink_open: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
-    lab, cf, sink_cf, excess = args[0], args[1], args[2], args[3]
-    K, V, E = cf.shape
-    anyspec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    smem = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.SMEM)
-    return pl.pallas_call(
-        functools.partial(_fused_kernel_streamed, sink_open=sink_open,
-                          num_regions=K),
-        in_specs=[anyspec] * 10 + [smem],
-        out_specs=[anyspec] * 5 + [smem] * 3,
-        out_shape=[
-            jax.ShapeDtypeStruct((K, V, E), cf.dtype),    # cf
-            jax.ShapeDtypeStruct((K, V), sink_cf.dtype),  # sink_cf
-            jax.ShapeDtypeStruct((K, V), excess.dtype),   # excess
-            jax.ShapeDtypeStruct((K, V), lab.dtype),      # lab
-            jax.ShapeDtypeStruct((K, V, E), cf.dtype),    # out_push
-            jax.ShapeDtypeStruct((K,), jnp.int32),        # sink_pushed
-            jax.ShapeDtypeStruct((K,), jnp.int32),        # relabel_sum
-            jax.ShapeDtypeStruct((K,), jnp.int32),        # iters
-        ],
-    )(*args, scal)
+        interpret=True,
+    )(lab, cf, sink_cf, excess, nbr, rev_slot, intra, pushable, cross_lab,
+      vmask, scal.reshape(lead + (1, 2)))
+    cf, sink_cf, excess, lab, out_push, sinkp, rls, it = outs
+    vecs = (a.reshape(lead + (V,)) for a in (sink_cf, excess, lab))
+    return (cf, *vecs, out_push, *(a.reshape(lead) for a in (sinkp, rls, it)))
 
 
 def engine_phase(lab, cf, sink_cf, excess, *, nbr_local, intra, emask, vmask,
                  cross_pushable, cross_lab, d_inf, sink_open: bool = True,
                  block_v: int = DEFAULT_BLOCK_V, interpret: bool = True,
-                 mode: str = "both", mask_dtype=jnp.int32):
+                 mode: str = "both"):
     """Engine-semantics adapter over ``push_relabel_phase``.
 
     Folds the engine's masks into the kernel's inputs: arcs are pushable iff
@@ -616,10 +476,10 @@ def engine_phase(lab, cf, sink_cf, excess, *, nbr_local, intra, emask, vmask,
     consumes.  ``mode`` prunes the output the caller discards ("push" /
     "relabel" / "both").
     """
-    pushable = ((cross_pushable | intra) & emask).astype(mask_dtype)
+    pushable = (cross_pushable | intra) & emask
     excess = jnp.where(vmask, excess, 0)
     sink = sink_cf if sink_open else jnp.zeros_like(sink_cf)
     return push_relabel_phase(lab, cf, sink, excess, nbr_local,
-                              intra.astype(mask_dtype), pushable, cross_lab,
+                              intra, pushable, cross_lab,
                               d_inf, block_v=block_v, interpret=interpret,
                               mode=mode)

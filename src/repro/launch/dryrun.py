@@ -42,6 +42,9 @@ from repro.configs.registry import ARCHS, get_arch
 from repro.launch.mesh import make_production_mesh
 from repro.roofline import analysis as roof
 
+# the chip the production mesh models (launch/mesh.py: v5e-256 pods);
+# its published peaks price the compiled cost terms
+TARGET_DEVICE_KIND = "TPU v5 lite"
 Q_CHUNK_THRESHOLD = 2048      # chunk whenever S exceeds this
 Q_CHUNK = 1024
 MICROBATCHES = 1              # grad-accumulation factor (hillclimb knob)
@@ -202,9 +205,10 @@ def dryrun_lm_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
     use_f = flops if flops is not None else raw_flops
     use_b = nbytes if nbytes is not None else raw_bytes
     use_c = coll if coll is not None else raw_coll
-    compute_s = use_f / roof.PEAK_FLOPS
-    memory_s = use_b / roof.HBM_BW
-    collective_s = use_c / roof.LINK_BW
+    peaks = roof.peaks_for(TARGET_DEVICE_KIND)
+    compute_s = use_f / peaks.flops
+    memory_s = use_b / peaks.hbm_bw
+    collective_s = use_c / peaks.link_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     rec = {
@@ -245,20 +249,14 @@ def _prefill_batch_specs(cfg, shape):
 def dryrun_maxflow(*, multi_pod: bool, region_size: int = 4096,
                    degree: int = 8, exchange: str = "full") -> dict:
     """Dry-run the distributed P-ARD sweep: one region per chip."""
-    from repro.core.distributed import (make_sharded_sweep,
+    from repro.core.distributed import (grid_like_meta, make_sharded_sweep,
                                         maxflow_input_specs)
-    from repro.core.graph import GraphMeta
     from repro.core.sweep import SweepConfig
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.size
-    K = n_chips
     V, E = region_size, degree
-    X = int(4 * (V ** 0.5)) * K
-    meta = GraphMeta(num_regions=K, region_size=V, max_degree=E,
-                     num_vertices=K * V, num_boundary=X // 2,
-                     num_cross_arcs=X, num_ghost_groups=X,
-                     d_inf_ard=X // 2, d_inf_prd=K * V)
+    meta = grid_like_meta(n_chips, V, E)
     axes = tuple(mesh.axis_names)
     t0 = time.time()
     with mesh:
@@ -270,9 +268,10 @@ def dryrun_maxflow(*, multi_pod: bool, region_size: int = 4096,
         compiled = lowered.compile()
         t_compile = round(time.time() - t0 - t_lower, 1)
     flops, nbytes, coll, coll_d = _cost_triple(compiled)
-    terms = {"compute": flops / roof.PEAK_FLOPS,
-             "memory": nbytes / roof.HBM_BW,
-             "collective": coll / roof.LINK_BW}
+    peaks = roof.peaks_for(TARGET_DEVICE_KIND)
+    terms = {"compute": flops / peaks.flops,
+             "memory": nbytes / peaks.hbm_bw,
+             "collective": coll / peaks.link_bw}
     return {
         "arch": f"maxflow-pard-{exchange}", "shape": f"V{V}xE{E}",
         "mesh": _mesh_tag(multi_pod), "status": "ok", "n_chips": n_chips,

@@ -153,8 +153,10 @@ def main():
         ap.error("--handle-budget-mb and --eviction-dir go together")
 
     from repro.core import SolverOptions
+    from repro.launch.cache import enable_compile_cache
     from repro.serve import (MaxflowService, ServiceConfig, replay_stream)
 
+    enable_compile_cache()
     ry, rx = (int(v) for v in args.regions.split("x"))
     opts = SolverOptions(method=args.method, num_regions=ry * rx,
                          engine_backend=args.engine_backend,

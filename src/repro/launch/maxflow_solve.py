@@ -153,7 +153,9 @@ def main():
 
     from repro.core import SweepConfig, grid_partition
     from repro.data.grids import synthetic_grid
+    from repro.launch.cache import enable_compile_cache
 
+    enable_compile_cache()
     ry, rx = (int(v) for v in args.regions.split("x"))
     if args.streaming:
         if args.sharded:

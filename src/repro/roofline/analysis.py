@@ -10,8 +10,8 @@ already reports per-device FLOPs/bytes; equivalently the spec's
 cost_analysis — we parse the optimized HLO and sum operand sizes of every
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link
-ICI (the conservative single-link figure; see EXPERIMENTS.md §Roofline).
+The peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``; a
+device kind that is not in the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -19,9 +19,34 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link (conservative: 1 link)
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks: FLOP/s (bf16), HBM bytes/s, and bytes/s
+    of one ICI link (the conservative single-link collective term)."""
+
+    flops: float
+    hbm_bw: float
+    link_bw: float
+    source: str
+
+
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s; ICI 1,600 Gbit/s per chip, taken "
+               "as one 50 GB/s link (EXPERIMENTS.md §Roofline)"),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; an unknown kind raises ``KeyError``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       f" known kinds: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -141,8 +166,12 @@ class Roofline:
                     coll_detail=self.coll_detail)
 
 
-def analyze(compiled, *, n_chips: int, model_flops_global: float = 0.0,
+def analyze(compiled, *, n_chips: int, device_kind: str,
+            model_flops_global: float = 0.0,
             hlo_text: str | None = None) -> Roofline:
+    """Roofline terms of ``compiled`` against the peaks of
+    ``device_kind``: the chip the program was compiled for."""
+    peaks = peaks_for(device_kind)
     cost = compiled.cost_analysis()
     if isinstance(cost, list):           # older jax returns [dict]
         cost = cost[0]
@@ -150,9 +179,9 @@ def analyze(compiled, *, n_chips: int, model_flops_global: float = 0.0,
     nbytes = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = collective_bytes(text)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = nbytes / HBM_BW
-    collective_s = coll["total"] / LINK_BW
+    compute_s = flops / peaks.flops
+    memory_s = nbytes / peaks.hbm_bw
+    collective_s = coll["total"] / peaks.link_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)

@@ -12,9 +12,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/repro_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 
 @pytest.fixture
 def fresh_compile_cache():

@@ -351,6 +351,48 @@ def test_degrade_config_walks_the_ladder():
     assert not res.is_kernel_failure(KeyError("unrelated"))
 
 
+@pytest.mark.parametrize("exc", [
+    NotImplementedError("Unimplemented primitive in Pallas TPU lowering "
+                        "for KernelType.TC: cumsum"),
+    NotImplementedError("Only 2D gather is supported"),
+    RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: Invalid "
+                 "relayout"),
+    AttributeError("module 'jax.experimental.pallas.tpu' has no attribute "
+                   "'TPUMemorySpace'"),
+], ids=["unimplemented-primitive", "gather", "mosaic", "missing-api"])
+def test_lowering_refusal_is_not_ladder_eligible(exc):
+    """A kernel the compiler refuses, or an API that is gone, must raise:
+    stepping down would hide that the requested route never ran."""
+    assert not res.is_kernel_failure(exc)
+    cfg = SweepConfig(engine_backend="pallas", engine_chunk_iters=8)
+
+    def run(c):
+        raise exc
+
+    notes = []
+    with pytest.raises(type(exc)):
+        res.run_with_degradation(run, cfg, notes)
+    assert notes == []
+
+
+def test_fused_pallas_on_tpu_raises_up_front(monkeypatch):
+    """On a TPU (steered here: the engine asks ``jax.default_backend``) a
+    fused pallas solve raises the typed refusal instead of degrading."""
+    from repro.core import UnsupportedFeatureError
+
+    p, part = _instance()
+    h = Solver(SolverOptions(method="prd", engine_backend="pallas",
+                             engine_chunk_iters=8)).prepare(p, part)
+    jax.clear_caches()            # the engine resolves the backend at trace
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with pytest.raises(UnsupportedFeatureError, match="fused_pallas"):
+            h.solve()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+
+
 def test_vmem_overflow_degrades_one_rung():
     """A kernel-class failure mid-solve re-runs one rung down; the result
     is bit-correct and the degradation is recorded, never silent."""

@@ -196,3 +196,18 @@ def test_sharded_route_unified_result():
     h2 = s.prepare(_instance(seed=10)[0], part)
     h2.solve(mesh=mesh)
     assert s.cache_info().traces == traces
+
+
+def test_sharded_route_warm_recut():
+    """A capacity update after a sharded solve (the explicit-axis mesh
+    ``jax.make_mesh`` builds) re-solves warm on the mesh and matches a
+    cold solve of the updated problem."""
+    p, part = _instance(seed=11)
+    mesh = jax.make_mesh((1,), ("regions",))
+    h = Solver(SolverOptions()).prepare(p, part)
+    h.solve(mesh=mesh)
+    rng = np.random.RandomState(3)
+    idx = rng.choice(len(p.edges), size=6, replace=False)
+    h.update(arcs=idx, cap_fwd=rng.randint(0, 301, size=6).astype(np.int32))
+    res = h.solve(mesh=mesh)
+    assert res.flow_value == solve_mincut(h.problem, part=part).flow_value

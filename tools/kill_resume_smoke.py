@@ -6,10 +6,12 @@ a checkpointing solve mid-sweep — no cleanup handlers, no atexit, exactly
 what a preempted worker looks like — and then resuming from whatever the
 dead process managed to publish:
 
-1. the parent solves the instance uninterrupted (the baseline);
-2. a child process runs the same solve with sweep-boundary checkpoints
-   and ``os.kill(getpid(), SIGKILL)`` at sweep K (installed through the
+1. a child process runs the solve with sweep-boundary checkpoints and
+   ``os.kill(getpid(), SIGKILL)`` at sweep K (installed through the
    executor fault hook, which fires AFTER the boundary's checkpoint);
+   the parent touches no device until the child is dead, so the child
+   can hold the accelerator;
+2. the parent solves the instance uninterrupted (the baseline);
 3. the parent asserts the child died on SIGKILL, that the latest published
    checkpoint is a mid-solve boundary, resumes from it, and asserts the
    result is BIT-EXACT against the baseline (flow, labels, residuals,
@@ -110,12 +112,24 @@ def child_streaming(ckdir: str) -> None:
     raise SystemExit("unreachable: the solve outlived its kill sweep")
 
 
+def _run_child(ckdir: str, *flags: str) -> None:
+    """Run the doomed child and check that it died on SIGKILL.  The parent
+    touches no device before this returns: a chip serves one process."""
+    proc = subprocess.run(
+        [sys.executable, __file__, *flags, "--child", ckdir],
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == -signal.SIGKILL, (
+        f"child exited {proc.returncode}, wanted SIGKILL "
+        f"({-signal.SIGKILL})\n--- child stderr ---\n{proc.stderr}")
+
+
 def parent_streaming(ckdir: str) -> None:
     import numpy as np
 
     from repro.core import resilience
     from repro.stream import build_stream, solve_stream
 
+    _run_child(ckdir, "--streaming")
     p, part = _stream_problem()
     ss = build_stream(p, part, _stream_cfg(), prefetch=False)
     ss, base_stats = solve_stream(ss)
@@ -123,14 +137,6 @@ def parent_streaming(ckdir: str) -> None:
     ss.store.close()
     assert base_stats.sweeps > KILL_AT, \
         f"instance converges in {base_stats.sweeps} sweeps; nothing to kill"
-
-    proc = subprocess.run(
-        [sys.executable, __file__, "--streaming", "--child", ckdir],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=600)
-    assert proc.returncode == -signal.SIGKILL, (
-        f"child exited {proc.returncode}, wanted SIGKILL "
-        f"({-signal.SIGKILL})\n--- child stderr ---\n{proc.stderr}")
 
     latest = resilience.latest_checkpoint(ckdir)
     assert latest is not None, "the killed child published no checkpoint"
@@ -162,19 +168,12 @@ def parent(ckdir: str) -> None:
     from repro.core import init_labels, resilience
     from repro.core.sweep import SweepConfig, solve
 
+    _run_child(ckdir)
     meta, state = _built()
     cfg = SweepConfig(method="ard")
     base_st, base_stats = solve(meta, init_labels(meta, state), cfg)
     assert base_stats.sweeps > KILL_AT, \
         f"instance converges in {base_stats.sweeps} sweeps; nothing to kill"
-
-    proc = subprocess.run(
-        [sys.executable, __file__, "--child", ckdir],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=600)
-    assert proc.returncode == -signal.SIGKILL, (
-        f"child exited {proc.returncode}, wanted SIGKILL "
-        f"({-signal.SIGKILL})\n--- child stderr ---\n{proc.stderr}")
 
     latest = resilience.latest_checkpoint(ckdir)
     assert latest is not None, "the killed child published no checkpoint"
