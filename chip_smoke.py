@@ -4,20 +4,21 @@
     python chip_smoke.py --chips 4    # four chips: the sharded phase only
 
 One chip.  An 8-connected ``synthetic_grid`` (paper Sec. 7.1) is cut into
-4x4 regions and solved cold through
-``Solver.prepare(...).solve()`` on three engine routes: the default (XLA
-engine, one host sync per sweep), the device-resident fused XLA engine,
-and the blocked Pallas kernel.  Every solve checks its cut == flow
-certificate; the routes are bit-exact by design, so flow and sweep counts
-must agree, and none may step down the degradation ladder.  Then two warm
-re-cuts (a 1% capacity perturbation through ``handle.update``) and a short
-``MaxflowService`` pass of small mixed grids plus a warm session re-cut,
-checked against the Edmonds-Karp oracle.
+4x4 regions and solved cold through ``Solver.prepare(...).solve()`` on
+three engine routes: the default (XLA engine, one host sync per sweep),
+the device-resident fused XLA engine, and the blocked Pallas kernel.
+Every solve checks its cut == flow certificate; the routes are bit-exact
+by design, so flow and sweep counts must agree, and none may step down the
+degradation ladder.  Then two warm re-cuts (a 1% capacity perturbation
+through ``handle.update``), a short ``MaxflowService`` pass of small mixed
+grids plus a warm session re-cut, checked against the Edmonds-Karp oracle,
+and last the megapixel (1024x1024) instance for one sweep with capped
+engine runs on every route, whose states must be bit-identical.
 
-Four chips.  The same instance solved by the sharded SPMD route over a
-four-chip mesh (16 regions, 4 per chip), then by the single-chip
-device-resident route it must equal.  Per-device peak memory shows the
-regions spread over the chips.
+Four chips.  The sharded SPMD route over a four-chip mesh (16 regions, 4
+per chip) against the single-chip device-resident route it must equal:
+solved to convergence at the small size, then the capped megapixel sweep.
+Per-device peak memory shows the regions spread over the chips.
 
 Each phase prints one line.  The last line is one JSON object,
 ``{"ok": true, "device": {...}}``, printed only when every phase passed.
@@ -34,12 +35,16 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
-# Vertices per side of the solved instance.  The megapixel shape (1024)
-# is out of reach of the current engine on a v5e: engine iterations grow
-# steeply with region size and each one is gather/scatter-bound, so a
-# converged solve took 27 s at 64x64 and 416 s at 96x96, and the first
-# sweep alone 700 s at 256x256 (one v5e chip, jax 0.9).
+# Vertices per side of the instance solved to convergence.  A converged
+# megapixel solve is out of reach of the current engine on a v5e: engine
+# iterations grow steeply with region size, so a converged solve took
+# 27 s at 64x64 and 416 s at 96x96, and the first sweep alone 700 s at
+# 256x256 (one v5e chip, jax 0.9).
 GRID = 64
+# The megapixel instance, run for a fixed amount of work on every route:
+# the real-size programs, device memory and multi-tile Pallas grid.
+BIG = 1024
+BOUNDED = dict(max_sweeps=1, engine_max_iters=16)
 REGIONS = (4, 4)
 ROUTES = {                  # SolverOptions overrides per route
     "xla": {},
@@ -143,6 +148,61 @@ def one_chip(jax, phases):
             _solve(jax, handle)))
 
     phases.run("service", lambda: _service())
+    bounded(jax, phases, [(route, kw, {}) for route, kw in ROUTES.items()])
+
+
+def bounded(jax, phases, runs):
+    """The megapixel instance for one capped sweep on each of ``runs``
+    (name, SolverOptions overrides, ``solve`` arguments).  They must leave
+    bit-identical states, and nothing may degrade.  Peak device memory is
+    process-wide, so cumulative over the phases; the host-loop routes also
+    print what XLA reports for their sweep program.
+    """
+    import numpy as np
+
+    from repro.core import Solver
+
+    p, part = _instance(BIG)
+    states = {}
+    for name, kw, solve_kw in runs:
+        t0 = time.perf_counter()
+        handle = Solver(_options(**kw, **BOUNDED)).prepare(p, part)
+        jax.block_until_ready(handle.state.cf)
+        prepare_s = time.perf_counter() - t0
+
+        def solve():
+            out, states[name] = _bounded_solve(jax, handle, **solve_kw)
+            return dict(prepare_s=round(prepare_s, 3), **out)
+
+        phases.run(f"bounded/{name}/{BIG}x{BIG}", solve)
+        del handle                    # free its device arrays for the next
+    first = states[runs[0][0]]
+    for name, state in states.items():
+        for k in first:
+            assert np.array_equal(state[k], first[k]), (name, k)
+
+
+def _bounded_solve(jax, handle, mesh=None):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.sweep import parallel_sweep
+
+    out = {}
+    opts = handle.solver.options
+    if not opts.device_resident:      # the host loop runs this program
+        mem = parallel_sweep.lower(handle.meta, handle.state,
+                                   opts.sweep_config(),
+                                   jnp.int32(0)).compile().memory_analysis()
+        out.update(sweep_argument_bytes=mem.argument_size_in_bytes,
+                   sweep_temp_bytes=mem.temp_size_in_bytes)
+    res = handle.solve(mesh=mesh)
+    jax.block_until_ready(res.state.cf)
+    assert res.stats.degraded == [], res.stats.degraded
+    out.update(_summary(res), converged=res.converged)
+    state = {k: np.asarray(getattr(res.state, k))
+             for k in ("flow_to_t", "d", "excess", "sink_cf", "cf")}
+    return out, state
 
 
 def _service():
@@ -189,6 +249,8 @@ def four_chips(jax, phases):
     assert (sharded["flow"], sharded["sweeps"]) == (single["flow"],
                                                     single["sweeps"]), \
         (sharded, single)
+    bounded(jax, phases, [("sharded-x4", kw, dict(mesh=mesh)),
+                          ("xla-fused-device", kw, {})])
 
 
 def main() -> None:

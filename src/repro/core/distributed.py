@@ -355,6 +355,8 @@ def solve_sharded(meta: GraphMeta, state: FlowState, mesh: Mesh,
         device_resident = cfg.device_resident
     if host_sync_every is None:
         host_sync_every = cfg.host_sync_every
+    if max_sweeps is None:
+        max_sweeps = cfg.max_sweeps
     shardings = flowstate_shardings(mesh, axes)
     if checkpoint is not None:
         salt = checkpoint.salt

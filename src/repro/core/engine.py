@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import dtypes as _dt
+from repro.core.executor import UnsupportedFeatureError
 from repro.kernels import push_relabel as _pr_kernel
 
 _I32 = jnp.int32
@@ -351,6 +352,15 @@ def _push_relabel_fused_batched(cf, sink_cf, excess, lab, *, nbr_local,
     if backend == "pallas":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
+        if not interpret:
+            # Mosaic refuses the fused body (in-kernel gather, cumsum and
+            # scatter-add; tests/test_tpu_compile.py): fail before tracing
+            # rather than let a compile error reach the degradation ladder
+            raise UnsupportedFeatureError(
+                "TPU", "fused_pallas",
+                "Mosaic cannot lower its in-kernel gather, cumsum and "
+                "scatter-add; use engine_backend='xla' with "
+                "engine_chunk_iters, or engine_backend='pallas' without it")
         md = _mask_dtype(cf, lab)
         intra_i = intra.astype(md)
         pushable_i = pushable.astype(md)

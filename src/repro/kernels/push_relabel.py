@@ -32,13 +32,12 @@ instance advances its region up to ``iter_limit`` complete engine
 iterations — push split, intra-region scatter, post-push relabel — with
 the state resident and a per-region early exit.  ``fused_engine_run`` is
 its single-region form.  Its body gathers ``lab[nbr]``, takes a cumsum and
-scatter-adds inside the kernel, which Mosaic refuses
-(``FUSED_MOSAIC_REFUSAL``).  It therefore runs only under the Pallas
-interpreter, where it is bit-exact against the other engine routes; a
-compiled (``interpret=False``) request raises ``UnsupportedFeatureError``
-before anything is traced, and never steps down the degradation ladder.
-``core.engine`` also falls back to the blocked path when a region exceeds
-the VMEM budget (``fused_region_fits_vmem``).
+scatter-adds inside the kernel, which Mosaic refuses ("Only 2D gather is
+supported" is the first refusal; tests/test_tpu_compile.py lowers it).  It
+therefore runs only under the Pallas interpreter, where it is bit-exact
+against the other engine routes; on a TPU ``core.engine`` refuses the route
+up front.  ``core.engine`` also falls back to the blocked path when a
+region exceeds the VMEM budget (``fused_region_fits_vmem``).
 """
 
 from __future__ import annotations
@@ -185,13 +184,6 @@ def push_relabel_phase(lab, cf, sink_cf, excess, nbr, intra, pushable,
 # int32 scalar/misc words per row.  The budget leaves headroom under the
 # ~16 MiB/core of TPU VMEM for double buffering and the scalar plumbing.
 FUSED_VMEM_BUDGET_BYTES = 12 * 2**20
-
-# What Mosaic (jax 0.9, TPU v5e) says of the fused body, first refusal
-# first; each later one appears once the earlier construct is removed.
-FUSED_MOSAIC_REFUSAL = (
-    "'Only 2D gather is supported' for lab[nbr]; 'Unimplemented primitive "
-    "in Pallas TPU lowering for KernelType.TC: cumsum'; the same for "
-    "'scatter-add'")
 
 
 def fused_region_vmem_bytes(V: int, E: int,
@@ -381,6 +373,7 @@ def fused_engine_run(lab, cf, sink_cf, excess, nbr, rev_slot, intra, pushable,
     return tuple(o[0] for o in outs)
 
 
+@functools.partial(jax.jit, static_argnames=("sink_open", "interpret"))
 def fused_engine_run_batched(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
                              pushable, cross_lab, vmask, d_inf, iter_limit, *,
                              sink_open: bool = True, interpret: bool = True):
@@ -400,29 +393,15 @@ def fused_engine_run_batched(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
     calls; what changes is the dispatch count: one launch instead of K
     (resp. B*K).
 
-    ``interpret=False`` raises ``UnsupportedFeatureError``: Mosaic refuses
-    the body (``FUSED_MOSAIC_REFUSAL``).
+    Per-vertex values ride as ``[*lead, 1, V]`` rows and scalars as
+    ``[*lead, 1, 1]`` so each block's last two dimensions are whole array
+    dimensions, as the TPU tiling requires; Mosaic then reaches the body
+    and refuses it (see the module docstring).
 
     Returns ``(cf, sink_cf, excess, lab, out_push, sink_pushed [lead],
     relabel_sum [lead], iters [lead])`` where ``lead`` = ``(K,)`` or
     ``(B, K)``.
     """
-    if not interpret:
-        from repro.core.executor import UnsupportedFeatureError
-        raise UnsupportedFeatureError(
-            "TPU", "fused_pallas",
-            f"Mosaic refuses its body ({FUSED_MOSAIC_REFUSAL}); use "
-            f"engine_backend='xla' with engine_chunk_iters, or "
-            f"engine_backend='pallas' without it")
-    return _fused_grid_call(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
-                            pushable, cross_lab, vmask, d_inf, iter_limit,
-                            sink_open=sink_open)
-
-
-@functools.partial(jax.jit, static_argnames=("sink_open",))
-def _fused_grid_call(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
-                     pushable, cross_lab, vmask, d_inf, iter_limit, *,
-                     sink_open: bool):
     lead = cf.shape[:-2]
     V, E = cf.shape[-2:]
     nlead = len(lead)
@@ -454,7 +433,7 @@ def _fused_grid_call(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
             jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # relabel_sum
             jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # iters
         ],
-        interpret=True,
+        interpret=interpret,
     )(lab, cf, sink_cf, excess, nbr, rev_slot, intra, pushable, cross_lab,
       vmask, scal.reshape(lead + (1, 2)))
     cf, sink_cf, excess, lab, out_push, sinkp, rls, it = outs

@@ -211,3 +211,18 @@ def test_sharded_route_warm_recut():
     h.update(arcs=idx, cap_fwd=rng.randint(0, 301, size=6).astype(np.int32))
     res = h.solve(mesh=mesh)
     assert res.flow_value == solve_mincut(h.problem, part=part).flow_value
+
+
+def test_sharded_route_honours_max_sweeps():
+    """``SolverOptions.max_sweeps`` caps the sharded route as it caps the
+    host loop: the same unconverged state, bit for bit."""
+    p, part = _instance(seed=12)
+    opts = SolverOptions(max_sweeps=1, engine_max_iters=4)
+    mesh = jax.make_mesh((1,), ("regions",))
+    got = Solver(opts).prepare(p, part).solve(mesh=mesh)
+    want = Solver(opts).prepare(p, part).solve()
+    assert not want.converged and not got.converged
+    assert got.stats.sweeps == want.stats.sweeps == 1
+    for k in ("flow_to_t", "d", "excess", "cf"):
+        np.testing.assert_array_equal(np.asarray(getattr(got.state, k)),
+                                      np.asarray(getattr(want.state, k)))

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.core import SweepConfig, UnsupportedFeatureError, build
+from repro.core import SweepConfig, build
 from repro.core import grid_partition
 from repro.core.distributed import (flowstate_shardings, grid_like_meta,
                                     make_sharded_sweep, maxflow_input_specs)
@@ -88,16 +88,30 @@ def test_blocked_kernel_compiles(one_chip, value_dtype, mask_dtype):
     assert _has_kernel(compiled)
 
 
-def test_fused_kernel_refused_up_front(one_chip):
-    """Mosaic refuses the fused body (in-kernel gather, cumsum, scatter):
-    a compiled request raises the typed error before anything is traced."""
+def test_blocked_kernel_compiles_padded_rows(one_chip):
+    """A region whose row count is not a whole number of tiles (a 100^2
+    grid cut 3x3: 1,156 vertices) takes the kernel's row padding."""
+    s = lambda shape, dt=jnp.int32: _sds(one_chip, shape, dt)
+    V = 34 * 34
+    assert V % pr.DEFAULT_BLOCK_V
+    args = (s((V,)), s((V, E)), s((V,)), s((V,)), s((V, E)),
+            s((V, E), jnp.bool_), s((V, E), jnp.bool_), s((V, E)), s(()))
+    fn = lambda *a: pr.push_relabel_phase(*a, interpret=False, mode="both")
+    assert _has_kernel(jax.jit(fn).lower(*args).compile())
+
+
+def test_fused_kernel_refused_by_mosaic(one_chip):
+    """Mosaic refuses the fused body; its first refusal is the in-kernel
+    ``lab[nbr]`` gather.  (The engine therefore refuses the fused pallas
+    route up front on a TPU; should this lowering ever pass, that refusal
+    and this test are out of date.)"""
     s = lambda *shape: _sds(one_chip, shape, jnp.int32)
     K, V = 16, 4096
     args = (s(K, V), s(K, V, E), s(K, V), s(K, V), s(K, V, E), s(K, V, E),
             s(K, V, E), s(K, V, E), s(K, V, E), s(K, V), s(), s(K))
-    with pytest.raises(UnsupportedFeatureError,
-                       match="Only 2D gather is supported"):
-        pr.fused_engine_run_batched(*args, interpret=False)
+    fn = lambda *a: pr.fused_engine_run_batched(*a, interpret=False)
+    with pytest.raises(Exception, match="Only 2D gather is supported"):
+        jax.jit(fn).lower(*args).compile()
 
 
 def _grid_state(n, sharding):
