@@ -354,8 +354,9 @@ def _push_relabel_fused_batched(cf, sink_cf, excess, lab, *, nbr_local,
             interpret = jax.default_backend() != "tpu"
         if not interpret:
             # Mosaic refuses the fused body (in-kernel gather, cumsum and
-            # scatter-add; tests/test_tpu_compile.py): fail before tracing
-            # rather than let a compile error reach the degradation ladder
+            # scatter-add; tests/test_tpu_compile.py): refuse the route
+            # here, typed, rather than let the kernel's lowering error
+            # reach the degradation ladder
             raise UnsupportedFeatureError(
                 "TPU", "fused_pallas",
                 "Mosaic cannot lower its in-kernel gather, cumsum and "
