@@ -26,7 +26,9 @@ Compilation is keyed by ``(BatchMeta, SweepConfig)`` — the hashable
 fields of the frozen ``executor.BatchedExecutor`` that is the jit static
 of the generic device chunk — so any batch landing in a previously seen
 shape bucket reuses the executable with zero retracing (``trace_count()``
-exposes the retrace counter for benchmarks/tests).
+exposes the retrace counter to ``Solver.cache_info`` and the tests; the
+time a batch spends in each layer is on the ``maxflow.*`` spans of
+``core/spans.py``).
 
 Batched solving is intentionally scoped to the serving configuration:
 parallel sweeps (Alg. 2) with the optional global-gap / partial-discharge

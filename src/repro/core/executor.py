@@ -59,6 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans as _spans
+
 _I32 = jnp.int32
 
 
@@ -302,22 +304,25 @@ def run_device(ex: RegionExecutor, state, limit, host_sync_every,
     """
     if chunk is None:
         chunk = partial(_device_chunk, ex)
-    carry = ex.init_carry(state) if carry0 is None else carry0
-    syncs = 0
-    done = 0 if carry0 is None \
-        else ex.progress(jax.device_get(carry), limit)[0]
-    while True:
-        cap = limit if host_sync_every is None \
-            else np.minimum(limit, done + host_sync_every)
-        state, carry = chunk(state, carry, jnp.asarray(cap, _I32))
-        host = jax.device_get(carry)
-        syncs += 1
-        done, running = ex.progress(host, limit)
-        if on_sync is not None:
-            on_sync(state, host, syncs)
-        state = _fire_fault_hook("device", state, done)
-        if not running:
-            break
+    with _spans.span("maxflow.sweeps", route="device") as sp:
+        carry = ex.init_carry(state) if carry0 is None else carry0
+        syncs = 0
+        done = 0 if carry0 is None \
+            else ex.progress(jax.device_get(carry), limit)[0]
+        while True:
+            cap = limit if host_sync_every is None \
+                else np.minimum(limit, done + host_sync_every)
+            with _spans.span("maxflow.sync"):
+                state, carry = chunk(state, carry, jnp.asarray(cap, _I32))
+                host = jax.device_get(carry)
+            syncs += 1
+            done, running = ex.progress(host, limit)
+            if on_sync is not None:
+                on_sync(state, host, syncs)
+            state = _fire_fault_hook("device", state, done)
+            if not running:
+                break
+        sp.set(sweeps=done, host_syncs=syncs)
     return state, host, syncs
 
 
@@ -347,6 +352,14 @@ def run_host(ex: RegionExecutor, state, limit,
     """
     if sweep is None:
         sweep = ex.sweep_host
+    with _spans.span("maxflow.sweeps", route="host") as sp:
+        state, trace, active_pre, syncs, idx = _host_loop(
+            ex, state, limit, sweep, on_sweep, start, on_obs)
+        sp.set(sweeps=idx - start, host_syncs=syncs)
+    return state, trace, active_pre, syncs, idx
+
+
+def _host_loop(ex, state, limit, sweep, on_sweep, start, on_obs):
     trace: list[tuple] = []
     active_pre: list[int] = []
     syncs = 0
@@ -360,8 +373,9 @@ def run_host(ex: RegionExecutor, state, limit,
             active_pre.append(n_act)
             if n_act == 0:
                 break
-        state, obs = sweep(state, idx)
-        host_obs = tuple(int(x) for x in jax.device_get(obs))
+        with _spans.span("maxflow.sweep", index=idx):
+            state, obs = sweep(state, idx)
+            host_obs = tuple(int(x) for x in jax.device_get(obs))
         syncs += 1
         idx += 1
         trace.append(host_obs)

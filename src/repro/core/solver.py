@@ -63,6 +63,7 @@ from repro.core import invariants as _inv
 from repro.core import labels as _labels
 from repro.core import partition as _partition
 from repro.core import resilience as _res
+from repro.core import spans as _spans
 from repro.core import sweep as _sweep
 from repro.core.graph import (FlowState, GraphMeta, GraphUpdate, Layout,
                               Problem, _round_pow2)
@@ -226,22 +227,26 @@ def _finish(meta: GraphMeta, state0: FlowState, state: FlowState,
     ``AssertionError``, as the historical bare assert was) carrying the
     same diagnosis on ``.diagnosis``.
     """
-    sink_side = _sweep.extract_cut(meta, state)
-    flow = int(state.flow_to_t) - offset
-    diagnosis = None
-    if not converged:
-        diagnosis = _inv.diagnose(
-            meta, state, ard=ard, reason="max_sweeps", sweeps=stats.sweeps,
-            max_sweeps=max_sweeps, flow_value=flow)
-    elif check:
-        cost = int(_sweep.cut_value(meta, state0, sink_side))
-        if cost != flow:
-            raise _inv.CertificateError(
-                f"internal error: cut cost {cost} != max preflow {flow}",
-                _inv.diagnose(meta, state, ard=ard, reason="certificate",
-                              sweeps=stats.sweeps, max_sweeps=max_sweeps,
-                              flow_value=flow, cut_cost=cost))
-    source_flat = ~layout.to_flat(np.asarray(sink_side))
+    with _spans.span("maxflow.finish"):
+        with _spans.span("maxflow.extract_cut") as sp:
+            sink_side = _sweep.extract_cut(meta, state)
+            sp.wait(sink_side)
+        flow = int(state.flow_to_t) - offset
+        diagnosis = None
+        if not converged:
+            diagnosis = _inv.diagnose(
+                meta, state, ard=ard, reason="max_sweeps",
+                sweeps=stats.sweeps, max_sweeps=max_sweeps, flow_value=flow)
+        elif check:
+            with _spans.span("maxflow.certificate"):
+                cost = int(_sweep.cut_value(meta, state0, sink_side))
+            if cost != flow:
+                raise _inv.CertificateError(
+                    f"internal error: cut cost {cost} != max preflow {flow}",
+                    _inv.diagnose(meta, state, ard=ard, reason="certificate",
+                                  sweeps=stats.sweeps, max_sweeps=max_sweeps,
+                                  flow_value=flow, cut_cost=cost))
+        source_flat = ~layout.to_flat(np.asarray(sink_side))
     return MincutResult(flow_value=flow, source_side=source_flat,
                         stats=stats, meta=meta, state=state, layout=layout,
                         converged=converged, diagnosis=diagnosis)
@@ -345,6 +350,10 @@ class ProblemHandle:
         next ``solve``; ``flow_to_t`` (and the flow-offset bookkeeping)
         carry across updates.  Returns ``self`` for chaining.
         """
+        with _spans.span("maxflow.update"):
+            return self._update(cap_fwd, cap_bwd, excess, sink_cap, arcs)
+
+    def _update(self, cap_fwd, cap_bwd, excess, sink_cap, arcs):
         p = self.problem
         m, n = len(p.edges), p.num_vertices
         if arcs is not None:
@@ -372,17 +381,18 @@ class ProblemHandle:
         assert new_exc.shape == (n,) and new_snk.shape == (n,)
         newp = dataclasses.replace(p, cap_fwd=new_fwd, cap_bwd=new_bwd,
                                    excess=new_exc, sink_cap=new_snk)
-        if self.solver.options.check:
-            # reject negative / overflow-risk capacities before they land
-            # on device (opt-out: SolverOptions.check=False serving paths)
-            _graph.validate_problem(newp, context="update")
-        else:
-            assert (new_fwd >= 0).all() and (new_bwd >= 0).all()
-            assert (new_exc >= 0).all() and (new_snk >= 0).all()
-        # narrowed storage is sized by the flow-mass bound at prepare time;
-        # an update that grows total capacity past it would wrap int16
-        # residuals silently — always rejected, even with check=False
-        _graph.validate_update_dtypes(self.meta, newp)
+        with _spans.span("maxflow.validate"):
+            if self.solver.options.check:
+                # reject negative / overflow-risk capacities before they
+                # land on device (opt-out: SolverOptions.check=False)
+                _graph.validate_problem(newp, context="update")
+            else:
+                assert (new_fwd >= 0).all() and (new_bwd >= 0).all()
+                assert (new_exc >= 0).all() and (new_snk >= 0).all()
+            # narrowed storage is sized by the flow-mass bound at prepare
+            # time; an update that grows total capacity past it would wrap
+            # int16 residuals silently — always rejected, even unchecked
+            _graph.validate_update_dtypes(self.meta, newp)
 
         d_fwd = new_fwd.astype(np.int64) - p.cap_fwd
         d_bwd = new_bwd.astype(np.int64) - p.cap_bwd
@@ -408,8 +418,11 @@ class ProblemHandle:
             d_excess=_pad_i32(d_exc[tchanged], tp))
 
         before = self.solver._trace_total()
-        self.state, self.state0, grew, doff = _graph.apply_update(
-            self.state, self.state0, upd)
+        with _spans.span("maxflow.apply_update", arcs=len(changed),
+                         terminals=len(tchanged), bucket=max(j, tp)) as sp:
+            self.state, self.state0, grew, doff = _graph.apply_update(
+                self.state, self.state0, upd)
+            sp.wait(self.state, self.state0)
         self.solver._note(before)
         self._dirty = True
         self._grew = self._grew | grew
@@ -438,16 +451,24 @@ class ProblemHandle:
         decreases only remove residual arcs, so the kept labels remain
         valid lower bounds and the relabel fixpoint would be wasted work.
         """
-        if not self.warm:
-            return _graph.init_labels(self.meta, self.state)
-        mode = self.solver.options._labels_mode()
-        st = self.state
-        if mode == "reset":
-            return st.replace(d=jnp.zeros_like(st.d))
-        if mode == "auto" and self._dirty and bool(self._grew):
-            return _labels.global_relabel(
-                self.meta, st, self.solver.options.method == "ard")
-        return st                     # "keep", or labels provably valid
+        with _spans.span("maxflow.entry_state") as sp:
+            if not self.warm:
+                sp.set(labels="cold")
+                return _graph.init_labels(self.meta, self.state)
+            mode = self.solver.options._labels_mode()
+            st = self.state
+            if mode == "reset":
+                sp.set(labels="reset")
+                return st.replace(d=jnp.zeros_like(st.d))
+            if mode == "auto" and self._dirty and bool(self._grew):
+                sp.set(labels="relabel")
+                with _spans.span("maxflow.global_relabel") as rl:
+                    st = _labels.global_relabel(
+                        self.meta, st, self.solver.options.method == "ard")
+                    rl.wait(st.d)
+                return st
+            sp.set(labels="kept")
+            return st                 # "keep", or labels provably valid
 
     def _layout_salt(self) -> str:
         """Fingerprint salt binding checkpoints to THIS partition — two
@@ -486,6 +507,17 @@ class ProblemHandle:
         ``host_sync_every`` boundaries on the device-resident and sharded
         drivers) — the serving tier's deadline-enforcement point.
         """
+        opts = self.solver.options
+        route = ("streaming" if opts.streaming else
+                 "sharded" if mesh is not None else
+                 "device" if opts.device_resident else "host")
+        with _spans.span("maxflow.solve", route=route) as sp:
+            res = self._solve(mesh, axes, checkpoint, resume_from, on_sweep)
+            sp.set(sweeps=res.stats.sweeps,
+                   engine_iters=res.stats.engine_iters)
+            return res
+
+    def _solve(self, mesh, axes, checkpoint, resume_from, on_sweep):
         opts = self.solver.options
         cfg = opts.sweep_config()
         if opts.autotune:
@@ -628,20 +660,28 @@ class Solver:
         into ``options.num_regions`` regions (the paper's fallback
         partitioner, as before).
         """
-        if self.options.check or self.options.dtype_policy == "narrow":
-            # fail fast on malformed input (negative capacities, int32
-            # overflow risk vs INF_CAP) before any device work; serving
-            # paths opt out with SolverOptions.check=False — except the
-            # forced-narrow bound check, which must never be silent
-            _graph.validate_problem(problem, context="problem",
-                                    dtype_policy=self.options.dtype_policy)
-        if part is None:
-            part = _partition.block_partition(problem.num_vertices,
-                                              self.options.num_regions)
-        part = np.asarray(part)
-        meta, state, layout = _graph.build(
-            problem, part, dtype_policy=self.options.dtype_policy)
-        return ProblemHandle(self, problem, part, meta, state, layout)
+        with _spans.span("maxflow.prepare", n=problem.num_vertices,
+                         m=len(problem.edges)):
+            if self.options.check or self.options.dtype_policy == "narrow":
+                # fail fast on malformed input (negative capacities, int32
+                # overflow risk vs INF_CAP) before any device work; serving
+                # paths opt out with SolverOptions.check=False — except the
+                # forced-narrow bound check, which must never be silent
+                with _spans.span("maxflow.validate"):
+                    _graph.validate_problem(
+                        problem, context="problem",
+                        dtype_policy=self.options.dtype_policy)
+            if part is None:
+                part = _partition.block_partition(problem.num_vertices,
+                                                  self.options.num_regions)
+            part = np.asarray(part)
+            with _spans.span("maxflow.build") as sp:
+                meta, state, layout = _graph.build(
+                    problem, part, dtype_policy=self.options.dtype_policy)
+                sp.wait(state)
+                sp.set(K=meta.num_regions, V=meta.region_size,
+                       E=meta.max_degree)
+            return ProblemHandle(self, problem, part, meta, state, layout)
 
     def solve(self, problem: Problem, part: np.ndarray | None = None, *,
               mesh=None) -> MincutResult:
@@ -666,6 +706,13 @@ class Solver:
         bucket (one checkpoint stream per solve; re-pack the same items in
         the same order to resume).
         """
+        with _spans.span("maxflow.solve_many", B=len(items)) as sp:
+            results = self._solve_many(items, parts, checkpoint, resume_from)
+            sp.set(buckets=len(self.last_batch_stats),
+                   engine_iters=sum(r.stats.engine_iters for r in results))
+            return results
+
+    def _solve_many(self, items, parts, checkpoint, resume_from):
         cfg = self.options.sweep_config()
         if self.options.streaming:
             raise ValueError(
@@ -691,7 +738,9 @@ class Solver:
         before = self._trace_total()
         builds = [(i, h.meta, h._entry_state(), h.layout, h.state0)
                   for i, h in enumerate(handles)]
-        packs = _graph.pack_built(builds)
+        with _spans.span("maxflow.pack") as sp:
+            packs = _graph.pack_built(builds)
+            sp.wait([p.state for p in packs])
         if (checkpoint is not None or resume_from is not None) \
                 and len(packs) != 1:
             raise ValueError(

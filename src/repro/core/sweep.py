@@ -673,8 +673,10 @@ def extract_cut(meta: GraphMeta, state: FlowState) -> jax.Array:
     Global residual-reachability fixpoint — the paper's final labeling
     sweeps, collapsed into one exact computation.
     """
+    # a new jit per call (so a compile per call): named so that traces and
+    # compile logs tell it from the sweep programs
     @jax.jit
-    def run(state: FlowState):
+    def extract_cut_fixpoint(state: FlowState):
         def body(carry):
             reach, _ = carry
             nbr_reach = gather_at_nbr(reach, state.nbr_region,
@@ -689,7 +691,7 @@ def extract_cut(meta: GraphMeta, state: FlowState) -> jax.Array:
                                       (init, jnp.asarray(True)))
         return reach
 
-    return run(state)
+    return extract_cut_fixpoint(state)
 
 
 def cut_value(meta: GraphMeta, state0: FlowState, sink_side: jax.Array) -> jax.Array:

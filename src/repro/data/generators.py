@@ -114,9 +114,9 @@ def pipeline_levels(rows: int = 64, levels: int = 16, *, pipe_cap: int = 114,
     handful of passes, and the maxflow equals the injected supply
     (``supply * rows``) exactly.
 
-    This is the scaling instance of the out-of-core benchmark
-    (``benchmarks/bench_streaming.py``): solve cost grows linearly with
-    ``rows`` while sweep and engine-iteration counts stay flat — the
+    This is the scaling instance of the out-of-core route (resident
+    against streamed peak RSS, EXPERIMENTS.md): solve cost grows linearly
+    with ``rows`` while sweep and engine-iteration counts stay flat — the
     GENRMF/RLG families above stress the algorithm, this one stresses
     the memory system.  Edges are emitted in sorted ``(u, v)`` order, so
     a DIMACS round trip through ``read_dimacs`` (which sorts) and the
