@@ -593,15 +593,17 @@ class ProblemHandle:
         notes: list[str] = []
         st, stats = _res.run_with_degradation(run, cfg, notes)
         stats.degraded = notes + stats.degraded
-        self.solver._note(before)
         self.state = st
         self.warm = True
         self._dirty = False
         self._grew = jnp.zeros((), bool)
-        return _finish(self.meta, self.state0, st, self.layout, stats,
-                       opts.check, offset=int(self._flow_offset),
-                       converged=stats.converged, ard=opts.method == "ard",
-                       max_sweeps=cfg.max_sweeps)
+        res = _finish(self.meta, self.state0, st, self.layout, stats,
+                      opts.check, offset=int(self._flow_offset),
+                      converged=stats.converged, ard=opts.method == "ard",
+                      max_sweeps=cfg.max_sweeps)
+        # noted after _finish, so that a cut-extraction trace makes a miss
+        self.solver._note(before)
+        return res
 
 
 class Solver:
@@ -615,7 +617,7 @@ class Solver:
     return the same ``MincutResult`` shape and share the session's
     compiled programs — ``cache_info()`` reports hits/misses, where a miss
     is an invocation that actually traced a device program (sweep, batch,
-    sharded-sweep or update tracers combined).
+    sharded-sweep, update or cut-extraction tracers combined).
     """
 
     def __init__(self, options: SolverOptions | None = None, **overrides):
@@ -759,8 +761,6 @@ class Solver:
             bstate, bstats = _batch.solve_batch(
                 packed, cfg_b, checkpoint=checkpoint, resume_from=resume_from,
                 salt=salt)
-            self._note(before)
-            before = self._trace_total()
             self.last_batch_stats.append(bstats)
             for b, idx in enumerate(packed.indices):
                 h = handles[idx]
@@ -796,4 +796,7 @@ class Solver:
                     offset=int(h._flow_offset), converged=converged,
                     ard=self.options.method == "ard",
                     max_sweeps=cfg.max_sweeps)
+            # noted after the bucket's _finish calls, as in a single solve
+            self._note(before)
+            before = self._trace_total()
         return results

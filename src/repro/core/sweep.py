@@ -58,10 +58,10 @@ from repro.core.prd import prd_discharge_batched, prd_discharge_one
 
 _I32 = jnp.int32
 
-# bumped once per trace of a jitted sweep program (one-sweep bodies and the
-# device-resident multi-sweep driver) — the observable behind the session
-# front-end's ``Solver.cache_info``: a re-solve on a known shape must not
-# bump it.
+# bumped once per trace of a jitted program of this module (one-sweep
+# bodies, the device-resident multi-sweep driver, cut extraction) — the
+# observable behind the session front-end's ``Solver.cache_info``: a
+# re-solve on a known shape must not bump it.
 _TRACE_COUNT = 0
 
 
@@ -70,8 +70,8 @@ def trace_count() -> int:
 
 
 def _bump_trace() -> None:
-    """Called from inside traced code (the generic executor device chunk):
-    runs once per trace, never on cached invocations."""
+    """Called from inside traced code (the generic executor device chunk,
+    cut extraction): runs once per trace, never on cached invocations."""
     global _TRACE_COUNT
     _TRACE_COUNT += 1
 
@@ -667,31 +667,41 @@ def _solve_host(meta: GraphMeta, state: FlowState, cfg: SweepConfig, ex, *,
     return state, stats
 
 
+@jax.jit
+def extract_cut_fixpoint(cf: jax.Array, emask: jax.Array, sink_cf: jax.Array,
+                         vmask: jax.Array, nbr_region: jax.Array,
+                         nbr_local: jax.Array) -> jax.Array:
+    """The residual-reachability fixpoint behind :func:`extract_cut`.
+
+    Takes only the six arrays it reads, so its compile cache is keyed on
+    (K, V, E), dtypes and shardings: instances that differ only in their
+    cross-arc count share one executable.
+    """
+    _bump_trace()
+
+    def body(carry):
+        reach, _ = carry
+        nbr_reach = gather_at_nbr(reach, nbr_region, nbr_local)
+        ok = (cf > 0) & emask & nbr_reach
+        new = (sink_cf > 0) | ok.any(axis=2)
+        new = (new | reach) & vmask
+        return new, (new != reach).any()
+
+    init = (sink_cf > 0) & vmask
+    reach, _ = jax.lax.while_loop(lambda c: c[1], body,
+                                  (init, jnp.asarray(True)))
+    return reach
+
+
 def extract_cut(meta: GraphMeta, state: FlowState) -> jax.Array:
     """Minimum cut (bool[K,V]: True = sink side T = {v : v -> t in G_f}).
 
     Global residual-reachability fixpoint — the paper's final labeling
     sweeps, collapsed into one exact computation.
     """
-    # a new jit per call (so a compile per call): named so that traces and
-    # compile logs tell it from the sweep programs
-    @jax.jit
-    def extract_cut_fixpoint(state: FlowState):
-        def body(carry):
-            reach, _ = carry
-            nbr_reach = gather_at_nbr(reach, state.nbr_region,
-                                      state.nbr_local)
-            ok = (state.cf > 0) & state.emask & nbr_reach
-            new = (state.sink_cf > 0) | ok.any(axis=2)
-            new = (new | reach) & state.vmask
-            return new, (new != reach).any()
-
-        init = (state.sink_cf > 0) & state.vmask
-        reach, _ = jax.lax.while_loop(lambda c: c[1], body,
-                                      (init, jnp.asarray(True)))
-        return reach
-
-    return extract_cut_fixpoint(state)
+    return extract_cut_fixpoint(state.cf, state.emask, state.sink_cf,
+                                state.vmask, state.nbr_region,
+                                state.nbr_local)
 
 
 def cut_value(meta: GraphMeta, state0: FlowState, sink_side: jax.Array) -> jax.Array:
