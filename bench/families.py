@@ -1,8 +1,10 @@
-"""Instance families of the benchmark, made from a seed.
+"""Instance families of the benchmark, made from a seed, and the grid
+geometry they share.
 
-The generators are copies of the program's own (``repro.data.grids``), kept
-here so that a later change to the program cannot change what the benchmark
-feeds it.  An instance is a plain dict of numpy arrays:
+A family is one file, ``bench/generators/<family>.py``, found by the
+``family`` of a configuration file.  Its ``make(shape, rng, **params)``
+makes one instance on a grid of any number of dimensions; an instance is a
+plain dict of numpy arrays:
 
     n         number of vertices
     edges     int64[m, 2]  undirected pairs (u, v)
@@ -10,86 +12,67 @@ feeds it.  An instance is a plain dict of numpy arrays:
     cap_bwd   int32[m]     capacity v -> u
     excess    int32[n]     source t-link capacity
     sink_cap  int32[n]     sink t-link capacity
-    shape     (height, width) of the pixel grid
+    shape     the grid's extent per axis, vertices numbered row-major
 
-``FAMILIES`` maps the ``family`` of a configuration file to its generator.
+The generators are copies of the program's own (``repro.data.grids``), kept
+here so that a later change to the program cannot change what the benchmark
+feeds it.  The grid's dimension is the configuration's: one extent per entry
+of ``partition.splits``.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
+import itertools
+from pathlib import Path
+
 import numpy as np
 
-# Paper Sec. 7.1 displacement list; the first k/2 pairs give k-connectivity.
-_DISPLACEMENTS = [(0, 1), (1, 0), (1, 2), (2, 1), (1, 3), (3, 1), (2, 3),
-                  (3, 2), (0, 2), (2, 0), (2, 2), (3, 3), (3, 4), (4, 2)]
+BENCH = Path(__file__).resolve().parent
 
 
-def _grid_edges(height: int, width: int, offsets) -> np.ndarray:
-    vid = np.arange(height * width).reshape(height, width)
-    out = [np.stack([vid[:height - dy, :width - dx].reshape(-1),
-                     vid[dy:, dx:].reshape(-1)], axis=1)
-           for dy, dx in offsets]
+@functools.lru_cache(maxsize=None)
+def load_generator(family: str, bench: Path = BENCH):
+    """``bench/generators/<family>.py``'s ``make``."""
+    path = bench / "generators" / f"{family}.py"
+    spec = importlib.util.spec_from_file_location(
+        "bench_generator_" + family.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.make
+
+
+def make(config: dict, shape: tuple[int, ...], rng: np.random.RandomState,
+         bench: Path = BENCH) -> dict:
+    """One instance of ``config``'s family on a grid of ``shape``."""
+    return load_generator(config["family"], bench)(
+        tuple(int(s) for s in shape), rng, **config["params"])
+
+
+def grid_edges(shape: tuple[int, ...], offsets) -> np.ndarray:
+    """The undirected pairs (v, v + offset) of a ``shape`` grid that lie
+    inside it, offset by offset in the order given, each block row-major
+    in v."""
+    vid = np.arange(int(np.prod(shape))).reshape(shape)
+    out = []
+    for off in offsets:
+        src = tuple(slice(max(0, -o), n - max(0, o))
+                    for o, n in zip(off, shape))
+        dst = tuple(slice(max(0, o), n - max(0, -o))
+                    for o, n in zip(off, shape))
+        out.append(np.stack([vid[src].reshape(-1), vid[dst].reshape(-1)],
+                            axis=1))
     return np.concatenate(out, axis=0).astype(np.int64)
 
 
-def synthetic_grid(height: int, width: int, rng: np.random.RandomState, *,
-                   connectivity: int, strength: int, excess_mag: int) -> dict:
-    """Paper Sec. 7.1 synthetic 2-D problem: constant edge capacity
-    ``strength``; each vertex draws an integer in [-mag, mag], positive as
-    a source link, negative as a sink link."""
-    if connectivity % 2 or connectivity > 2 * len(_DISPLACEMENTS):
-        raise ValueError(f"unsupported connectivity {connectivity}")
-    n = height * width
-    edges = _grid_edges(height, width, _DISPLACEMENTS[:connectivity // 2])
-    cap = np.full(len(edges), strength, dtype=np.int32)
-    term = rng.randint(-excess_mag, excess_mag + 1, size=n)
-    return dict(n=n, edges=edges, cap_fwd=cap, cap_bwd=cap.copy(),
-                excess=np.where(term > 0, term, 0).astype(np.int32),
-                sink_cap=np.where(term < 0, -term, 0).astype(np.int32),
-                shape=(height, width))
-
-
-def segmentation_seeds_grid(height: int, width: int,
-                            rng: np.random.RandomState, *, smoothness: int,
-                            seed_strength: int) -> dict:
-    """Interactive segmentation (Boykov-Jolly scribbles): a 4-connected
-    grid with random contrast weights in [1, smoothness], a foreground
-    scribble (a disk of a ninth of the side at the centre) holding source
-    links and a background scribble (the 2-pixel border) holding sink
-    links, each of ``seed_strength`` plus noise in [0, 15)."""
-    n = height * width
-    yy, xx = np.mgrid[:height, :width]
-    cy, cx, r = height / 2, width / 2, min(height, width) / 3
-    fg = (yy - cy) ** 2 + (xx - cx) ** 2 < (r / 3) ** 2
-    bg = (yy < 2) | (yy >= height - 2) | (xx < 2) | (xx >= width - 2)
-    exc = np.where(fg & ~bg, seed_strength + rng.randint(0, 15, size=(
-        height, width)), 0)
-    snk = np.where(bg, seed_strength + rng.randint(0, 15, size=(
-        height, width)), 0)
-    edges = _grid_edges(height, width, [(0, 1), (1, 0)])
-    cap = rng.randint(1, smoothness + 1, size=len(edges)).astype(np.int32)
-    return dict(n=n, edges=edges, cap_fwd=cap, cap_bwd=cap.copy(),
-                excess=exc.reshape(-1).astype(np.int32),
-                sink_cap=snk.reshape(-1).astype(np.int32),
-                shape=(height, width))
-
-
-FAMILIES = {
-    "synthetic_grid": synthetic_grid,
-    "segmentation_seeds_grid": segmentation_seeds_grid,
-}
-
-
-def make(config: dict, height: int, width: int,
-         rng: np.random.RandomState) -> dict:
-    """One instance of ``config``'s family at ``height`` x ``width``."""
-    return FAMILIES[config["family"]](height, width, rng, **config["params"])
-
-
-def grid_partition(shape: tuple[int, int], splits: tuple[int, int]
+def grid_partition(shape: tuple[int, ...], splits: tuple[int, ...]
                    ) -> np.ndarray:
-    """Region id per vertex: the grid cut into splits[0] x splits[1]
+    """Region id per vertex: the grid cut into splits[0] x splits[1] x ...
     blocks of (nearly) equal extent, row-major (paper Sec. 5.3)."""
+    if len(shape) != len(splits):
+        raise ValueError(f"grid {shape} and splits {splits} differ in "
+                         f"dimension")
     idx = np.indices(shape)
     region = np.zeros(shape, dtype=np.int64)
     for d, (extent, s) in enumerate(zip(shape, splits)):
@@ -97,34 +80,64 @@ def grid_partition(shape: tuple[int, int], splits: tuple[int, int]
     return region.reshape(-1)
 
 
-def disk(shape: tuple[int, int], cy: int, cx: int, radius: int
+def partition(config: dict, inst: dict) -> np.ndarray:
+    """Region id per vertex of ``inst``, as ``config["partition"]`` says."""
+    part = config["partition"]
+    if part["kind"] != "grid":
+        raise SystemExit(f"partition kind {part['kind']!r} is not served: "
+                         f"the benchmark partitions grids only ('grid')")
+    splits = tuple(part["splits"])
+    if len(splits) != len(inst["shape"]):
+        raise SystemExit(f"partition splits {list(splits)} do not fit the "
+                         f"{len(inst['shape'])}-D grid {inst['shape']}")
+    return grid_partition(inst["shape"], splits)
+
+
+def ball(shape: tuple[int, ...], centre: tuple[int, ...], radius: int
          ) -> np.ndarray:
-    """Vertex ids of the pixels within ``radius`` of (cy, cx)."""
-    yy, xx = np.mgrid[:shape[0], :shape[1]]
-    inside = (yy - cy) ** 2 + (xx - cx) ** 2 <= radius * radius
-    return np.flatnonzero(inside.reshape(-1))
+    """Vertex ids of the grid points within ``radius`` of ``centre``."""
+    idx = np.indices(shape)
+    dist2 = sum((idx[d] - c) ** 2 for d, c in enumerate(centre))
+    return np.flatnonzero((dist2 <= radius * radius).reshape(-1))
 
 
-# The eight symmetries of the square, as maps from the old grid of vertex
-# ids to the new one: new[y, x] = old vertex id at that position.
-_SYMMETRIES = (
-    lambda v: v, lambda v: v.T, lambda v: v[::-1, ::-1],
-    lambda v: v[::-1, ::-1].T, lambda v: v[::-1, :], lambda v: v[:, ::-1],
-    lambda v: v.T[::-1, :], lambda v: v.T[:, ::-1])
+# A symmetry of the grid is a signed axis permutation (perm, flips): the
+# new grid of vertex ids is np.flip(np.transpose(old, perm), flips), so
+# new[position] = the old vertex id at that position.  The eight of the
+# square keep the order every 2-D presentation has been drawn in (as
+# lambdas: v, v.T, v[::-1, ::-1], v[::-1, ::-1].T, v[::-1, :], v[:, ::-1],
+# v.T[::-1, :], v.T[:, ::-1]); changing it changes what each seed presents.
+_SQUARE = (((0, 1), ()), ((1, 0), ()), ((0, 1), (0, 1)), ((1, 0), (0, 1)),
+           ((0, 1), (0,)), ((0, 1), (1,)), ((1, 0), (0,)), ((1, 0), (1,)))
 
 
-def _symmetry_map(shape: tuple[int, int], k: int) -> np.ndarray:
-    return _SYMMETRIES[k](np.arange(shape[0] * shape[1]).reshape(shape))
+@functools.lru_cache(maxsize=None)
+def signed_permutations(d: int) -> tuple:
+    """The 2^d * d! symmetries of a d-dimensional grid, in a fixed order.
+    For d = 2, ``_SQUARE``.  Otherwise symmetry k = 2^d * p + mask: the
+    p-th permutation of ``itertools.permutations(range(d))``, then a flip
+    of axis a of the permuted grid for each bit a set in ``mask``."""
+    if d == 2:
+        return _SQUARE
+    return tuple((perm, tuple(a for a in range(d) if mask >> a & 1))
+                 for perm in itertools.permutations(range(d))
+                 for mask in range(2 ** d))
 
 
-def symmetries(shape: tuple[int, int], splits: tuple[int, int]
+def _symmetry_map(shape: tuple[int, ...], k: int) -> np.ndarray:
+    perm, flips = signed_permutations(len(shape))[k]
+    old = np.arange(int(np.prod(shape))).reshape(shape)
+    return np.flip(np.transpose(old, perm), flips)
+
+
+def symmetries(shape: tuple[int, ...], splits: tuple[int, ...]
                ) -> list[int]:
-    """The symmetries of the square that map the grid partition of
-    ``shape`` onto the grid partition of the image's shape, region for
-    region: under those an instance is the same problem, relabelled."""
+    """The symmetries of the grid that map the grid partition of ``shape``
+    onto the grid partition of the image's shape, region for region: under
+    those an instance is the same problem, relabelled."""
     old = grid_partition(shape, splits)
     out = []
-    for k in range(len(_SYMMETRIES)):
+    for k in range(len(signed_permutations(len(shape)))):
         m = _symmetry_map(shape, k)
         new = grid_partition(m.shape, splits)
         pairs = set(zip(new.tolist(), old[m.reshape(-1)].tolist()))
@@ -146,7 +159,7 @@ def transform(inst: dict, k: int) -> dict:
                 sink_cap=sink_cap, shape=m.shape)
 
 
-def moved_vertex(shape: tuple[int, int], k: int, v: int) -> int:
+def moved_vertex(shape: tuple[int, ...], k: int, v: int) -> int:
     """The id that vertex ``v`` of a ``shape`` grid takes under ``k``."""
     return int(np.flatnonzero(_symmetry_map(shape, k).reshape(-1) == v)[0])
 

@@ -8,9 +8,11 @@ configuration and the run's seed, then driven by ``bench/run.py``:
     loop.request(i)           # one timed request -> Request
     loop.expected()           # (answer, reference instance) pairs to check
 
-Every window cycles the same problems in the same order, made from
-``_BASE_SEED``; the run's seed presents each of them under a symmetry of the
-square that maps the partition onto itself (``families.symmetries``): the
+Every loop serves a grid of the configuration's dimension, one axis per
+entry of ``partition.splits``: a ``side`` of the traffic is the extent of
+every axis.  Every window cycles the same problems in the same order, made
+from ``_BASE_SEED``; the run's seed presents each of them under a symmetry
+of the grid that maps the partition onto itself (``families.symmetries``): the
 vertices are renumbered, edges and terminals move with them, and the
 problem, its maximum flow and the solver's count of sweeps and engine
 iterations stay the same.  So every seed does the same work on inputs of its
@@ -21,6 +23,7 @@ are checked too.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 
 import numpy as np
@@ -63,17 +66,21 @@ class _Loop:
         self.config = ctx.config
         self.traffic = ctx.traffic
         self.splits = tuple(self.config["partition"]["splits"])
+        self.dims = len(self.splits)
         self.instances: dict = {}       # key -> instance dict, for checks
         self.warm_answers: list = []    # answers produced during set-up
         self._symmetries: dict = {}     # shape -> symmetries it allows
 
-    def _instance(self, key, height, width, rng):
-        inst = families.make(self.config, height, width, rng)
+    def _make(self, shape, rng):
+        return families.make(self.config, shape, rng, self.ctx.bench)
+
+    def _instance(self, key, shape, rng):
+        inst = self._make(shape, rng)
         self.instances[key] = inst
         return inst
 
     def _part(self, inst):
-        return families.grid_partition(inst["shape"], self.splits)
+        return families.partition(self.config, inst)
 
     def _present(self, key, inst, i):
         """Problem ``key`` as the run presents it at draw ``i``: under a
@@ -109,12 +116,12 @@ class Cold(_Loop):
 
     def __init__(self, ctx):
         super().__init__(ctx)
-        side, pool = self.traffic["side"], self.traffic["pool"]
-        self.pool = [families.make(self.config, side, side, families.rng_for(
-            _BASE_SEED, _POOL, i)) for i in range(pool)]
+        shape = (self.traffic["side"],) * self.dims
+        self.pool = [self._make(shape, families.rng_for(_BASE_SEED, _POOL, i))
+                     for i in range(self.traffic["pool"])]
         for j in range(self.traffic.get("warmup", 1)):
             key = ("warm", j)
-            inst = self._instance(key, side, side, families.rng_for(
+            inst = self._instance(key, shape, families.rng_for(
                 ctx.seed, _WARM, j))
             self.warm_answers.append(self._cut(inst, key).answers[0])
 
@@ -141,7 +148,7 @@ class Cold(_Loop):
 
 class Recut(_Loop):
     """One interactive session: an image of side ``side`` prepared and
-    cut in set-up, then each request is one brush stroke (a disk of
+    cut in set-up, then each request is one brush stroke (a ball of
     ``brush_radius`` pixels, labelled opposite to the session's cut at its
     centre) through ``handle.update(excess=, sink_cap=)`` and
     ``handle.solve()``.  A stroke first restores the previous stroke's
@@ -150,9 +157,9 @@ class Recut(_Loop):
 
     def __init__(self, ctx):
         super().__init__(ctx)
-        side = self.traffic["side"]
-        image = families.make(self.config, side, side, families.rng_for(
-            _BASE_SEED, _POOL, 0))
+        image = self._make((self.traffic["side"],) * self.dims,
+                           families.rng_for(_BASE_SEED, _POOL, 0))
+        self.image_shape = image["shape"]
         (*_, sym), base = self._present(("base",), image, 0)
         self.base = base
         self.handle = ctx.solver.prepare(to_problem(base), self._part(base))
@@ -171,38 +178,45 @@ class Recut(_Loop):
     def _strokes(self, rng, count, sym=0):
         """(centre, painted excess, painted sink_cap, edited instance); the
         centres are drawn on the image as made and moved by ``sym``."""
-        h, w = self.base["shape"]
+        shape = self.image_shape
         r = self.traffic["brush_radius"]
         strength = self.config["params"]["seed_strength"]
         out = []
         for _ in range(count):
-            cy, cx = int(rng.randint(h)), int(rng.randint(w))
-            cy, cx = divmod(families.moved_vertex((h, w), sym, cy * w + cx), w)
-            pix = families.disk((h, w), cy, cx, r)
+            centre = tuple(int(rng.randint(n)) for n in shape)
+            v = families.moved_vertex(shape, sym, int(np.ravel_multi_index(
+                centre, shape)))
+            centre = tuple(int(c) for c in np.unravel_index(
+                v, self.base["shape"]))
+            pix = families.ball(self.base["shape"], centre, r)
             exc, snk = self.base["excess"].copy(), self.base["sink_cap"].copy()
-            if self.base_source[cy * w + cx]:   # on the object: paint background
+            if self.base_source[v]:     # on the object: paint background
                 exc[pix], snk[pix] = 0, strength
-            else:                               # on the background: object
+            else:                       # on the background: object
                 exc[pix], snk[pix] = strength, 0
-            out.append(((cy, cx), exc, snk, dict(self.base, excess=exc,
-                                                 sink_cap=snk)))
+            out.append((centre, exc, snk, dict(self.base, excess=exc,
+                                               sink_cap=snk)))
         return out
 
     def _warm_buckets(self):
         """Compile the update program for every size bucket a stroke can
-        land in, on a second handle of the same image."""
+        land in, on a second handle of the same image.  Each update raises
+        sink capacities, so each solve also runs the global relabel that a
+        stroke's solve runs: set-up builds it whatever the warm-up strokes
+        draw."""
         jax = self.ctx.jax
         scratch = self.ctx.solver.prepare(to_problem(self.base),
                                           self._part(self.base))
         scratch.solve()
         r = self.traffic["brush_radius"]
-        most = 2 * len(families.disk((4 * r + 2, 4 * r + 2), 2 * r, 2 * r, r))
+        most = 2 * len(families.ball((4 * r + 2,) * self.dims,
+                                     (2 * r,) * self.dims, r))
         b = 1
         while b < 2 * most:
             # exactly b terminal entries differ from the handle's problem
-            exc = self.base["excess"].copy()
-            exc[:b] = scratch.problem.excess[:b] + 1
-            scratch.update(excess=exc)
+            snk = self.base["sink_cap"].copy()
+            snk[:b] = scratch.problem.sink_cap[:b] + 1
+            scratch.update(sink_cap=snk)
             scratch.solve()
             b *= 2
         _sync(jax, scratch)
@@ -231,8 +245,9 @@ class Recut(_Loop):
 
 class Fleet(_Loop):
     """Closed loop of ``Solver.solve_many`` calls, each on ``batch``
-    instances whose height and width are drawn from ``sides`` (inclusive),
-    a range that packs into one shape bucket (checked here)."""
+    instances whose extent along each axis is drawn from ``sides``
+    (inclusive), a range that packs into one shape bucket (checked
+    here)."""
 
     def __init__(self, ctx):
         super().__init__(ctx)
@@ -251,8 +266,9 @@ class Fleet(_Loop):
         lo, hi = self.traffic["sides"]
         out = []
         for b in range(self.traffic["batch"]):
-            h, w = (int(x) for x in rng.randint(lo, hi + 1, size=2))
-            out.append((key + (b,), families.make(self.config, h, w, rng)))
+            shape = tuple(int(x) for x in rng.randint(lo, hi + 1,
+                                                      size=self.dims))
+            out.append((key + (b,), self._make(shape, rng)))
         return out
 
     def _check_one_bucket(self):
@@ -260,13 +276,11 @@ class Fleet(_Loop):
 
         lo, hi = self.traffic["sides"]
         shapes = {}
-        for h in range(lo, hi + 1):
-            for w in range(lo, hi + 1):
-                inst = families.make(self.config, h, w,
-                                     np.random.RandomState(0))
-                handle = self.ctx.solver.prepare(to_problem(inst),
-                                                 self._part(inst))
-                shapes[(h, w)] = bucket_shape_for(handle.meta)
+        for shape in itertools.product(range(lo, hi + 1), repeat=self.dims):
+            inst = self._make(shape, np.random.RandomState(0))
+            handle = self.ctx.solver.prepare(to_problem(inst),
+                                             self._part(inst))
+            shapes[shape] = bucket_shape_for(handle.meta)
         if len(set(shapes.values())) != 1:
             raise SystemExit(f"fleet sides {lo}..{hi} pack into more than "
                              f"one shape bucket: {shapes}")

@@ -12,11 +12,15 @@ the window and reports the cell's per-layer metrics instead of its
 end-to-end ones.
 
 Everything is found by name: the configuration in
-``bench/configs/<config>.json``, the traffic in
-``bench/traffic/<traffic>.json`` (whose ``loop`` names a load loop of
-``bench/loops.py``), and each metric's reader in
-``bench/metrics/<metric>.py``.  Without a TPU, or with fewer chips than the
-cell asks for, it exits non-zero before measuring.
+``bench/configs/<config>.json`` (whose ``family`` names its generator,
+``bench/generators/<family>.py``, and whose ``partition.splits`` set the
+grid's dimension), the traffic in ``bench/traffic/<traffic>.json`` (whose
+``loop`` names a load loop of ``bench/loops.py``), and each metric's reader
+in ``bench/metrics/<metric>.py``.  In a traced run the program's own spans
+(``repro.core.spans``, where the program has them) are recorded over the
+window and handed to the readers as ``Run.program_spans``.  Without a TPU,
+or with fewer chips than the cell asks for, it exits non-zero before
+measuring.
 """
 
 from __future__ import annotations
@@ -146,7 +150,8 @@ class CompileCounter:
 @dataclasses.dataclass
 class Context:
     """What a load loop gets: the configuration, traffic, seed, the
-    session ``Solver`` and the span recorder."""
+    session ``Solver``, the span recorder and the checkout's ``bench``
+    directory, where the generators are found."""
 
     jax: object
     config: dict
@@ -154,6 +159,7 @@ class Context:
     seed: int
     solver: object
     spans: Spans
+    bench: Path = BENCH
 
 
 @dataclasses.dataclass
@@ -171,12 +177,13 @@ class Run:
     peaks: object                   # peaks.ChipPeaks of the device
     trace: dict | None = None       # trace.reduce() of the traced slice
     traced: list = dataclasses.field(default_factory=list)  # its requests
+    # repro.core.spans records inside the window, in a traced run
+    program_spans: list = dataclasses.field(default_factory=list)
 
     def part(self, inst):
         from bench import families
 
-        return families.grid_partition(
-            inst["shape"], tuple(self.config["partition"]["splits"]))
+        return families.partition(self.config, inst)
 
 
 def _process_start() -> float:
@@ -266,11 +273,11 @@ class Tracer:
             self.state = "done"
             self.spans.annotate = False
 
-    def reduce(self) -> dict:
+    def reduce(self, span_names) -> dict:
         from bench import trace
 
         try:
-            return trace.reduce(trace.find_xplane(self.dir), SPAN_NAMES)
+            return trace.reduce(trace.find_xplane(self.dir), span_names)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -322,6 +329,10 @@ def run(argv=None, *, accept_devices=is_tpu, root: Path = ROOT,
     sys.path.insert(0, str(ROOT / "src"))      # the program under test
     from bench import loops, peaks
     from repro.core import Solver, SolverOptions
+    try:
+        from repro.core import spans as program_spans
+    except ImportError:             # a program without spans records none
+        program_spans = None
 
     dev = devices[0]
     chip_peaks = peaks.peaks_for(dev.device_kind) \
@@ -329,7 +340,7 @@ def run(argv=None, *, accept_devices=is_tpu, root: Path = ROOT,
     counter = CompileCounter(jax)
     spans = Spans()
     solver = Solver(SolverOptions(**config["solver"]))
-    ctx = Context(jax, config, traffic, args.seed, solver, spans)
+    ctx = Context(jax, config, traffic, args.seed, solver, spans, bench)
     loop = loops.LOOPS[traffic["loop"]](ctx)
     setup_s = time.time() - _process_start()
 
@@ -337,32 +348,39 @@ def run(argv=None, *, accept_devices=is_tpu, root: Path = ROOT,
     traced: list = []
     misses0 = solver.cache_info().misses
     tracer = Tracer(jax, spans) if args.trace else None
+    recording = bool(tracer and program_spans)
     _close_cache(jax)
-    counter.on = True
-    t0 = time.perf_counter()
-    try:
-        while not requests or time.perf_counter() - t0 < args.seconds:
-            if tracer and tracer.wants(time.perf_counter() - t0,
-                                       args.seconds, traced):
-                traced.append(_request(loop, len(requests), tracer, err))
-                requests.append(traced[-1])
-            else:
-                tracer and tracer.stop()
-                requests.append(_request(loop, len(requests), None, err))
-    finally:
-        counter.on = False
-        tracer and tracer.stop()
-    if tracer and not traced:       # no request started inside the slice:
-        tracer.state = "before"     # trace one more, after the window
-        tracer.wants(args.seconds, args.seconds, traced)
-        traced.append(_request(loop, len(requests), tracer, err))
-        tracer.stop()
+    with (program_spans.recording() if recording
+          else contextlib.nullcontext([])) as records:
+        counter.on = True
+        t0 = time.perf_counter()
+        try:
+            while not requests or time.perf_counter() - t0 < args.seconds:
+                if tracer and tracer.wants(time.perf_counter() - t0,
+                                           args.seconds, traced):
+                    traced.append(_request(loop, len(requests), tracer, err))
+                    requests.append(traced[-1])
+                else:
+                    tracer and tracer.stop()
+                    requests.append(_request(loop, len(requests), None, err))
+        finally:
+            counter.on = False
+            tracer and tracer.stop()
+        if tracer and not traced:   # no request started inside the slice:
+            tracer.state = "before"  # trace one more, after the window
+            tracer.wants(args.seconds, args.seconds, traced)
+            traced.append(_request(loop, len(requests), tracer, err))
+            tracer.stop()
     window_s = requests[-1].t1 - t0
     cache_misses = solver.cache_info().misses - misses0
     stats = [d.memory_stats() or {} for d in devices[:cell["chips"]]]
     peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
 
-    trace = tracer.reduce() if tracer else None
+    lo, hi = requests[0].t0, requests[-1].t1
+    in_window = [r for r in records
+                 if r.start_ns * 1e-9 >= lo and r.end_ns * 1e-9 <= hi]
+    trace = tracer.reduce(SPAN_NAMES + tuple(sorted(
+        {r.name for r in records}))) if tracer else None
 
     warm_answers, instances = loop.expected()
     del loop, solver, ctx
@@ -378,7 +396,8 @@ def run(argv=None, *, accept_devices=is_tpu, root: Path = ROOT,
     correct = all(checks[k] <= limits[k] for k in checks)
 
     record = Run(cell["name"], config, setup_s, window_s, requests,
-                 compiles, cache_misses, instances, chip_peaks, trace, traced)
+                 compiles, cache_misses, instances, chip_peaks, trace, traced,
+                 in_window)
     values = {}
     for m, read in metrics:
         v = read(record)
@@ -396,6 +415,8 @@ def run(argv=None, *, accept_devices=is_tpu, root: Path = ROOT,
     print(json.dumps({"cuts_checked": len(answers),
                       "window_requests": len(requests),
                       "compiles_in_window": counter.compiles,
+                      "program_spans": program_spans.summary(in_window)
+                      if recording else None,
                       "cache_loads_in_window": counter.cache_loads,
                       "moved_share": _moved_share(requests),
                       "trace_lines": trace and trace["lines"]}), file=err)

@@ -48,3 +48,86 @@ def test_gap_named_by_the_innermost_span():
     assert trace._host_activity(spans, 99.5) == "solve"
     assert trace._host_activity(spans, 150) == "between"
 
+
+
+def _span(name, ms, compile_ms=0.0):
+    from repro.core.spans import Span
+
+    return Span(name=name, id=0, parent=None, root=0, attrs={}, start_ns=0,
+                end_ns=int(ms * 1e6), compile_s=compile_ms / 1e3)
+
+
+def _fake_run(spans=(), trace=None):
+    from types import SimpleNamespace
+
+    reqs = [SimpleNamespace(cuts=2, engine_iters=[100, 300]),
+            SimpleNamespace(cuts=2, engine_iters=[200, 400])]
+    return SimpleNamespace(requests=reqs, traced=reqs[1:],
+                           program_spans=list(spans), trace=trace)
+
+
+_SPANS = [_span("maxflow.solve", 50.0, compile_ms=3.0),
+          _span("maxflow.extract_cut", 2.0), _span("maxflow.extract_cut", 6.0),
+          _span("maxflow.certificate", 4.0),
+          _span("maxflow.global_relabel", 10.0, compile_ms=1.0),
+          _span("maxflow.finish", 12.0)]
+_TRACE = dict(span_busy_s={"maxflow.sweeps": 0.03},
+              span_s={"maxflow.sweeps": 0.04})
+
+
+@pytest.mark.parametrize("metric, want", [
+    ("extract_ms.cut", 8.0 / 4),
+    ("certificate_ms.cut", 4.0 / 4),
+    ("compile_ms.cut", 4.0 / 4),
+    ("relabel_ms.cut", 10.0 / 4),
+    ("finish_ms.fleet", 12.0 / 4),
+    ("sweeps_us_per_iter.cut", 0.03e6 / 600),
+    ("sweep_idle_pct.cut", 25.0),
+])
+def test_span_reader_arithmetic(metric, want):
+    """Each reader of the program's spans, on a run of 4 cuts whose traced
+    requests hold 600 engine iterations; a run that recorded nothing
+    reads nothing."""
+    from bench.run import load_metric
+
+    read = load_metric(metric)
+    assert read(_fake_run(_SPANS, _TRACE)) == pytest.approx(want)
+    assert read(_fake_run()) is None
+
+
+def _program_span_metrics(cell):
+    import json
+
+    from bench.tests.helpers import ROOT
+
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    return {m["name"] for m in spec["per_layer"]
+            if m["source"] == "program_span" and cell in m["workloads"]}
+
+
+@pytest.mark.parametrize("cell", ["seg2d-seeds.recut", "synth2d-8c.fleet",
+                                  "seg2d-seeds.cold"])
+def test_traced_run_hands_the_program_spans_to_readers(tmp_path, cell):
+    """Traced, the window's spans reach every reader of them and the stderr
+    summary; untraced, nothing is recorded and recording is left off."""
+    import json
+
+    from repro.core import spans
+
+    from bench.tests.helpers import run_cell, tiny_checkout
+
+    root = tiny_checkout(tmp_path)
+    rc, line, err = run_cell(root, cell, trace=1)
+    assert rc == 0 and line["correct"] is True, err
+    want = _program_span_metrics(cell)
+    assert want and want <= set(line["metrics"])
+    info = json.loads(next(ln for ln in err.splitlines()
+                           if ln.startswith('{"cuts_checked"')))
+    root_span = "maxflow.solve_many" if "fleet" in cell else "maxflow.solve"
+    assert info["program_spans"][root_span]["count"] >= 1
+    rc, line, err = run_cell(root, cell, trace=0)
+    assert rc == 0 and line["correct"] is True, err
+    info = json.loads(next(ln for ln in err.splitlines()
+                           if ln.startswith('{"cuts_checked"')))
+    assert info["program_spans"] is None
+    assert not spans._recording

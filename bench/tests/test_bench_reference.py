@@ -57,7 +57,7 @@ def test_cut_is_the_smallest_sink_side():
 @pytest.mark.parametrize("config", ["synth2d-8c", "seg2d-seeds"])
 @pytest.mark.parametrize("seed", [0, 2**31 + 7, 4294967311])
 def test_agrees_with_networkx(config, seed):
-    inst = families.make(_config(config), 9, 11, families.rng_for(seed, 1))
+    inst = families.make(_config(config), (9, 11), families.rng_for(seed, 1))
     flow, source = min_cut(inst)
     assert flow == _networkx_flow(inst)
     # the source side's cut costs exactly the flow
@@ -75,7 +75,7 @@ def test_quantized_control_differs(config):
     families the benchmark runs."""
     differ = 0
     for seed in range(4):
-        inst = families.make(_config(config), 16, 16,
+        inst = families.make(_config(config), (16, 16),
                              families.rng_for(seed, 1))
         differ += min_cut_quantized(inst)[0] != min_cut(inst)[0]
     assert differ >= 3

@@ -22,7 +22,7 @@ def test_bytes_independent_of_the_handle_dtype():
     from repro.core import Solver, SolverOptions
 
     cfg = _config("seg2d-seeds")
-    inst = families.make(cfg, 8, 8, families.rng_for(3, 1))
+    inst = families.make(cfg, (8, 8), families.rng_for(3, 1))
     part = families.grid_partition((8, 8), (2, 2))
     metas = [Solver(SolverOptions(num_regions=4, dtype_policy=p)).prepare(
         to_problem(inst), part).meta for p in ("int32", "auto")]
@@ -61,9 +61,8 @@ def test_fleet_sides_pack_into_one_bucket():
     shapes = set()
     for h in range(lo, hi + 1):
         for w in range(lo, hi + 1):
-            inst = families.make(cfg, h, w, families.rng_for(h * 100 + w))
-            part = families.grid_partition((h, w), tuple(
-                cfg["partition"]["splits"]))
+            inst = families.make(cfg, (h, w), families.rng_for(h * 100 + w))
+            part = families.partition(cfg, inst)
             shapes.add(bucket_shape_for(solver.prepare(
                 to_problem(inst), part).meta))
     assert len(shapes) == 1, shapes
