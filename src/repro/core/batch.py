@@ -77,8 +77,9 @@ def _bump_trace() -> None:
 class BatchStats:
     """Per-batch solve accounting (host side, after the final sync).
 
-    ``sweeps``/``engine_iters`` are per-instance i32[B] (bit-equal to the
-    standalone drivers); ``engine_launches`` and ``host_syncs`` are global
+    ``sweeps``/``engine_iters``/``ard_stages`` are per-instance i32[B]
+    (bit-equal to the standalone drivers; ``ard_stages`` is ``None`` for
+    ``method="prd"``); ``engine_launches`` and ``host_syncs`` are global
     to the batch — the whole point of batching is that the batch shares
     one launch/sync stream, so a per-instance split would be fiction.
     Per-instance ``SweepStats`` derived from this record are marked
@@ -88,6 +89,7 @@ class BatchStats:
 
     sweeps: np.ndarray
     engine_iters: np.ndarray
+    ard_stages: np.ndarray | None = None
     engine_launches: int = 0
     host_syncs: int = 0
     converged: np.ndarray | None = None   # bool[B]: instance reached zero
@@ -166,8 +168,8 @@ def _parallel_sweep_batch(bmeta: BatchMeta, cfg: SweepConfig,
     instances whose result the driver will keep — frozen instances get
     their ARD stage schedule emptied (cap -2 admits not even the sink
     stage) so they never add stage-loop trips to the shared launch stream.
-    Returns ``(state, engine_iters [B], engine_launches scalar)`` —
-    launches are global to the batch.
+    Returns ``(state, engine_iters [B], ard_stages [B], engine_launches
+    scalar)`` — launches are global to the batch.
     """
     B, K = bmeta.num_instances, bmeta.num_regions
     V, E = bmeta.region_size, bmeta.max_degree
@@ -213,7 +215,8 @@ def _parallel_sweep_batch(bmeta: BatchMeta, cfg: SweepConfig,
     if cfg.use_global_gap:
         new = _global_gap_batch(new, d_inf, ard)
     iters = res.engine_iters.reshape(B, K).sum(1)
-    return new, iters, res.engine_launches
+    stages = res.stages.reshape(B, K).sum(1)
+    return new, iters, stages, res.engine_launches
 
 
 def solve_batch(packed: PackedBatch, cfg: SweepConfig | None = None, *,
@@ -264,6 +267,7 @@ def solve_batch(packed: PackedBatch, cfg: SweepConfig | None = None, *,
         seed_syncs = int(ckpt.stats.get("host_syncs", 0))
         carry0 = (jnp.asarray(ckpt.payload["sweeps"], _I32),
                   jnp.asarray(ckpt.payload["engine_iters"], _I32),
+                  jnp.asarray(ckpt.payload["ard_stages"], _I32),
                   jnp.asarray(int(ckpt.stats["engine_launches"]), _I32),
                   jnp.asarray(ckpt.payload["n_act"], _I32))
 
@@ -275,10 +279,11 @@ def solve_batch(packed: PackedBatch, cfg: SweepConfig | None = None, *,
             done, running = ex.progress(host, limit)
             if running and done - last_saved[0] < checkpoint.every:
                 return
-            sweeps, iters, launches, n_act = host
+            sweeps, iters, stages, launches, n_act = host
             payload = _res.state_payload(st)
             payload["sweeps"] = np.asarray(sweeps, np.int32)
             payload["engine_iters"] = np.asarray(iters, np.int32)
+            payload["ard_stages"] = np.asarray(stages, np.int32)
             payload["n_act"] = np.asarray(n_act, np.int32)
             _res.save_checkpoint(checkpoint.directory, _res.SolveCheckpoint(
                 fingerprint=fp, route="batch", sweeps=done, payload=payload,
@@ -290,12 +295,14 @@ def solve_batch(packed: PackedBatch, cfg: SweepConfig | None = None, *,
     state, host, syncs = _executor.run_device(
         ex, state, limit, cfg.host_sync_every, carry0=carry0,
         on_sync=on_sync)
-    sweeps, iters, launches, n_act = host
+    sweeps, iters, stages, launches, n_act = host
     note = _res.vmem_fallback_note(cfg, bmeta.region_size, bmeta.max_degree,
                                    dtypes=bmeta.kernel_dtypes)
     return state, BatchStats(
         sweeps=np.asarray(sweeps, np.int64),
         engine_iters=np.asarray(iters, np.int64),
+        ard_stages=np.asarray(stages, np.int64)
+        if cfg.method == "ard" else None,
         engine_launches=int(launches), host_syncs=seed_syncs + syncs,
         converged=np.asarray(n_act) == 0,
         degraded=[] if note is None else [note])

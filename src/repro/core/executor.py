@@ -277,11 +277,12 @@ def _slot_swap(ex: "BatchedExecutor", state, carry, slot, inst):
     ex.note_trace()
     state = jax.tree_util.tree_map(
         lambda dst, src: dst.at[slot].set(src[0]), state, inst)
-    sweeps, iters, launches, _ = carry
+    sweeps, iters, stages, launches, _ = carry
     zero = jnp.zeros((), _I32)
     sweeps = sweeps.at[slot].set(zero)
     iters = iters.at[slot].set(zero)
-    return state, (sweeps, iters, launches, ex.num_active(state))
+    stages = stages.at[slot].set(zero)
+    return state, (sweeps, iters, stages, launches, ex.num_active(state))
 
 
 def run_device(ex: RegionExecutor, state, limit, host_sync_every,
@@ -406,8 +407,8 @@ class LocalExecutor(RegionExecutor):
     """Single-instance solve on the local device (``core.sweep``).
 
     Carry layout (the device-resident statistics mirror): ``(sweep_idx,
-    engine_iters, engine_launches, regions_discharged, flow_ring [R],
-    active_ring [R], n_active)``.
+    engine_iters, ard_stages, engine_launches, regions_discharged,
+    flow_ring [R], active_ring [R], n_active)``.
     """
 
     meta: Any
@@ -431,22 +432,25 @@ class LocalExecutor(RegionExecutor):
     def init_carry(self, state) -> tuple:
         z = jnp.zeros((), _I32)
         ring = jnp.zeros((self.cfg.stats_ring_size,), _I32)
-        return (z, z, z, z, ring, ring, self.num_active(state).astype(_I32))
+        return (z, z, z, z, z, ring, ring,
+                self.num_active(state).astype(_I32))
 
     def one_sweep(self, state, carry, limit):
         sw = self._sweep_mod()
         meta, cfg = self.meta, self.cfg
-        idx, it, ln, dc, fr, ar, n_act = carry
+        idx, it, sg, ln, dc, fr, ar, n_act = carry
         R = cfg.stats_ring_size
         ar = ar.at[idx % R].set(n_act)
         if cfg.parallel:
-            state, dit, dln = sw.parallel_sweep(meta, state, cfg, idx)
+            state, dit, dsg, dln = sw.parallel_sweep(meta, state, cfg, idx)
             ddc = _I32(meta.num_regions)
         else:
-            state, dit, dln, ddc = sw.sequential_sweep(meta, state, cfg, idx)
+            state, dit, dsg, dln, ddc = sw.sequential_sweep(
+                meta, state, cfg, idx)
         n_act = self.num_active(state).astype(_I32)
         fr = fr.at[idx % R].set(state.flow_to_t)
-        return state, (idx + 1, it + dit, ln + dln, dc + ddc, fr, ar, n_act)
+        return state, (idx + 1, it + dit, sg + dsg, ln + dln, dc + ddc, fr,
+                       ar, n_act)
 
     def keep_running(self, state, carry, limit):
         idx, n_act = carry[0], carry[-1]
@@ -461,14 +465,14 @@ class LocalExecutor(RegionExecutor):
         meta, cfg = self.meta, self.cfg
         sweep_idx = jnp.asarray(idx, _I32)
         if cfg.parallel:
-            state, iters, launches = sw.parallel_sweep(
+            state, iters, stages, launches = sw.parallel_sweep(
                 meta, state, cfg, sweep_idx)
             disc = _I32(meta.num_regions)
         else:
-            state, iters, launches, disc = sw.sequential_sweep(
+            state, iters, stages, launches, disc = sw.sequential_sweep(
                 meta, state, cfg, sweep_idx)
-        obs = (self.num_active(state), state.flow_to_t, iters, launches,
-               disc)
+        obs = (self.num_active(state), state.flow_to_t, iters, stages,
+               launches, disc)
         return state, obs
 
 
@@ -476,11 +480,11 @@ class LocalExecutor(RegionExecutor):
 class BatchedExecutor(RegionExecutor):
     """Multi-instance solve over a leading instance axis (``core.batch``).
 
-    Carry layout: ``(sweeps [B], engine_iters [B], engine_launches,
-    n_active [B])`` — per-instance convergence flags live in the loop
-    (``run = (sweeps < limit) & (n_act > 0)``), so a converged instance is
-    frozen by selects and costs the engine's O(1) early exit inside the
-    shared launch.  Device-resident only: the whole point of the batch is
+    Carry layout: ``(sweeps [B], engine_iters [B], ard_stages [B],
+    engine_launches, n_active [B])`` — per-instance convergence flags live
+    in the loop (``run = (sweeps < limit) & (n_act > 0)``), so a converged
+    instance is frozen by selects and costs the engine's O(1) early exit
+    inside the shared launch.  Device-resident only: the whole point of the batch is
     sharing one launch/sync stream, which a per-sweep host loop would
     forfeit.
     """
@@ -510,15 +514,15 @@ class BatchedExecutor(RegionExecutor):
 
     def init_carry(self, state) -> tuple:
         zb = jnp.zeros((self.bmeta.num_instances,), _I32)
-        return (zb, zb, jnp.zeros((), _I32), self.num_active(state))
+        return (zb, zb, zb, jnp.zeros((), _I32), self.num_active(state))
 
     def one_sweep(self, state, carry, limit):
         bt = self._batch_mod()
-        sweeps, it, ln, n_act = carry
+        sweeps, it, sg, ln, n_act = carry
         run = (sweeps < limit) & (n_act > 0)                    # [B]
         st_in = state.replace(
             excess=jnp.where(run[:, None, None], state.excess, 0))
-        new, dit, dln = bt._parallel_sweep_batch(
+        new, dit, dsg, dln = bt._parallel_sweep_batch(
             self.bmeta, self.cfg, st_in, sweeps, run)
         w3 = run[:, None, None, None]
         w2 = run[:, None, None]
@@ -530,7 +534,8 @@ class BatchedExecutor(RegionExecutor):
             flow_to_t=jnp.where(run, new.flow_to_t, state.flow_to_t))
         n_act = self.num_active(state)
         return state, (sweeps + run.astype(_I32),
-                       it + jnp.where(run, dit, 0), ln + dln, n_act)
+                       it + jnp.where(run, dit, 0),
+                       sg + jnp.where(run, dsg, 0), ln + dln, n_act)
 
     def keep_running(self, state, carry, limit):
         sweeps, n_act = carry[0], carry[-1]

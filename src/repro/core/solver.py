@@ -514,7 +514,8 @@ class ProblemHandle:
         with _spans.span("maxflow.solve", route=route) as sp:
             res = self._solve(mesh, axes, checkpoint, resume_from, on_sweep)
             sp.set(sweeps=res.stats.sweeps,
-                   engine_iters=res.stats.engine_iters)
+                   engine_iters=res.stats.engine_iters,
+                   ard_stages=res.stats.ard_stages)
             return res
 
     def _solve(self, mesh, axes, checkpoint, resume_from, on_sweep):
@@ -580,7 +581,8 @@ class ProblemHandle:
                 st = _narrow_state(st, self.meta)
                 _pb, msg_bytes = _sweep._page_and_msg_bytes(self.meta)
                 stats = _sweep.SweepStats(
-                    sweeps=sweeps, engine_iters=None, engine_launches=None,
+                    sweeps=sweeps, engine_iters=None, ard_stages=None,
+                    engine_launches=None,
                     host_syncs=syncs, boundary_bytes=sweeps * msg_bytes,
                     page_bytes=None, num_boundary=self.meta.num_boundary,
                     regions_discharged=None,
@@ -780,6 +782,8 @@ class Solver:
                 stats = _sweep.SweepStats(
                     sweeps=sweeps,
                     engine_iters=int(bstats.engine_iters[b]),
+                    ard_stages=None if bstats.ard_stages is None
+                    else int(bstats.ard_stages[b]),
                     engine_launches=bstats.engine_launches,
                     host_syncs=bstats.host_syncs,
                     boundary_bytes=sweeps * msg_bytes,

@@ -141,14 +141,18 @@ class SweepStats:
     every field is about this one solve; ``"batch"`` — the result came out
     of a batched multi-instance solve, so ``engine_launches``/``host_syncs``
     are GLOBAL to the whole batch that shared the launch/sync stream (the
-    per-instance split would be fiction), while ``sweeps``/``engine_iters``
-    and the byte counters remain exact per-instance values.  Fields typed
-    ``int | None`` are ``None`` on routes that cannot observe them (the
-    sharded driver does not count engine dispatches).
+    per-instance split would be fiction), while ``sweeps``/``engine_iters``/
+    ``ard_stages`` and the byte counters remain exact per-instance values.
+    Fields typed ``int | None`` are ``None`` on routes that cannot observe
+    them (the sharded driver counts neither engine dispatches nor stages;
+    ``ard_stages`` is ``None`` for ``method="prd"``, which has no stages).
     """
 
     sweeps: int = 0
     engine_iters: int | None = 0
+    ard_stages: int | None = 0   # ARD stages run (one engine run per
+    #                              distinct ghost label, ``ard.py``), summed
+    #                              over sweeps and regions
     engine_launches: int | None = 0   # compute-program dispatches (2/iter
     #                              unfused; fused: 1/chunk-trip pallas —
     #                              batched over all regions of a parallel
@@ -181,8 +185,8 @@ class SweepStats:
     #                              fallbacks) — never silent
 
 
-_STAT_KEYS = ("sweeps", "engine_iters", "engine_launches", "host_syncs",
-              "boundary_bytes", "page_bytes", "num_boundary",
+_STAT_KEYS = ("sweeps", "engine_iters", "ard_stages", "engine_launches",
+              "host_syncs", "boundary_bytes", "page_bytes", "num_boundary",
               "staged_in_bytes", "staged_out_bytes", "regions_discharged",
               "flow_curve", "active_curve", "converged", "degraded")
 
@@ -278,7 +282,8 @@ def parallel_sweep(meta: GraphMeta, state: FlowState, cfg: SweepConfig,
         new = heuristics.boundary_relabel(meta, new)
     if cfg.use_global_gap:
         new = global_gap(meta, new, ard=cfg.method == "ard")
-    return new, res.engine_iters.sum(), res.engine_launches.sum()
+    return (new, res.engine_iters.sum(), res.stages.sum(),
+            res.engine_launches.sum())
 
 
 @partial(jax.jit, static_argnums=(0, 2))
@@ -303,7 +308,7 @@ def sequential_sweep(meta: GraphMeta, state: FlowState, cfg: SweepConfig,
     intra = intra_mask(state)
 
     def body(k, carry):
-        state, iters, launches, discharged = carry
+        state, iters, stages, launches, discharged = carry
         sl = lambda a: jax.lax.dynamic_index_in_dim(a, k, 0, keepdims=False)
         # ghost labels only for the arcs of region k (a [V,E] gather) — the
         # other K-1 regions' ghosts are never read by this discharge, so
@@ -347,22 +352,22 @@ def sequential_sweep(meta: GraphMeta, state: FlowState, cfg: SweepConfig,
             st = _apply_cross_flow(st, out_push, accept=mine)
             if cfg.use_global_gap:
                 st = global_gap(meta, st, ard=cfg.method == "ard")
-            return st, res.engine_iters, res.engine_launches
+            return st, res.engine_iters, res.stages, res.engine_launches
 
         def skip(state):
-            return state, jnp.zeros((), _I32), jnp.zeros((), _I32)
+            z = jnp.zeros((), _I32)
+            return state, z, z, z
 
-        state, it, ln = jax.lax.cond(active, run, skip, state)
-        return (state, iters + it, launches + ln,
+        state, it, sg, ln = jax.lax.cond(active, run, skip, state)
+        return (state, iters + it, stages + sg, launches + ln,
                 discharged + active.astype(_I32))
 
-    state, iters, launches, discharged = jax.lax.fori_loop(
-        0, K, body,
-        (state, jnp.zeros((), _I32), jnp.zeros((), _I32),
-         jnp.zeros((), _I32)))
+    z = jnp.zeros((), _I32)
+    state, iters, stages, launches, discharged = jax.lax.fori_loop(
+        0, K, body, (state, z, z, z, z))
     if cfg.use_boundary_relabel and cfg.method == "ard":
         state = heuristics.boundary_relabel(meta, state)
-    return state, iters, launches, discharged
+    return state, iters, stages, launches, discharged
 
 
 def num_active(meta: GraphMeta, state: FlowState, cfg: SweepConfig) -> jax.Array:
@@ -403,12 +408,13 @@ def _device_stats(host, syncs, max_sweeps, R, page_bytes, msg_bytes,
     is complete without seed accumulation; only ``host_syncs`` counts per
     incarnation and needs the checkpoint's total added.
     """
-    idx, it, ln, dc, fr, ar, n_act = host
+    idx, it, sg, ln, dc, fr, ar, n_act = host
     stats = SweepStats()
     done = int(idx)
     stats.host_syncs = seed_syncs + syncs
     stats.sweeps = done
     stats.engine_iters = int(it)
+    stats.ard_stages = int(sg)
     stats.engine_launches = int(ln)
     stats.regions_discharged = int(dc)
     stats.page_bytes = int(dc) * page_bytes
@@ -472,6 +478,7 @@ def _solve_device_resident(meta: GraphMeta, state: FlowState,
             ar[j % R] = active_curve[j - first]
         carry0 = (jnp.asarray(done0, _I32),
                   jnp.asarray(seed.engine_iters, _I32),
+                  jnp.asarray(seed.ard_stages, _I32),
                   jnp.asarray(seed.engine_launches, _I32),
                   jnp.asarray(seed.regions_discharged, _I32),
                   jnp.asarray(fr), jnp.asarray(ar),
@@ -594,6 +601,8 @@ def solve(meta: GraphMeta, state: FlowState, cfg: SweepConfig | None = None,
     if note is not None and note not in stats.degraded:
         stats.degraded.append(note)
     stats.num_boundary = meta.num_boundary
+    if cfg.method != "ard":
+        stats.ard_stages = None
     return state, stats
 
 
@@ -623,8 +632,9 @@ def _solve_host(meta: GraphMeta, state: FlowState, cfg: SweepConfig, ex, *,
         stats.active_curve = stats.active_curve + active_pre
         stats.flow_curve = list(stats.flow_curve)
         stats.degraded = list(stats.degraded)
-        for n_act, flow, it, ln, dc in trace:
+        for n_act, flow, it, sg, ln, dc in trace:
             stats.engine_iters += it
+            stats.ard_stages += sg
             stats.engine_launches += ln
             stats.regions_discharged += dc
             stats.page_bytes += dc * page_bytes

@@ -9,6 +9,8 @@ Sec. 7.1: (0,1),(1,0) -> 4-connected, first 8 -> 8-connected, etc.
 
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 
 from repro.core.graph import Problem
@@ -113,6 +115,69 @@ def segmentation_seeds_grid(height: int, width: int, *, seed: int = 0,
                    cap_bwd=cap.copy(),
                    excess=exc2d.reshape(-1).astype(np.int32),
                    sink_cap=snk2d.reshape(-1).astype(np.int32))
+
+
+def volume_offsets(d: int, neighbours: str) -> list[tuple[int, ...]]:
+    """One offset of each +-pair of a d-dimensional grid neighbourhood: the
+    unit axes for ``"faces"`` (6-connected in 3-D), every nonzero step in
+    {-1, 0, 1}^d whose first nonzero entry is 1 for ``"all"`` (26-connected
+    in 3-D)."""
+    if neighbours == "faces":
+        return [tuple(int(a == b) for b in range(d)) for a in range(d)]
+    if neighbours == "all":
+        return [o for o in itertools.product((-1, 0, 1), repeat=d)
+                if any(o) and o[next(i for i, x in enumerate(o) if x)] == 1]
+    raise ValueError(f"unknown neighbours {neighbours!r}: 'faces' or 'all'")
+
+
+def grid_edges(shape: tuple[int, ...], offsets) -> np.ndarray:
+    """The undirected pairs (v, v + offset) of a row-major ``shape`` grid
+    that lie inside it, offset by offset in the order given, each block
+    row-major in v."""
+    vid = np.arange(int(np.prod(shape))).reshape(shape)
+    out = []
+    for off in offsets:
+        src = tuple(slice(max(0, -o), n - max(0, o))
+                    for o, n in zip(off, shape))
+        dst = tuple(slice(max(0, o), n - max(0, -o))
+                    for o, n in zip(off, shape))
+        out.append(np.stack([vid[src].reshape(-1), vid[dst].reshape(-1)],
+                            axis=1))
+    return np.concatenate(out, axis=0).astype(np.int64)
+
+
+def segmentation_seeds_volume(shape: tuple[int, ...], *,
+                              neighbours: str = "all", smoothness: int = 20,
+                              seed_strength: int = 200,
+                              seed: int = 0) -> Problem:
+    """Seeded segmentation of a volume of any dimension (Boykov &
+    Funka-Lea, Graph Cuts and Efficient N-D Image Segmentation, IJCV 2006;
+    the ``*.n6c``/``*.n26c`` volumes of the UWO vision max-flow benchmark).
+
+    The N-D form of ``segmentation_seeds_grid``: neighbours are the
+    ``"faces"`` (6-connected in 3-D) or ``"all"`` (26-connected) grid
+    points one step away, each pair with one contrast weight uniform in
+    [1, smoothness] on both arcs; a ball of radius min(shape)/9 at the
+    centre holds source links, the 2-voxel border sink links, each of
+    ``seed_strength`` plus noise in [0, 15).  Vertices are numbered
+    row-major.
+    """
+    shape = tuple(int(s) for s in shape)
+    rng = np.random.RandomState(seed)
+    idx = np.indices(shape)
+    r = min(shape) / 9
+    fg = sum((idx[d] - n / 2) ** 2 for d, n in enumerate(shape)) < r * r
+    bg = np.zeros(shape, dtype=bool)
+    for d, n in enumerate(shape):
+        bg |= (idx[d] < 2) | (idx[d] >= n - 2)
+    exc = np.where(fg & ~bg, seed_strength + rng.randint(0, 15, size=shape), 0)
+    snk = np.where(bg, seed_strength + rng.randint(0, 15, size=shape), 0)
+    edges = grid_edges(shape, volume_offsets(len(shape), neighbours))
+    cap = rng.randint(1, smoothness + 1, size=len(edges)).astype(np.int32)
+    return Problem(num_vertices=int(np.prod(shape)), edges=edges,
+                   cap_fwd=cap, cap_bwd=cap.copy(),
+                   excess=exc.reshape(-1).astype(np.int32),
+                   sink_cap=snk.reshape(-1).astype(np.int32))
 
 
 def random_sparse(n: int, m: int, *, cap_mag: int = 100, term_mag: int = 50,

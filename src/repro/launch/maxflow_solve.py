@@ -20,6 +20,14 @@ Each HxW entry becomes one synthetic instance (seeds --seed, --seed+1,
 ``.max`` files (see repro.data.dimacs) can be mixed in by path:
 ``--batch instance.max,64x64``.
 
+Volume mode cuts a seeded 3-D segmentation volume (Boykov & Funka-Lea;
+``repro.data.grids.segmentation_seeds_volume``) under an AxBxC grid of
+regions, 6- or 26-connected; a DxHxW entry of ``--batch`` is one such
+volume:
+
+    PYTHONPATH=src python -m repro.launch.maxflow_solve \
+        --volume 16x16x16 --neighbours 26 --regions 4x2x2
+
 Warm-start serving mode re-solves the prepared instance N times through
 ONE ``Solver`` session, perturbing a P-fraction of the edge capacities
 before each re-solve (``handle.update`` reparameterizes the residual
@@ -63,7 +71,16 @@ def main():
     ap.add_argument("--width", type=int, default=64)
     ap.add_argument("--connectivity", type=int, default=8)
     ap.add_argument("--strength", type=int, default=150)
-    ap.add_argument("--regions", default="2x2")
+    ap.add_argument("--regions", default=None,
+                    help="grid of regions, one extent per axis: AxB for a "
+                         "2-D grid, AxBxC for a volume (default 2x2, or "
+                         "2x2x2 with --volume)")
+    ap.add_argument("--volume", default=None, metavar="DxHxW",
+                    help="volume mode: cut a seeded segmentation volume "
+                         "(segmentation_seeds_volume) of this extent in "
+                         "place of the 2-D synthetic grid")
+    ap.add_argument("--neighbours", type=int, choices=[6, 26], default=26,
+                    help="volume mode: 6 (faces) or 26 (all) neighbours")
     ap.add_argument("--method", choices=["ard", "prd"], default="ard")
     ap.add_argument("--sequential", action="store_true")
     ap.add_argument("--sharded", action="store_true")
@@ -104,7 +121,8 @@ def main():
                          "every M sweeps (default: only at convergence)")
     ap.add_argument("--batch", default=None, metavar="SPEC[,SPEC...]",
                     help="batched throughput mode: comma-separated instance "
-                         "specs (HxW synthetic grid or a DIMACS .max path) "
+                         "specs (HxW synthetic grid, DxHxW segmentation "
+                         "volume or a DIMACS .max path) "
                          "solved together through solve_mincut_batch — one "
                          "shape-bucketed grid=(B,K) device program per "
                          "bucket, compiled solve cached per bucket shape")
@@ -152,11 +170,23 @@ def main():
     import jax.numpy as jnp
 
     from repro.core import SweepConfig, grid_partition
-    from repro.data.grids import synthetic_grid
+    from repro.data.grids import segmentation_seeds_volume, synthetic_grid
     from repro.launch.cache import enable_compile_cache
 
     enable_compile_cache()
-    ry, rx = (int(v) for v in args.regions.split("x"))
+    neighbours = {6: "faces", 26: "all"}[args.neighbours]
+    regions = tuple(int(v) for v in (
+        args.regions or ("2x2x2" if args.volume else "2x2")).split("x"))
+    num_regions = int(np.prod(regions))
+
+    def volume(spec, seed):
+        shape = tuple(int(v) for v in spec.split("x"))
+        if len(shape) != len(regions):
+            ap.error(f"volume {spec} and --regions "
+                     f"{'x'.join(map(str, regions))} differ in dimension")
+        return (segmentation_seeds_volume(shape, neighbours=neighbours,
+                                          seed=seed),
+                grid_partition(shape, regions))
     if args.streaming:
         if args.sharded:
             ap.error("--streaming and --sharded are mutually exclusive "
@@ -203,22 +233,27 @@ def main():
         probs, parts = [], []
         for i, spec in enumerate(args.batch.split(",")):
             grid = re.fullmatch(r"(\d+)x(\d+)", spec)
+            vol = re.fullmatch(r"\d+x\d+x\d+", spec)
             if grid and not Path(spec).exists():   # a file named HxW wins
                 h, w = int(grid[1]), int(grid[2])
                 probs.append(synthetic_grid(
                     h, w, connectivity=args.connectivity,
                     strength=args.strength, seed=args.seed + i))
-                parts.append(grid_partition((h, w), (ry, rx)))
+                parts.append(grid_partition((h, w), regions))
+            elif vol and not Path(spec).exists():
+                prob, part = volume(spec, args.seed + i)
+                probs.append(prob)
+                parts.append(part)
             elif Path(spec).is_file():
                 probs.append(read_dimacs(spec))
                 parts.append(None)     # node-number fallback partitioner
             else:
-                ap.error(f"--batch spec {spec!r} is neither HxW nor an "
-                         "existing DIMACS file")
+                ap.error(f"--batch spec {spec!r} is neither HxW, DxHxW nor "
+                         "an existing DIMACS file")
         from repro.core import Solver, SolverOptions
 
         solver = Solver(SolverOptions.from_sweep_config(
-            cfg, num_regions=ry * rx, check=not args.no_check,
+            cfg, num_regions=num_regions, check=not args.no_check,
             dtype_policy=args.dtype_policy, autotune=args.autotune))
         t0 = time.time()
         results = solver.solve_many(probs, parts)
@@ -226,7 +261,8 @@ def main():
         for i, res in enumerate(results):
             print(f"[maxflow]   instance {i}: flow={res.flow_value} "
                   f"sweeps={res.stats.sweeps} "
-                  f"engine_iters={res.stats.engine_iters}")
+                  f"engine_iters={res.stats.engine_iters} "
+                  f"ard_stages={res.stats.ard_stages}")
         launches = sum(bs.engine_launches for bs in solver.last_batch_stats)
         syncs = sum(bs.host_syncs for bs in solver.last_batch_stats)
         print(f"[maxflow] batch of {len(results)} ({args.method}, "
@@ -236,17 +272,20 @@ def main():
               f"({len(results) / max(dt, 1e-9):.1f} instances/s)")
         return
 
-    prob = synthetic_grid(args.height, args.width,
-                          connectivity=args.connectivity,
-                          strength=args.strength, seed=args.seed)
-    part = grid_partition((args.height, args.width), (ry, rx))
+    if args.volume:
+        prob, part = volume(args.volume, args.seed)
+    else:
+        prob = synthetic_grid(args.height, args.width,
+                              connectivity=args.connectivity,
+                              strength=args.strength, seed=args.seed)
+        part = grid_partition((args.height, args.width), regions)
 
     # one Solver session for the cold solve and every warm re-solve: the
     # build/Layout and every compiled program are reused across the loop
     from repro.core import Solver, SolverOptions
 
     solver = Solver(SolverOptions.from_sweep_config(
-        cfg, num_regions=ry * rx, check=not args.no_check,
+        cfg, num_regions=num_regions, check=not args.no_check,
         dtype_policy=args.dtype_policy, autotune=args.autotune,
         streaming=args.streaming,
         max_resident_regions=args.max_resident_regions,
@@ -271,6 +310,8 @@ def main():
     print(f"[maxflow] {args.method} parallel={cfg.parallel} {route} "
           f"dtypes={kd.label}/{kd.flow}/{kd.mask}: "
           f"flow={res.flow_value} sweeps={res.stats.sweeps} "
+          f"engine_iters={res.stats.engine_iters} "
+          f"ard_stages={res.stats.ard_stages} "
           f"launches={res.stats.engine_launches} "
           f"host_syncs={res.stats.host_syncs} "
           f"boundary_bytes={res.stats.boundary_bytes} "

@@ -490,14 +490,14 @@ class MaxflowService:
         bucket.syncs += 1
         # np.array (not asarray): device_get buffers are read-only and
         # sweeps_host is written on swap-in
-        sweeps, iters, launches, n_act = (np.array(x) for x in host)
+        sweeps, iters, stages, launches, n_act = (np.array(x) for x in host)
         now = self._clock()
         for b, slot in enumerate(bucket.slots):
             if slot is None:
                 continue
             if n_act[b] == 0 or sweeps[b] >= bucket.limits[b]:
-                self._harvest(bucket, b, sweeps, iters, int(launches),
-                              n_act)
+                self._harvest(bucket, b, sweeps, iters, stages,
+                              int(launches), n_act)
             elif slot.ticket.deadline_at is not None \
                     and now >= slot.ticket.deadline_at:
                 self._expire_slot(bucket, b, sweeps, now)
@@ -509,7 +509,7 @@ class MaxflowService:
         bucket.slots[b] = None
         bucket.limits[b] = 0   # run flag off until the next swap-in
 
-    def _harvest(self, bucket: _Bucket, b: int, sweeps, iters,
+    def _harvest(self, bucket: _Bucket, b: int, sweeps, iters, stages,
                  launches: int, n_act) -> None:
         """Unpack slot ``b`` into a ``MincutResult`` (the ``solve_many``
         unpacking, per slot) and leave the session handle warm."""
@@ -527,6 +527,7 @@ class MaxflowService:
         page_bytes, msg_bytes = _sweep._page_and_msg_bytes(meta)
         stats = _sweep.SweepStats(
             sweeps=sw, engine_iters=int(iters[b]),
+            ard_stages=int(stages[b]) if self._cfg.method == "ard" else None,
             engine_launches=launches, host_syncs=bucket.syncs,
             boundary_bytes=sw * msg_bytes,
             page_bytes=sw * meta.num_regions * page_bytes,

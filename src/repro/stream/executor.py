@@ -78,7 +78,7 @@ def _make_discharge(meta, cfg):
         else:
             res = prd_discharge_one(cf, sink_cf, excess, d, ghost, **kw)
         return (res.cf, res.sink_cf, res.excess, jnp.maximum(d, res.d),
-                res.out_push, res.sink_pushed, res.engine_iters,
+                res.out_push, res.sink_pushed, res.engine_iters, res.stages,
                 res.engine_launches)
 
     return jax.jit(fn)
@@ -160,9 +160,9 @@ def _next_active(ss: StreamState, k: int) -> int | None:
 def stream_sweep(ss: StreamState, idx) -> tuple[StreamState, tuple]:
     """One full sweep over staged regions; the ``sweep_host`` body of
     ``StreamingExecutor``.  Returns ``(ss, obs)`` with obs =
-    ``(n_active, flow_to_t, engine_iters, engine_launches,
+    ``(n_active, flow_to_t, engine_iters, ard_stages, engine_launches,
     regions_discharged, staged_in_delta, staged_out_delta)`` — the first
-    five exactly the resident host loop's observation tuple.
+    six exactly the resident host loop's observation tuple.
     """
     import jax
 
@@ -170,7 +170,7 @@ def stream_sweep(ss: StreamState, idx) -> tuple[StreamState, tuple]:
     d_inf = ss.d_inf
     in0 = ss.store.staged_in_bytes
     out0 = ss.store.staged_out_bytes
-    iters = launches = discharged = 0
+    iters = stages = launches = discharged = 0
     sweep_idx = int(idx)
     stage_cap = np.int32(max(sweep_idx - 1, -1)) if cfg.partial_discharge \
         else np.int32(meta.d_inf_ard)
@@ -193,10 +193,11 @@ def stream_sweep(ss: StreamState, idx) -> tuple[StreamState, tuple]:
                             flow["d"], ghost, stage_cap,
                             topo["nbr_local"], topo["rev_slot"], intra,
                             topo["emask"], topo["vmask"])
-        (cf, sink_cf, excess, d, out_push, sink_pushed, it, ln) = (
+        (cf, sink_cf, excess, d, out_push, sink_pushed, it, sg, ln) = (
             np.asarray(a) for a in jax.device_get(out))
         bnd.flow_to_t += int(sink_pushed)
         iters += int(it)
+        stages += int(sg)
         launches += int(ln)
         discharged += 1
         if len(ox):
@@ -208,7 +209,7 @@ def stream_sweep(ss: StreamState, idx) -> tuple[StreamState, tuple]:
                           topo["vmask"], d_inf)
         ss.store.writeback(k, new_flow)
 
-    obs = (bnd.num_active(d_inf), bnd.flow_to_t, iters, launches,
+    obs = (bnd.num_active(d_inf), bnd.flow_to_t, iters, stages, launches,
            discharged, ss.store.staged_in_bytes - in0,
            ss.store.staged_out_bytes - out0)
     return ss, obs
@@ -272,7 +273,7 @@ def assemble_state(ss: StreamState, state):
 
 
 # --------------------------------------------------------------------------
-# the solve driver (mirrors sweep._solve_host, 7-tuple observations)
+# the solve driver (mirrors sweep._solve_host, 8-tuple observations)
 # --------------------------------------------------------------------------
 
 def solve_stream(ss: StreamState, *, on_sweep=None, checkpoint=None,
@@ -316,8 +317,9 @@ def solve_stream(ss: StreamState, *, on_sweep=None, checkpoint=None,
         stats.active_curve = stats.active_curve + active_pre
         stats.flow_curve = list(stats.flow_curve)
         stats.degraded = list(stats.degraded)
-        for n_act, flow, it, ln, dc, sin, sout in trace:
+        for n_act, flow, it, sg, ln, dc, sin, sout in trace:
             stats.engine_iters += it
+            stats.ard_stages += sg
             stats.engine_launches += ln
             stats.regions_discharged += dc
             stats.page_bytes += dc * page_bytes
@@ -360,4 +362,6 @@ def solve_stream(ss: StreamState, *, on_sweep=None, checkpoint=None,
         stats.converged = bool(seed.converged)
     if checkpoint is not None and sweeps > last_saved[0]:
         _save_ckpt(ss, sweeps, trace, active_pre)
+    if cfg.method != "ard":
+        stats.ard_stages = None
     return ss, stats

@@ -139,8 +139,8 @@ def test_sweep_invariants(p, k):
     for sweep in range(12):
         if int(num_active(meta, state, cfg)) == 0:
             break
-        state, _, _ = parallel_sweep(meta, state, cfg,
-                                     jnp.asarray(sweep, jnp.int32))
+        state, *_ = parallel_sweep(meta, state, cfg,
+                                  jnp.asarray(sweep, jnp.int32))
         d = np.asarray(state.d)
         assert (d >= prev_d).all(), "labels must be monotone"
         prev_d = d
