@@ -25,7 +25,7 @@ Used by prd.py (global labels, paper Sec. 3) and by each ARD stage
 Backends
 --------
 The per-iteration *compute phase* (admissibility, excess split, relabel
-minimum — everything except the scatter application of the deltas) is a pure
+minimum — everything except the application of the deltas) is a pure
 function from the current state to ``(delta [V, 1+E], new_lab [V])``, and is
 selectable:
 
@@ -38,8 +38,12 @@ selectable:
 Each iteration calls the phase twice: once on the pre-push state (the delta
 output drives the push) and once on the post-push state (the new_lab output
 is the relabel — relabels must see the arcs created by this iteration's
-pushes).  Scatter application of the deltas (reverse arcs, receiver excess)
-stays in XLA in both backends, as the kernel docstring prescribes.
+pushes).  The application of the deltas stays in XLA in both backends, as
+the kernel docstring prescribes: each arc pulls what its reverse arc pushed
+(one gather through ``rev_slot``) and a row sum credits the receiving
+vertex (``kernels.push_relabel.make_push_apply``).  The pull relies on the
+pairing every layout emits: on intra arcs ``rev_slot`` is an involution and
+``intra`` is symmetric under it (see ``push_relabel``).
 
 Fused chunked mode (``chunk_iters``)
 ------------------------------------
@@ -47,7 +51,7 @@ With ``chunk_iters=k`` the engine switches to the *region-resident fused*
 driver: the outer ``lax.while_loop`` body advances up to ``k`` complete
 iterations per trip instead of one.  For ``backend="pallas"`` one trip is a
 single ``fused_engine_run`` kernel launch with the whole region state in
-VMEM (push split, intra-region scatter and post-push relabel all in-kernel,
+VMEM (push split, intra-region apply and post-push relabel all in-kernel,
 early exit when no vertex is active); when the region exceeds the VMEM
 budget (``kernels.push_relabel.fused_region_fits_vmem``) the engine falls
 back to the blocked two-phase path.  Mosaic refuses that kernel's body, so
@@ -241,6 +245,14 @@ def push_relabel(
     (one launch per k iterations, region state resident); all combinations
     produce bit-identical states.  A Pallas region that exceeds the VMEM
     budget falls back to the blocked two-phase path.
+
+    Every route applies intra-region pushes as a pull
+    (``kernels.push_relabel.make_push_apply``), which holds the layout to
+    one contract: on intra arcs ``rev_slot`` is an involution — the
+    reverse of ``(v, e)`` is ``(nbr_local[v, e], rev_slot[v, e])``, whose
+    reverse is ``(v, e)`` — and ``intra`` is symmetric under it.
+    ``graph.build``, ``graph.pack_built``, ``stream.build_stream`` and the
+    sharded DIMACS reader emit that (tests/test_push_apply.py).
     """
     V, E = cf.shape
     d_inf = jnp.asarray(d_inf, _I32)
@@ -255,8 +267,9 @@ def push_relabel(
             cross_pushable=cross_pushable, cross_lab=cross_lab, d_inf=d_inf,
             sink_open=sink_open, max_iters=max_iters, backend=backend,
             chunk_iters=chunk_iters, interpret=interpret)
-    flat_n = V * E
     zero_e = jnp.zeros((V, E), cf.dtype)
+    apply = _pr_kernel.make_push_apply(nbr=nbr_local, rev_slot=rev_slot,
+                                       intra=intra)
     phase = make_phase(backend, nbr_local=nbr_local, intra=intra, emask=emask,
                        vmask=vmask, cross_pushable=cross_pushable,
                        cross_lab=cross_lab, d_inf=d_inf, sink_open=sink_open,
@@ -269,26 +282,11 @@ def push_relabel(
         # ---- push phase (compute on the pre-push state) ----
         delta, _ = phase(s.lab, s.cf, s.sink_cf, s.excess, mode="push")
         d_sink = delta[:, 0]
-        d_arc = delta[:, 1:]
-        # row sums stay in the storage dtype (bounded by the vertex's
-        # excess, which the narrow range check already covers); an implicit
-        # int32 promotion here would silently widen the while-loop carry
-        pushed = d_sink + jnp.sum(d_arc, axis=1, dtype=d_arc.dtype)
 
-        # ---- scatter application (always XLA: global, cross-tile) ----
-        excess = s.excess - pushed
+        # ---- push application (always XLA: it reads across tiles) ----
+        cf, excess, d_cross = apply(s.cf, s.excess - d_sink, delta[:, 1:])
         sink_cf = s.sink_cf - d_sink
-        cf = s.cf - d_arc
-        # intra reverse arcs + receiver excess
-        d_intra = jnp.where(intra, d_arc, 0)
-        flat_idx = (nbr_local * E + rev_slot).reshape(flat_n)
-        cf = (cf.reshape(flat_n).at[flat_idx]
-              .add(d_intra.reshape(flat_n), mode="drop").reshape(V, E))
-        recv = jnp.zeros((V,), cf.dtype).at[nbr_local.reshape(flat_n)].add(
-            d_intra.reshape(flat_n), mode="drop")
-        excess = excess + recv
         # cross arcs: flow leaves the region (applied later by the driver)
-        d_cross = d_arc - d_intra
         out_push = s.out_push + d_cross
 
         s2 = EngineState(cf, sink_cf, excess, s.lab, out_push,
@@ -353,14 +351,14 @@ def _push_relabel_fused_batched(cf, sink_cf, excess, lab, *, nbr_local,
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         if not interpret:
-            # Mosaic refuses the fused body (in-kernel gather, cumsum and
-            # scatter-add; tests/test_tpu_compile.py): refuse the route
+            # Mosaic refuses the fused body (in-kernel gathers and cumsum;
+            # tests/test_tpu_compile.py): refuse the route
             # here, typed, rather than let the kernel's lowering error
             # reach the degradation ladder
             raise UnsupportedFeatureError(
                 "TPU", "fused_pallas",
-                "Mosaic cannot lower its in-kernel gather, cumsum and "
-                "scatter-add; use engine_backend='xla' with "
+                "Mosaic cannot lower its in-kernel gathers and cumsum; "
+                "use engine_backend='xla' with "
                 "engine_chunk_iters, or engine_backend='pallas' without it")
         md = _mask_dtype(cf, lab)
         intra_i = intra.astype(md)

@@ -10,10 +10,11 @@ Blocked two-phase kernel (``push_relabel_phase``)
 The grid tiles the vertex dimension: each program instance loads a
 ``(BV, E)`` tile of residuals and of neighbour labels plus ``(BV, 1)``
 columns of the per-vertex values (BV = 256 rows by default).  The
-neighbour-label gather ``lab[nbr]`` and the scatter application of the
-deltas (reverse arcs, receiver excess) stay in XLA: Mosaic lowers neither
-a 1-D gather nor a scatter.  The excess split is an exclusive prefix
-unrolled over the E static lanes (Mosaic has no cumsum), and narrow int16
+neighbour-label gather ``lab[nbr]`` and the application of the deltas
+(``make_push_apply``: reverse arcs and receiver excess, pulled through
+``rev_slot``) stay in XLA: Mosaic lowers no 1-D gather.  The excess split
+is an exclusive prefix unrolled over the E static lanes (Mosaic has no
+cumsum), and narrow int16
 storage computes in int32 inside the kernel (Mosaic reduces no int16
 vectors).  This is the kernel of the ``engine_backend="pallas"`` route; it
 compiles for TPU v5e (tests/test_tpu_compile.py) and is bit-exact against
@@ -29,11 +30,12 @@ Region-resident fused kernel (interpret mode only)
 ``fused_engine_run_batched`` is one ``grid=(K,)`` (or ``grid=(B, K)`` for a
 solve batch) ``pallas_call`` whose block is a *whole region*: each program
 instance advances its region up to ``iter_limit`` complete engine
-iterations — push split, intra-region scatter, post-push relabel — with
+iterations — push split, intra-region apply, post-push relabel — with
 the state resident and a per-region early exit.  ``fused_engine_run`` is
-its single-region form.  Its body gathers ``lab[nbr]``, takes a cumsum and
-scatter-adds inside the kernel, which Mosaic refuses ("Only 2D gather is
-supported" is the first refusal; tests/test_tpu_compile.py lowers it).  It
+its single-region form.  Its body gathers ``lab[nbr]`` and the reverse
+arcs' pushes and takes a cumsum inside the kernel, which Mosaic refuses
+("Only 2D gather is supported" is the first refusal;
+tests/test_tpu_compile.py lowers it).  It
 therefore runs only under the Pallas interpreter, where it is bit-exact
 against the other engine routes; on a TPU ``core.engine`` refuses the route
 up front.  ``core.engine`` also falls back to the blocked path when a
@@ -177,7 +179,7 @@ def push_relabel_phase(lab, cf, sink_cf, excess, nbr, intra, pushable,
 # --------------------------------------------------------------------------
 
 # VMEM working set of one fused iteration, per value family.  [V, E]
-# arrays: cf, out_push, d_arc, d_intra carry flow values; nbr, rev_slot
+# arrays: cf, out_push, d_arc, pulled credits carry flow; nbr, rev_slot
 # are int32 indices; intra, pushable are masks; cross_lab carries labels.
 # caps/delta are flow-valued [V, 1+E].  [V] vectors: sink_cf, excess,
 # avail carry flow; lab, new_lab carry labels; vmask is a mask; plus two
@@ -213,24 +215,60 @@ def fused_region_fits_vmem(V: int, E: int,
     return fused_region_vmem_bytes(V, E, dtypes) <= budget
 
 
+def make_push_apply(*, nbr, rev_slot, intra):
+    """Build the apply step of one engine iteration (everything but the sink).
+
+    ``apply(cf, excess, d_arc) -> (cf, excess, d_cross)`` takes every arc's
+    push ``d_arc [V, E]`` off its own residual and its vertex's excess, and
+    credits each intra-region push to the reverse arc and the receiving
+    vertex.  The credit is a *pull*: arc ``(v, e)`` gathers what its reverse
+    ``(nbr[v, e], rev_slot[v, e])`` pushed, and a row sum gives the vertex's
+    receipts.  That is one gather where a push-side apply takes two
+    scatter-adds (reverse arcs, receiver excess), and the same integers in
+    another order, so every sum is exact.  Cross-region pushes come back
+    as ``d_cross`` for the sweep driver.
+
+    Contract (every layout ``graph.build``, ``graph.pack_built``,
+    ``stream.build_stream`` and ``data.dimacs.read_dimacs_sharded``
+    emit): on intra arcs ``rev_slot`` is an involution — the reverse of
+    ``(v, e)`` is ``(nbr[v, e], rev_slot[v, e])``, whose reverse is
+    ``(v, e)`` — and ``intra`` is symmetric under it.  A padding slot
+    (``intra`` false) credits 0.  The unfused engine body and
+    ``make_fused_iteration`` both apply through this, so the repo has one
+    formulation of the push application; ``kernels.ref.fused_iteration_ref``
+    keeps the scatter form as the oracle.
+    """
+    V, E = nbr.shape
+    src = nbr * E + rev_slot          # flat slot of each arc's reverse
+
+    def apply(cf, excess, d_arc):
+        back = jnp.where(intra, d_arc.reshape(V * E)[src], 0)
+        cf = cf - d_arc + back
+        # row sums stay in the storage dtype: a vertex's pushes are bounded
+        # by its excess and its receipts by the capacity mass, which the
+        # narrow range check covers
+        excess = (excess - jnp.sum(d_arc, axis=1, dtype=d_arc.dtype)
+                  + jnp.sum(back, axis=1, dtype=d_arc.dtype))
+        return cf, excess, jnp.where(intra, 0, d_arc)
+
+    return apply
+
+
 def make_fused_iteration(*, nbr, rev_slot, intra, pushable, cross_lab, vmask,
                          d_inf, sink_open: bool):
     """Build the pure fused-iteration function shared by both backends.
 
     ``iteration(cf, sink_cf, excess, lab) -> (cf, sink_cf, excess, new_lab,
     d_cross, d_sink_total, relabel_inc)`` performs push compute (labels
-    frozen), intra-region scatter application (reverse arcs via
-    ``rev_slot``, receiver excess via ``nbr``) and the post-push relabel in
-    one function — the per-step unit of the region-resident kernel and of
+    frozen), the push application (``make_push_apply``: reverse arcs and
+    receiver excess pulled through ``rev_slot``) and the post-push relabel
+    in one function — the per-step unit of the region-resident kernel and of
     the fused XLA engine body.  Defining it once is what makes the two
     fused backends bit-exact by construction; ``kernels.ref.
     fused_iteration_ref`` stays the independent oracle.  ``intra``/
     ``pushable``/``vmask`` are bool, ``d_inf`` an i32 scalar.
     """
-    V, E = nbr.shape
-    flat_n = V * E
-    flat_idx = (nbr * E + rev_slot).reshape(flat_n)
-    recv_idx = nbr.reshape(flat_n)
+    apply = make_push_apply(nbr=nbr, rev_slot=rev_slot, intra=intra)
     inf = _inf_for(cross_lab.dtype)
 
     def iteration(cf, sink_cf, excess, lab):
@@ -252,16 +290,9 @@ def make_fused_iteration(*, nbr, rev_slot, intra, pushable, cross_lab, vmask,
         delta = jnp.clip(avail[:, None] - cum_excl, 0, caps)
         d_sink = delta[:, 0]
         d_arc = delta[:, 1:]
-        # ---- scatter application (intra reverse arcs + receiver excess) ----
-        excess = excess - d_sink - jnp.sum(d_arc, axis=1, dtype=d_arc.dtype)
+        # ---- push application (intra credits pulled from reverse arcs) ----
+        cf, excess, d_cross = apply(cf, excess - d_sink, d_arc)
         sink_cf = sink_cf - d_sink
-        cf = cf - d_arc
-        d_intra = jnp.where(intra, d_arc, 0)
-        cf = (cf.reshape(flat_n).at[flat_idx]
-              .add(d_intra.reshape(flat_n), mode="drop").reshape(V, E))
-        excess = excess + jnp.zeros((V,), excess.dtype).at[recv_idx].add(
-            d_intra.reshape(flat_n), mode="drop")
-        d_cross = d_arc - d_intra
         # ---- relabel (on the post-push residual graph) ----
         act2 = (excess > 0) & (lab < dinf) & vmask
         adm2 = (cf > 0) & (lab[:, None] == nlab + 1) & act2[:, None]
@@ -287,7 +318,7 @@ def _fused_region_loop(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
     """Up to ``limit`` fused engine iterations on one region's arrays.
 
     One iteration is bit-identical to one trip of the unfused engine loop
-    (push compute -> intra scatter -> post-push relabel); the while_loop
+    (push compute -> push apply -> post-push relabel); the while_loop
     exits early once no vertex is active, so idle regions cost O(1).  This
     is the shared in-kernel body of the single-region (``grid=()``) and the
     grid-over-regions (``grid=(K,)``) fused kernels.

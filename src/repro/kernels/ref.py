@@ -7,7 +7,10 @@
   push-relabel kernel (kernels/push_relabel.py).
 * ``fused_iteration_ref`` — pure-jnp oracle for one *complete* fused engine
   iteration (push compute + intra-region scatter + post-push relabel), the
-  unit the region-resident fused kernel advances per in-kernel step.
+  unit the region-resident fused kernel advances per in-kernel step.  It
+  keeps the push-side scatter form of the apply; the engine pulls
+  (``kernels.push_relabel.make_push_apply``), so the two agree exactly on
+  layouts whose ``rev_slot`` pairs intra arcs.
 * ``attention_ref`` — pure-jnp oracle for the Pallas flash-attention kernel.
 """
 

@@ -86,17 +86,34 @@ def test_push_relabel_phase_matches_ref(V, E, block_v):
 
 
 def _random_region(V, E, seed):
-    """Random (not necessarily consistent) region network: enough for
-    bit-parity checks, which only need both backends to see the same bits."""
+    """Random region network: capacities, labels, masks and cross arcs are
+    random, and its arcs pair up as a residual network's do.  The ``V*E``
+    slots are cut into pairs by a shuffled perfect matching; each pair's
+    slots name each other as ``(nbr_local, rev_slot)`` and share one
+    ``intra`` draw, so ``rev_slot`` is an involution on intra arcs and
+    ``intra`` is symmetric under it (the layout contract the engine's
+    push apply relies on).  With ``V*E`` odd the slot left over is a
+    cross arc.  The labeling need not be valid, so runs take a cap."""
     rng = np.random.RandomState(seed)
+    cf = rng.randint(0, 50, (V, E))
+    sink_cf = rng.randint(0, 20, (V,))
+    excess = rng.randint(0, 40, (V,))
+    lab = rng.randint(0, 8, (V,))
+    nbr_local = rng.randint(0, V, V * E)
+    rev_slot = rng.randint(0, E, V * E)
+    intra = np.zeros(V * E, bool)
+    pairs = rng.permutation(V * E)[:V * E // 2 * 2].reshape(-1, 2)
+    for a, b in (pairs[:, 0], pairs[:, 1]), (pairs[:, 1], pairs[:, 0]):
+        nbr_local[a], rev_slot[a] = b // E, b % E
+    intra[pairs] = (rng.rand(len(pairs)) < 0.8)[:, None]
     return dict(
-        cf=jnp.asarray(rng.randint(0, 50, (V, E)), jnp.int32),
-        sink_cf=jnp.asarray(rng.randint(0, 20, (V,)), jnp.int32),
-        excess=jnp.asarray(rng.randint(0, 40, (V,)), jnp.int32),
-        lab=jnp.asarray(rng.randint(0, 8, (V,)), jnp.int32),
-        nbr_local=jnp.asarray(rng.randint(0, V, (V, E)), jnp.int32),
-        rev_slot=jnp.asarray(rng.randint(0, E, (V, E)), jnp.int32),
-        intra=jnp.asarray(rng.rand(V, E) < 0.8),
+        cf=jnp.asarray(cf, jnp.int32),
+        sink_cf=jnp.asarray(sink_cf, jnp.int32),
+        excess=jnp.asarray(excess, jnp.int32),
+        lab=jnp.asarray(lab, jnp.int32),
+        nbr_local=jnp.asarray(nbr_local.reshape(V, E), jnp.int32),
+        rev_slot=jnp.asarray(rev_slot.reshape(V, E), jnp.int32),
+        intra=jnp.asarray(intra.reshape(V, E)),
         emask=jnp.asarray(rng.rand(V, E) < 0.9),
         vmask=jnp.asarray(rng.rand(V) < 0.95),
         cross_pushable=jnp.asarray(rng.rand(V, E) < 0.5),
@@ -154,7 +171,7 @@ def _engine_kwargs(r, V, **over):
 @pytest.mark.parametrize("V,E", [(16, 4), (33, 5)],
                          ids=["(16,4)", "(33,5)"])
 def test_fused_iteration_matches_ref_oracle(V, E, backend):
-    """One fused engine iteration (push + intra scatter + post-push relabel)
+    """One fused engine iteration (push + intra apply + post-push relabel)
     is bit-equal to the kernels/ref.py fused-iteration oracle."""
     r = _random_region(V, E, seed=11 * V + E)
     es = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
@@ -208,7 +225,7 @@ def test_fused_engine_matches_unfused(V, E, backend, chunk):
 def _consistent_region(n, m, seed):
     """A *valid* single-region network (true reverse slots, zero labels) —
     the engine provably terminates on it, unlike on _random_region's
-    arbitrary topology, so it can run to convergence."""
+    arbitrary labels and masks, so it can run to convergence."""
     from repro.core.graph import build, intra_mask
     from repro.data.grids import random_sparse
 

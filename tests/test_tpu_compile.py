@@ -8,6 +8,8 @@ here, so the kernels are called with ``interpret=False`` and sweep-level
 programs are steered from the test (``tpu_backend``).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -119,6 +121,65 @@ def _grid_state(n, sharding):
     meta, state, _ = build(p, grid_partition((n, n), (4, 4)))
     return meta, jax.tree.map(
         lambda a: _sds(sharding, a.shape, a.dtype), state)
+
+
+def _while_loops(hlo: str) -> dict:
+    """``{while name: opcodes reachable from its body}`` of an HLO module's
+    text, following ``body=``, ``calls=``, ``to_apply=`` and the like into
+    nested and fused computations."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    callee = re.compile(r"(?:body|condition|calls|to_apply|branch_computations"
+                        r"|called_computations)=\{?(%[^}\s]+(?:, %[^},\s]+)*)")
+    opcode = re.compile(r"= .*? ([a-z][a-z0-9\-]*)\(")
+
+    def reach(name):
+        seen, todo = set(), [name]
+        while todo:
+            c = todo.pop()
+            if c in seen or c not in comps:
+                continue
+            seen.add(c)
+            for line in comps[c]:
+                for m in callee.finditer(line):
+                    todo += [x.strip().lstrip("%").rstrip(",")
+                             for x in m.group(1).split(",")]
+        return [m.group(1) for c in seen for line in comps[c]
+                if (m := opcode.search(line))]
+
+    loops = {}
+    for lines in comps.values():
+        for line in lines:
+            m = re.search(r"%(while\.\d+) = .* while\(.*body=%([^,\s]+)",
+                          line)
+            if m:
+                loops[m.group(1)] = reach(m.group(2))
+    return loops
+
+
+def test_engine_loop_applies_pushes_without_scatter(one_chip, tpu_backend):
+    """The engine's while body (the innermost loop that holds the excess
+    split's cumsum) applies intra-region pushes by gathering from the
+    reverse arc: two gathers (neighbour labels, reverse pushes) and no
+    scatter, on a 32^2 8-connected grid in 4x4 regions (xla backend)."""
+    meta, state = _grid_state(32, one_chip)
+    compiled = parallel_sweep.lower(
+        meta, state, SweepConfig(method="ard", engine_backend="xla"),
+        _sds(one_chip, (), jnp.int32)).compile()
+    loops = _while_loops(compiled.as_text())
+    engine = [ops for ops in loops.values()
+              if "reduce-window" in ops and "while" not in ops]
+    assert len(engine) == 1, sorted(loops)
+    ops = engine[0]
+    assert ops.count("scatter") == 0, ops
+    assert ops.count("gather") == 2, ops
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
