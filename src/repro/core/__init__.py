@@ -51,7 +51,6 @@ from repro.core.resilience import (CheckpointMismatchError, CheckpointPolicy,
                                    VmemOverflowError, fault_injection,
                                    latest_checkpoint, load_checkpoint,
                                    save_checkpoint)
-from repro.core.autotune import TunedConfig, tune, tuned_sweep_config
 from repro.core.dtypes import DTYPE_POLICIES, KernelDtypes
 from repro.core.partition import bfs_partition, block_partition, grid_partition
 from repro.core.reduction import region_reduction
@@ -71,7 +70,7 @@ __all__ = [
     "ShardedExecutor", "SolveCheckpoint", "SolveSupervisor", "Solver",
     "StreamingExecutor",
     "SolverCacheInfo", "SolverOptions", "SupervisorReport", "SweepConfig",
-    "SweepStats", "TunedConfig", "UnsupportedFeatureError", "Violation",
+    "SweepStats", "UnsupportedFeatureError", "Violation",
     "VmemOverflowError", "apply_update",
     "bfs_partition", "block_partition", "bucket_shape_for",
     "build", "cut_value", "extract_cut", "fault_injection",
@@ -79,5 +78,5 @@ __all__ = [
     "latest_checkpoint", "load_checkpoint",
     "pack_built", "pack_instances",
     "region_reduction", "save_checkpoint", "solve", "solve_mincut",
-    "solve_mincut_batch", "tune", "tuned_sweep_config", "validate_problem",
+    "solve_mincut_batch", "validate_problem",
 ]

@@ -106,9 +106,7 @@ def solve_mincut_batch(
     One-shot convenience over ``Solver.solve_many`` (a kept ``Solver``
     session amortizes the compile cache across calls): problems are packed
     into shape buckets and each bucket is solved by one batched device
-    program — on the fused pallas path one ``grid=(B, K)`` kernel launch
-    per engine chunk-trip for the whole bucket.  Results are returned in
-    input order and are bit-identical per instance to ``solve_mincut``.
+    program.  Results are returned in input order and are bit-identical per instance to ``solve_mincut``.
     """
     solver = BatchedSolver(config, num_regions=num_regions, check=check)
     return solver.solve(problems, parts)

@@ -131,19 +131,16 @@ def ard_discharge_batched(cf, sink_cf, excess, ghost_d, *, nbr_local,
                           stage_cap, max_iters: int | None = None,
                           backend: str = "xla",
                           chunk_iters: int | None = None,
-                          linf=None,
-                          grid2d: tuple[int, int] | None = None
-                          ) -> DischargeResult:
+                          linf=None) -> DischargeResult:
     """ARD on all K regions of a parallel sweep, collectively.
 
     The batched counterpart of ``jax.vmap(ard_discharge_one)``: the stage
     loop advances every region in lockstep (a region whose stage schedule
     is exhausted is frozen by a per-region select, exactly like vmapped
     while_loop batching), and each stage's engine run goes through
-    ``engine.push_relabel_batched`` — one grid-over-regions kernel launch
-    per chunk on the fused pallas path instead of K per-region launch
-    sequences.  Per-region results (state, labels, out_push, engine
-    iterations, stage counts) are bit-identical to the vmapped scalar path;
+    ``engine.push_relabel_batched``, one engine run for every region.
+    Per-region results (state, labels, out_push, engine iterations, stage
+    counts) are bit-identical to the vmapped scalar path;
     ``engine_launches`` becomes the global dispatch count of the sweep.
 
     ``d_inf``/``stage_cap`` may be scalars or per-region i32[K] vectors and
@@ -151,7 +148,7 @@ def ard_discharge_batched(cf, sink_cf, excess, ghost_d, *, nbr_local,
     padded row count ``V + 2``) — a solve batch's regions carry their own
     instance's ceilings, which keeps every region's iteration sequence
     identical to the instance's standalone solve regardless of bucket
-    padding.  ``grid2d`` renders the fused pallas launch as ``grid=(B,Kr)``.
+    padding.
     """
     K, V, E = cf.shape
     cross = emask & ~intra
@@ -188,7 +185,7 @@ def ard_discharge_batched(cf, sink_cf, excess, ghost_d, *, nbr_local,
             vmask=vmask, cross_pushable=target_cross,
             cross_lab=jnp.zeros_like(ghost_d), d_inf=linf,
             sink_open=True, max_iters=max_iters, backend=backend,
-            chunk_iters=chunk_iters, grid2d=grid2d)
+            chunk_iters=chunk_iters)
         w3, w2 = more[:, None, None], more[:, None]
         return (i + more.astype(_I32),
                 jnp.where(w3, es.cf, cf),

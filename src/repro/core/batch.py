@@ -6,9 +6,8 @@ This module lifts the device-resident sweep driver of ``sweep.py`` over a
 leading instance axis B:
 
 * one batched parallel sweep discharges **every region of every instance**
-  through the grid-over-regions discharge operators — on the fused pallas
-  path a single ``grid=(B, K)`` kernel launch per engine chunk-trip
-  (``kernels.push_relabel.fused_engine_run_batched``);
+  through the batched discharge operators, over the flat ``B*K`` region
+  axis;
 * the whole multi-sweep loop runs in one ``lax.while_loop`` with
   **per-instance convergence flags**: an instance that has converged (or
   exhausted its sweep budget) is frozen by per-instance selects and its
@@ -38,7 +37,6 @@ single-instance driver.
 
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
 from functools import partial
 
@@ -94,7 +92,6 @@ class BatchStats:
     host_syncs: int = 0
     converged: np.ndarray | None = None   # bool[B]: instance reached zero
     #                                       active vertices within budget
-    degraded: list = dataclasses.field(default_factory=list)
 
 
 def _ghost_labels(state: BatchState) -> jax.Array:
@@ -161,10 +158,9 @@ def _parallel_sweep_batch(bmeta: BatchMeta, cfg: SweepConfig,
     """One parallel sweep (Alg. 2) over every instance of the batch.
 
     Identical math to ``sweep.parallel_sweep`` applied per instance: the
-    discharge goes through the flat [B*K] grid-over-regions operators with
-    per-region ceilings (``grid2d`` renders the fused pallas launch as the
-    ``grid=(B, K)`` program), fusion uses the bucket-dim cross tables, and
-    the gap heuristic runs per instance.  ``run`` (bool[B]) marks the
+    discharge goes through the flat [B*K] batched operators with
+    per-region ceilings, fusion uses the bucket-dim cross tables, and the
+    gap heuristic runs per instance.  ``run`` (bool[B]) marks the
     instances whose result the driver will keep — frozen instances get
     their ARD stage schedule emptied (cap -2 admits not even the sink
     stage) so they never add stage-loop trips to the shared launch stream.
@@ -183,7 +179,7 @@ def _parallel_sweep_batch(bmeta: BatchMeta, cfg: SweepConfig,
     kw = dict(nbr_local=f3(state.nbr_local), rev_slot=f3(state.rev_slot),
               intra=f3(intra), emask=f3(state.emask), vmask=f2(state.vmask),
               max_iters=cfg.engine_max_iters, backend=cfg.engine_backend,
-              chunk_iters=cfg.engine_chunk_iters, grid2d=(B, K))
+              chunk_iters=cfg.engine_chunk_iters)
     if ard:
         if cfg.partial_discharge:
             stage_cap = jnp.broadcast_to(
@@ -296,13 +292,10 @@ def solve_batch(packed: PackedBatch, cfg: SweepConfig | None = None, *,
         ex, state, limit, cfg.host_sync_every, carry0=carry0,
         on_sync=on_sync)
     sweeps, iters, stages, launches, n_act = host
-    note = _res.vmem_fallback_note(cfg, bmeta.region_size, bmeta.max_degree,
-                                   dtypes=bmeta.kernel_dtypes)
     return state, BatchStats(
         sweeps=np.asarray(sweeps, np.int64),
         engine_iters=np.asarray(iters, np.int64),
         ard_stages=np.asarray(stages, np.int64)
         if cfg.method == "ard" else None,
         engine_launches=int(launches), host_syncs=seed_syncs + syncs,
-        converged=np.asarray(n_act) == 0,
-        degraded=[] if note is None else [note])
+        converged=np.asarray(n_act) == 0)

@@ -133,8 +133,7 @@ def _one_sweep_local(meta: GraphMeta, cfg: SweepConfig, axes,
         _I32(meta.d_inf_ard))
 
     # batched discharge over this shard's local regions: same per-region
-    # results as vmapping the scalar operators, but the fused pallas path
-    # is one grid-over-regions kernel launch per chunk per shard
+    # results as vmapping the scalar operators
     disc_kw = dict(nbr_local=state.nbr_local, rev_slot=state.rev_slot,
                    intra=intra, emask=state.emask, vmask=state.vmask,
                    max_iters=cfg.engine_max_iters,

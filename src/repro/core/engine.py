@@ -47,56 +47,30 @@ pairing every layout emits: on intra arcs ``rev_slot`` is an involution and
 
 Fused chunked mode (``chunk_iters``)
 ------------------------------------
-With ``chunk_iters=k`` the engine switches to the *region-resident fused*
-driver: the outer ``lax.while_loop`` body advances up to ``k`` complete
-iterations per trip instead of one.  For ``backend="pallas"`` one trip is a
-single ``fused_engine_run`` kernel launch with the whole region state in
-VMEM (push split, intra-region apply and post-push relabel all in-kernel,
-early exit when no vertex is active); when the region exceeds the VMEM
-budget (``kernels.push_relabel.fused_region_fits_vmem``) the engine falls
-back to the blocked two-phase path.  Mosaic refuses that kernel's body, so
-it runs only interpreted: on a TPU the fused pallas route raises
-``UnsupportedFeatureError`` when it is traced.  For ``backend="xla"`` one
-trip is the symmetric single traced body (the shared
-``kernels.push_relabel.make_fused_iteration`` inside an inner bounded
-loop) — one compute+apply+relabel program per iteration instead of two
-phase calls.  All four paths (fused/unfused × xla/pallas) are bit-exact;
-``EngineState.launches`` counts compute-program dispatches per engine run
-(2 per iteration unfused; fused: 1 per chunk on pallas — a real kernel
-launch — and 1 per iteration on xla, which fuses the two phase calls but
-keeps per-iteration program structure) for the benchmark's
-launch-reduction accounting.
+With ``chunk_iters=k`` the outer ``lax.while_loop`` body advances up to
+``k`` complete iterations per trip instead of one, each a single traced
+body (``kernels.push_relabel.make_fused_iteration`` inside an inner
+bounded loop) — one compute+apply+relabel program per iteration instead of
+two phase calls.  The fused body is XLA only: ``SweepConfig`` refuses
+``engine_backend="pallas"`` together with ``engine_chunk_iters``, so the
+engine runs three routes — xla unfused, xla fused and the blocked Pallas
+kernel — all bit-exact.  ``EngineState.launches`` counts compute-program
+dispatches per engine run (2 per iteration unfused, 1 per iteration
+fused), summed into ``SweepStats.engine_launches``.
 """
 
 from __future__ import annotations
 
-import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import dtypes as _dt
-from repro.core.executor import UnsupportedFeatureError
 from repro.kernels import push_relabel as _pr_kernel
 
 _I32 = jnp.int32
 
-
-def _mask_dtype(cf, lab):
-    """Kernel mask staging dtype: int8 whenever either value family is
-    stored narrow (the KernelDtypes policy), int32 otherwise."""
-    return jnp.int8 if (cf.dtype.itemsize < 4 or lab.dtype.itemsize < 4) \
-        else jnp.int32
-
-
-def _kernel_dtypes(cf, lab) -> _dt.KernelDtypes:
-    """Reconstruct the KernelDtypes policy in force from live arrays (for
-    the dtype-aware VMEM budget check)."""
-    mask = "int8" if (cf.dtype.itemsize < 4 or lab.dtype.itemsize < 4) \
-        else "int32"
-    return _dt.KernelDtypes(label=lab.dtype.name, flow=cf.dtype.name,
-                            mask=mask)
 
 ENGINE_BACKENDS = ("xla", "pallas")
 
@@ -111,7 +85,7 @@ class EngineState(NamedTuple):
     iters: jax.Array       # i32[]
     relabel_sum: jax.Array  # i32[]    total label increase (for complexity accounting)
     launches: jax.Array    # i32[]    compute-program dispatches: 2/iter unfused,
-    #                                 1/chunk fused-pallas, 1/iter fused-xla
+    #                                 1/iter fused
 
 
 def _phase_xla(lab, cf, sink_cf, excess, *, nbr_local, intra, pushable,
@@ -192,14 +166,12 @@ def make_phase(backend: str, *, nbr_local, intra, emask, vmask,
 
 def _push_relabel_fused(cf, sink_cf, excess, lab, *, nbr_local, rev_slot,
                         intra, emask, vmask, cross_pushable, cross_lab, d_inf,
-                        sink_open, max_iters, backend, chunk_iters,
-                        interpret) -> EngineState:
-    """Chunked fused driver on a single region: one launch advances up to
+                        sink_open, max_iters, chunk_iters) -> EngineState:
+    """Chunked fused driver on a single region: one trip advances up to
     ``chunk_iters`` complete iterations, early-exiting as soon as no vertex
     is active.  Thin K = 1 wrapper over ``_push_relabel_fused_batched`` so
-    the chunk-clamping / early-exit / launch-accounting logic exists once;
-    the accounting is identical at K = 1 (pallas: 1 per trip; xla: 1 per
-    advanced iteration).
+    the chunk-clamping / early-exit / launch-accounting logic exists once
+    (1 launch per advanced iteration).
     """
     one = lambda a: a[None]
     es = _push_relabel_fused_batched(
@@ -208,7 +180,7 @@ def _push_relabel_fused(cf, sink_cf, excess, lab, *, nbr_local, rev_slot,
         emask=one(emask), vmask=one(vmask),
         cross_pushable=one(cross_pushable), cross_lab=one(cross_lab),
         d_inf=d_inf, sink_open=sink_open, max_iters=max_iters,
-        backend=backend, chunk_iters=chunk_iters, interpret=interpret)
+        chunk_iters=chunk_iters)
     return EngineState(es.cf[0], es.sink_cf[0], es.excess[0], es.lab[0],
                        es.out_push[0], es.sink_pushed[0], es.iters[0],
                        es.relabel_sum[0], es.launches)
@@ -234,17 +206,15 @@ def push_relabel(
     block_v: int | None = None,
     interpret: bool | None = None,
     chunk_iters: int | None = None,
-    vmem_budget_bytes: int | None = None,
 ) -> EngineState:
     """Run push/relabel until no active vertex remains.
 
     Returns the final engine state; ``out_push`` holds the flow sent over
     cross-region arcs, to be fused/applied by the sweep driver.  ``backend``
-    selects the compute-phase implementation ("xla" dense rows or the fused
-    "pallas" kernel); ``chunk_iters=k`` selects the fused chunked driver
-    (one launch per k iterations, region state resident); all combinations
-    produce bit-identical states.  A Pallas region that exceeds the VMEM
-    budget falls back to the blocked two-phase path.
+    selects the unfused compute phase ("xla" dense rows or the blocked
+    "pallas" kernel); ``chunk_iters=k`` selects the fused XLA driver (k
+    iterations per outer trip), which ``SweepConfig`` allows on
+    ``backend="xla"`` only.  Every route produces bit-identical states.
 
     Every route applies intra-region pushes as a pull
     (``kernels.push_relabel.make_push_apply``), which holds the layout to
@@ -256,17 +226,13 @@ def push_relabel(
     """
     V, E = cf.shape
     d_inf = jnp.asarray(d_inf, _I32)
-    if chunk_iters is not None and backend == "pallas" \
-            and not _pr_kernel.fused_region_fits_vmem(
-                V, E, vmem_budget_bytes, dtypes=_kernel_dtypes(cf, lab)):
-        chunk_iters = None       # region too big to sit in VMEM: blocked path
     if chunk_iters is not None:
         return _push_relabel_fused(
             cf, sink_cf, excess, lab, nbr_local=nbr_local, rev_slot=rev_slot,
             intra=intra, emask=emask, vmask=vmask,
             cross_pushable=cross_pushable, cross_lab=cross_lab, d_inf=d_inf,
-            sink_open=sink_open, max_iters=max_iters, backend=backend,
-            chunk_iters=chunk_iters, interpret=interpret)
+            sink_open=sink_open, max_iters=max_iters,
+            chunk_iters=chunk_iters)
     zero_e = jnp.zeros((V, E), cf.dtype)
     apply = _pr_kernel.make_push_apply(nbr=nbr_local, rev_slot=rev_slot,
                                        intra=intra)
@@ -314,27 +280,20 @@ def push_relabel(
 def _push_relabel_fused_batched(cf, sink_cf, excess, lab, *, nbr_local,
                                 rev_slot, intra, emask, vmask, cross_pushable,
                                 cross_lab, d_inf, sink_open, max_iters,
-                                backend, chunk_iters, interpret,
-                                grid2d: tuple[int, int] | None = None
-                                ) -> EngineState:
-    """Fused chunked driver over ALL regions at once (grid-over-regions).
+                                chunk_iters) -> EngineState:
+    """Fused chunked driver over ALL regions at once.
 
     One outer trip advances every still-running region by up to
-    ``chunk_iters`` iterations: on ``backend="pallas"`` the trip is a single
-    ``fused_engine_run_batched`` launch (``grid=(K,)``, per-region in-kernel
-    early exit); on ``backend="xla"`` it is one traced batched body with
-    per-region run masking.  Each region's iteration sequence is exactly the
-    scalar driver's (a region advances iff it has an active vertex and
-    budget left), so per-region states and iteration counts are
-    bit-identical to ``jax.vmap`` of the scalar path.  ``launches`` is the
-    *global* dispatch count: 1 per trip on pallas (the kernel covers every
-    region), one traced body per advanced region-iteration on xla —
-    mirroring the scalar fused accounting summed over regions.
+    ``chunk_iters`` iterations: one traced batched body (the fused
+    iteration vmapped over the region axis) with per-region run masking.
+    Each region's iteration sequence is exactly the scalar driver's (a
+    region advances iff it has an active vertex and budget left), so
+    per-region states and iteration counts are bit-identical to
+    ``jax.vmap`` of the scalar path.  ``launches`` is the *global* dispatch
+    count: one traced body per advanced region-iteration.
 
     ``d_inf`` may be a scalar or a per-region i32[K] vector (a solve
-    batch's regions carry their instance's ceiling).  ``grid2d=(B, Kr)``
-    with ``K == B*Kr`` reshapes the pallas launch to the ``grid=(B, Kr)``
-    kernel form — same launch count, but the grid names the instance axis.
+    batch's regions carry their instance's ceiling).
     """
     K, V, E = cf.shape
     chunk = int(chunk_iters)
@@ -347,72 +306,43 @@ def _push_relabel_fused_batched(cf, sink_cf, excess, lab, *, nbr_local,
     def region_active(excess, lab):
         return ((excess > 0) & (lab < d_inf[:, None]) & vmask).any(axis=1)
 
-    if backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        if not interpret:
-            # Mosaic refuses the fused body (in-kernel gathers and cumsum;
-            # tests/test_tpu_compile.py): refuse the route
-            # here, typed, rather than let the kernel's lowering error
-            # reach the degradation ladder
-            raise UnsupportedFeatureError(
-                "TPU", "fused_pallas",
-                "Mosaic cannot lower its in-kernel gathers and cumsum; "
-                "use engine_backend='xla' with "
-                "engine_chunk_iters, or engine_backend='pallas' without it")
-        md = _mask_dtype(cf, lab)
-        intra_i = intra.astype(md)
-        pushable_i = pushable.astype(md)
-        vmask_i = vmask.astype(md)
-        lead = (K,) if grid2d is None else tuple(grid2d)
-        assert math.prod(lead) == K, (lead, K)
-        rs = lambda a: a.reshape(lead + a.shape[1:])
+    # the pure fused iteration, vmapped over the region axis; a per-region
+    # run mask freezes regions that are idle or out of budget, exactly like
+    # vmap-of-while_loop batching does
+    def one_region(cf, sink_cf, excess, lab, nbr, rev, it_m, pu_m, cl, vm,
+                   di):
+        step = _pr_kernel.make_fused_iteration(
+            nbr=nbr, rev_slot=rev, intra=it_m, pushable=pu_m, cross_lab=cl,
+            vmask=vm, d_inf=di, sink_open=sink_open)
+        return step(cf, sink_cf, excess, lab)
 
-        def launch(lab, cf, sink_cf, excess, limit):
-            out = _pr_kernel.fused_engine_run_batched(
-                rs(lab), rs(cf), rs(sink_cf), rs(excess), rs(nbr_local),
-                rs(rev_slot), rs(intra_i), rs(pushable_i), rs(cross_lab),
-                rs(vmask_i), rs(d_inf), rs(limit),
-                sink_open=sink_open, interpret=interpret)
-            return tuple(o.reshape((K,) + o.shape[len(lead):]) for o in out)
-    else:
-        # the same pure fused iteration, vmapped over the region axis; a
-        # per-region run mask freezes regions that are idle or out of
-        # budget, exactly like vmap-of-while_loop batching does
-        def one_region(cf, sink_cf, excess, lab, nbr, rev, it_m, pu_m, cl,
-                       vm, di):
-            step = _pr_kernel.make_fused_iteration(
-                nbr=nbr, rev_slot=rev, intra=it_m, pushable=pu_m,
-                cross_lab=cl, vmask=vm, d_inf=di, sink_open=sink_open)
-            return step(cf, sink_cf, excess, lab)
+    batched_iteration = jax.vmap(one_region)
 
-        batched_iteration = jax.vmap(one_region)
+    def launch(lab, cf, sink_cf, excess, limit):
+        def icond(c):
+            cf, sink_cf, excess, lab, op, sp, rs, it = c
+            return ((it < limit) & region_active(excess, lab)).any()
 
-        def launch(lab, cf, sink_cf, excess, limit):
-            def icond(c):
-                cf, sink_cf, excess, lab, op, sp, rs, it = c
-                return ((it < limit) & region_active(excess, lab)).any()
+        def ibody(c):
+            cf, sink_cf, excess, lab, op, sp, rs, it = c
+            run = (it < limit) & region_active(excess, lab)          # [K]
+            ncf, nsink, nexc, nlab, d_cross, d_sink, rinc = \
+                batched_iteration(cf, sink_cf, excess, lab, nbr_local,
+                                  rev_slot, intra, pushable, cross_lab,
+                                  vmask, d_inf)
+            w3, w2 = run[:, None, None], run[:, None]
+            cf = jnp.where(w3, ncf, cf)
+            sink_cf = jnp.where(w2, nsink, sink_cf)
+            excess = jnp.where(w2, nexc, excess)
+            lab = jnp.where(w2, nlab, lab)
+            op = op + jnp.where(w3, d_cross, 0)
+            sp = sp + jnp.where(run, d_sink, 0)
+            rs = rs + jnp.where(run, rinc, 0)
+            return (cf, sink_cf, excess, lab, op, sp, rs,
+                    it + run.astype(_I32))
 
-            def ibody(c):
-                cf, sink_cf, excess, lab, op, sp, rs, it = c
-                run = (it < limit) & region_active(excess, lab)      # [K]
-                ncf, nsink, nexc, nlab, d_cross, d_sink, rinc = \
-                    batched_iteration(cf, sink_cf, excess, lab, nbr_local,
-                                      rev_slot, intra, pushable, cross_lab,
-                                      vmask, d_inf)
-                w3, w2 = run[:, None, None], run[:, None]
-                cf = jnp.where(w3, ncf, cf)
-                sink_cf = jnp.where(w2, nsink, sink_cf)
-                excess = jnp.where(w2, nexc, excess)
-                lab = jnp.where(w2, nlab, lab)
-                op = op + jnp.where(w3, d_cross, 0)
-                sp = sp + jnp.where(run, d_sink, 0)
-                rs = rs + jnp.where(run, rinc, 0)
-                return (cf, sink_cf, excess, lab, op, sp, rs,
-                        it + run.astype(_I32))
-
-            init = (cf, sink_cf, excess, lab, zero_e, zero_k, zero_k, zero_k)
-            return jax.lax.while_loop(icond, ibody, init)
+        init = (cf, sink_cf, excess, lab, zero_e, zero_k, zero_k, zero_k)
+        return jax.lax.while_loop(icond, ibody, init)
 
     def cond(s: EngineState):
         run = region_active(s.excess, s.lab)
@@ -426,13 +356,9 @@ def _push_relabel_fused_batched(cf, sink_cf, excess, lab, *, nbr_local,
             limit = jnp.minimum(limit, jnp.asarray(max_iters, _I32) - s.iters)
         cf, sink_cf, excess, lab, dpush, dsink, drls, dit = launch(
             s.lab, s.cf, s.sink_cf, s.excess, limit)
-        # one real kernel launch covers every region on pallas; the fused
-        # XLA body is one compute program per advanced region-iteration
-        # (the scalar fused-xla accounting, summed over regions)
-        dln = jnp.ones((), _I32) if backend == "pallas" else dit.sum()
         return EngineState(cf, sink_cf, excess, lab, s.out_push + dpush,
                            s.sink_pushed + dsink, s.iters + dit,
-                           s.relabel_sum + drls, s.launches + dln)
+                           s.relabel_sum + drls, s.launches + dit.sum())
 
     init = EngineState(cf, sink_cf, excess, lab, zero_e, zero_k, zero_k,
                        zero_k, jnp.zeros((), _I32))
@@ -459,33 +385,22 @@ def push_relabel_batched(
     block_v: int | None = None,
     interpret: bool | None = None,
     chunk_iters: int | None = None,
-    vmem_budget_bytes: int | None = None,
-    grid2d: tuple[int, int] | None = None,
 ) -> EngineState:
     """Run push/relabel on all K regions of a sweep through one entry point.
 
     The batched counterpart of ``push_relabel``: per-region results (state,
     ``out_push``, iteration counts) are bit-identical to vmapping the
-    scalar engine, but the fused paths dispatch over regions collectively —
-    one ``grid=(K,)`` kernel launch per chunk on ``backend="pallas"``
-    instead of K independent launch sequences.  ``EngineState`` fields are
-    the [K]-batched forms except ``launches``, which is the global dispatch
-    count of this engine run.  Unfused configurations (``chunk_iters=None``)
-    and Pallas regions over the VMEM budget fall back to ``jax.vmap`` of
-    the scalar engine (per-region launch counts summed).
+    scalar engine.  ``EngineState`` fields are the [K]-batched forms except
+    ``launches``, which is the global dispatch count of this engine run.
+    Unfused configurations (``chunk_iters=None``) are ``jax.vmap`` of the
+    scalar engine (per-region launch counts summed); the fused driver runs
+    every region in one batched body.
 
     ``d_inf`` may be a scalar or per-region i32[K] (each region of a solve
-    batch keeps its own instance's ceiling).  ``grid2d=(B, Kr)`` renders
-    the fused pallas launch as a ``grid=(B, Kr)`` program over the flat
-    region axis ``K == B*Kr`` (the solve-batch form); results and launch
-    counts are unchanged.
+    batch keeps its own instance's ceiling).
     """
-    K, V, E = cf.shape
+    K = cf.shape[0]
     d_inf = jnp.asarray(d_inf, _I32)
-    if chunk_iters is not None and backend == "pallas" \
-            and not _pr_kernel.fused_region_fits_vmem(
-                V, E, vmem_budget_bytes, dtypes=_kernel_dtypes(cf, lab)):
-        chunk_iters = None
     if chunk_iters is None:
         d_inf_k = jnp.broadcast_to(d_inf, (K,))
         fn = lambda cf, s, e, l, nl, rs, it, em, vm, cp, cl, di: push_relabel(
@@ -501,8 +416,7 @@ def push_relabel_batched(
         cf, sink_cf, excess, lab, nbr_local=nbr_local, rev_slot=rev_slot,
         intra=intra, emask=emask, vmask=vmask, cross_pushable=cross_pushable,
         cross_lab=cross_lab, d_inf=d_inf, sink_open=sink_open,
-        max_iters=max_iters, backend=backend, chunk_iters=chunk_iters,
-        interpret=interpret, grid2d=grid2d)
+        max_iters=max_iters, chunk_iters=chunk_iters)
 
 
 def bfs_to_targets(

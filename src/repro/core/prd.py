@@ -47,19 +47,16 @@ def prd_discharge_batched(cf, sink_cf, excess, d, ghost_d, *, nbr_local,
                           rev_slot, intra, emask, vmask, d_inf,
                           max_iters: int | None = None,
                           backend: str = "xla",
-                          chunk_iters: int | None = None,
-                          grid2d: tuple[int, int] | None = None
+                          chunk_iters: int | None = None
                           ) -> DischargeResult:
     """PRD on all K regions of a parallel sweep, collectively.
 
     Batched counterpart of ``jax.vmap(prd_discharge_one)``: PRD is a single
     engine run per region, so this is one ``engine.push_relabel_batched``
-    call — on the fused pallas path, one grid-over-regions kernel launch
-    per chunk for the whole sweep.  Per-region results are bit-identical to
+    call for the whole sweep.  Per-region results are bit-identical to
     the vmapped scalar path; ``engine_launches`` is the global dispatch
     count.  ``d_inf`` may be a scalar or per-region i32[K] (a solve batch's
-    regions keep their own instance's label ceiling); ``grid2d`` renders
-    the fused pallas launch as the ``grid=(B, Kr)`` solve-batch program.
+    regions keep their own instance's label ceiling).
     """
     K, V, E = cf.shape
     cross = emask & ~intra
@@ -69,7 +66,7 @@ def prd_discharge_batched(cf, sink_cf, excess, d, ghost_d, *, nbr_local,
         nbr_local=nbr_local, rev_slot=rev_slot, intra=intra, emask=emask,
         vmask=vmask, cross_pushable=cross, cross_lab=ghost_d, d_inf=d_inf,
         sink_open=True, max_iters=max_iters, backend=backend,
-        chunk_iters=chunk_iters, grid2d=grid2d)
+        chunk_iters=chunk_iters)
     return DischargeResult(es.cf, es.sink_cf, es.excess, es.lab, es.out_push,
                            es.sink_pushed, es.iters,
                            jnp.ones((K,), _I32), es.launches)

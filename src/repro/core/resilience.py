@@ -32,11 +32,10 @@ fault matrix.
 
 **Degradation ladder.**  Accelerator resource exhaustion (VMEM or device
 memory) degrades the engine configuration one rung at a time —
-pallas-fused -> xla-fused -> xla-unfused (:func:`degrade_config`) —
-re-running the route on the next rung; every rung is bit-exact by the
-repo's engine-equivalence invariant, and every degradation is recorded
-in ``SweepStats.degraded`` (never silent).  The engine's build-time
-static VMEM fallback is surfaced the same way (:func:`vmem_fallback_note`).
+pallas-unfused -> xla-unfused and xla-fused -> xla-unfused
+(:func:`degrade_config`) — re-running the route on the next rung; every
+rung is bit-exact by the repo's engine-equivalence invariant, and every
+degradation is recorded in ``SweepStats.degraded`` (never silent).
 
 This module also owns the ONE atomic-snapshot implementation
 (:func:`snapshot_save`/:func:`snapshot_restore`/:func:`snapshot_latest`),
@@ -76,8 +75,8 @@ class PreemptionError(InjectedFault):
 
 
 class VmemOverflowError(RuntimeError):
-    """Kernel region state exceeds the VMEM budget (real or injected) —
-    a kernel-class failure the degradation ladder handles."""
+    """An engine kernel's working set exceeds the VMEM budget (real or
+    injected) — a kernel-class failure the degradation ladder handles."""
 
 
 class CheckpointMismatchError(ValueError):
@@ -86,10 +85,12 @@ class CheckpointMismatchError(ValueError):
 
 
 # --------------------------------------------------------------------------
-# degradation ladder: pallas-fused -> xla-fused -> xla-unfused
+# degradation ladder: pallas-unfused -> xla-unfused, xla-fused -> xla-unfused
 # --------------------------------------------------------------------------
 
-KERNEL_LADDER = ("pallas-fused", "xla-fused", "xla-unfused")
+# every rung a config can sit on (``SweepConfig`` refuses pallas with
+# engine_chunk_iters)
+KERNEL_LADDER = ("pallas-unfused", "xla-fused", "xla-unfused")
 
 
 def config_rung(cfg) -> str:
@@ -101,8 +102,8 @@ def config_rung(cfg) -> str:
 def degrade_config(cfg):
     """One rung down — or ``None`` at the bottom (nothing left to shed).
 
-    pallas anything -> same shape on xla (sheds the Pallas kernel);
-    xla-fused -> xla-unfused (sheds the chunked resident engine).  Every
+    pallas-unfused -> xla-unfused (sheds the Pallas kernel);
+    xla-fused -> xla-unfused (sheds the fused chunked engine).  Every
     rung computes bit-identical results (the repo's engine-equivalence
     invariant), so degradation changes performance, never answers.
     """
@@ -146,26 +147,6 @@ def run_with_degradation(run: Callable, cfg, notes: list[str]):
                 f"{config_rung(cfg)} -> {config_rung(nxt)}: "
                 f"{type(exc).__name__}: {exc}")
             cfg = nxt
-
-
-def vmem_fallback_note(cfg, region_size: int, max_degree: int,
-                       dtypes=None) -> str | None:
-    """Surface the engine's build-time static VMEM fallback.
-
-    The fused pallas engine silently falls back to the blocked two-phase
-    path when a region's resident state exceeds the VMEM budget
-    (``kernels.push_relabel.fused_region_fits_vmem``); this returns the
-    degradation note the drivers record in ``SweepStats.degraded`` so the
-    fallback is visible (results are bit-exact either way).
-    """
-    if cfg.engine_backend != "pallas" or cfg.engine_chunk_iters is None:
-        return None
-    from repro.kernels import push_relabel as _pr
-    if _pr.fused_region_fits_vmem(region_size, max_degree, dtypes=dtypes):
-        return None
-    return (f"pallas-fused: region state (V={region_size}, E={max_degree}) "
-            f"exceeds the VMEM budget; engine uses the blocked two-phase "
-            f"path (bit-exact)")
 
 
 # --------------------------------------------------------------------------
@@ -301,7 +282,7 @@ def solve_fingerprint(meta, cfg, salt: str = "") -> str:
     (the session front-end hashes ``Layout.part`` so two same-shaped
     problems do not cross-resume).  Engine-backend knobs, sweep budgets
     and accounting knobs are deliberately EXCLUDED: every backend rung and
-    every route computes bit-identical states, so resuming a pallas-fused
+    every route computes bit-identical states, so resuming a pallas
     device-resident solve on the xla host loop — or after a degradation —
     is exact and allowed.
     """
@@ -501,7 +482,7 @@ class FaultPlan:
             raise PreemptionError(f"injected preemption {where}")
         if self.kind == "vmem_overflow":
             raise VmemOverflowError(
-                f"injected VMEM overflow {where}: fused region state "
+                f"injected VMEM overflow {where}: engine working set "
                 f"exceeds the VMEM budget")
         # corrupt_labels: pin boundary labels at the ceiling — excess
         # trapped there goes inactive, the solve stops early with a
@@ -644,5 +625,5 @@ __all__ = [
     "load_checkpoint", "resolve_resume", "restore_state",
     "run_with_degradation", "save_checkpoint", "snapshot_latest",
     "snapshot_manifest", "snapshot_restore", "snapshot_save",
-    "solve_fingerprint", "state_payload", "vmem_fallback_note",
+    "solve_fingerprint", "state_payload",
 ]

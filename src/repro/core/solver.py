@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import autotune as _autotune
 from repro.core import batch as _batch
 from repro.core import distributed as _distributed
 from repro.core import dtypes as _dt
@@ -111,12 +110,6 @@ class SolverOptions:
         ``ProblemValidationError`` at ``prepare`` time.  Narrowed handles
         re-check the flow bound on every ``update`` (capacity growth can
         outgrow int16; topology — hence the label bound — cannot change).
-    autotune — resolve ``engine_chunk_iters`` (and fused-vs-blocked
-        dispatch) per ``(bucket dims, backend, dtypes)`` key through the
-        VMEM-budget autotuner (``core.autotune``) instead of the static
-        default.  An explicitly pinned ``engine_chunk_iters`` wins over
-        the tuner; tuned decisions persist in a JSON cache so repeat keys
-        cost zero search and zero retrace.
     streaming — route solves through the out-of-core streaming executor
         (``repro.stream``): regions are staged one at a time from a disk
         spill pool, at most ``max_resident_regions`` region states are in
@@ -148,7 +141,6 @@ class SolverOptions:
     check: bool = True
     warm_labels: bool | str = "auto"
     dtype_policy: str = "int32"
-    autotune: bool = False
     # --- sharded-route knobs ---
     exchange: str = "full"
     # --- streaming-route knobs ---
@@ -496,7 +488,7 @@ class ProblemHandle:
 
         Accelerator resource exhaustion (``resilience.is_kernel_failure``)
         degrades the engine configuration one ladder rung at a time
-        (pallas-fused -> xla-fused -> xla-unfused,
+        (pallas -> xla, xla-fused -> xla-unfused,
         ``resilience.degrade_config``) and re-runs — every rung is
         bit-exact, and each degradation is recorded in ``stats.degraded``,
         never silent.  Any other failure, a kernel the compiler refuses
@@ -521,8 +513,6 @@ class ProblemHandle:
     def _solve(self, mesh, axes, checkpoint, resume_from, on_sweep):
         opts = self.solver.options
         cfg = opts.sweep_config()
-        if opts.autotune:
-            cfg = _autotune.tuned_sweep_config(cfg, self.meta)
         salt = self._layout_salt()
         if isinstance(checkpoint, (str, Path)):
             checkpoint = _res.CheckpointPolicy(directory=checkpoint)
@@ -757,11 +747,8 @@ class Solver:
         results: list[MincutResult | None] = [None] * len(handles)
         self.last_batch_stats = []
         for packed in packs:
-            cfg_b = cfg
-            if self.options.autotune:
-                cfg_b = _autotune.tuned_sweep_config(cfg, packed.meta)
             bstate, bstats = _batch.solve_batch(
-                packed, cfg_b, checkpoint=checkpoint, resume_from=resume_from,
+                packed, cfg, checkpoint=checkpoint, resume_from=resume_from,
                 salt=salt)
             self.last_batch_stats.append(bstats)
             for b, idx in enumerate(packed.indices):

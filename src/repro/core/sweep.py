@@ -24,8 +24,7 @@ device-resident driver (``SweepConfig.device_resident``) runs the whole
 loop — discharge, fusion, heuristics, convergence check and statistics —
 inside one ``lax.while_loop``, syncing to the host once per
 ``host_sync_every`` sweeps (default: once per solve).  Parallel sweeps
-discharge through the *batched* operators (grid-over-regions kernel: one
-launch covers all K regions) instead of vmapping the per-region path.
+discharge all K regions through the *batched* operators.
 
 Both drivers are thin composition over the generic region-executor loop
 (``core.executor``): ``solve`` instantiates ``executor.LocalExecutor``
@@ -88,14 +87,14 @@ class SweepConfig:
     max_sweeps          — hard cap (defaults to the theoretical bound).
     engine_max_iters    — safety cap for the inner engine (None = unbounded).
     engine_backend      — compute-phase backend of the discharge engine:
-                          "xla" (dense rows) or "pallas" (fused kernel,
-                          interpret mode off-TPU); bit-identical results.
-    engine_chunk_iters  — fused chunked engine: k complete iterations per
-                          compute-program launch with region state resident
-                          (one pallas_call per chunk on the "pallas"
-                          backend, one traced body per iteration on "xla");
-                          None keeps the unfused two-phase engine.  All
-                          combinations are bit-identical.
+                          "xla" (dense rows) or "pallas" (the blocked
+                          kernel, interpret mode off-TPU); bit-identical
+                          results.
+    engine_chunk_iters  — fused XLA engine: up to k complete iterations per
+                          outer engine trip, one traced body per iteration;
+                          None keeps the unfused two-phase engine.  XLA
+                          only: with engine_backend="pallas" it raises
+                          ``ValueError``.  Bit-identical to unfused.
     device_resident     — run the whole solve loop (discharge, fusion, gap
                           heuristic, convergence check, statistics) inside
                           one ``lax.while_loop`` on device instead of one
@@ -129,6 +128,13 @@ class SweepConfig:
         assert self.method in ("ard", "prd")
         assert self.engine_backend in ENGINE_BACKENDS
         assert self.engine_chunk_iters is None or self.engine_chunk_iters >= 1
+        if self.engine_backend == "pallas" \
+                and self.engine_chunk_iters is not None:
+            raise ValueError(
+                "engine_backend='pallas' takes no engine_chunk_iters: the "
+                "fused chunked engine is XLA only (use engine_backend='xla' "
+                "with engine_chunk_iters, or engine_backend='pallas' with "
+                "engine_chunk_iters=None)")
         assert self.host_sync_every is None or self.host_sync_every >= 1
         assert self.stats_ring_size >= 1
 
@@ -154,9 +160,7 @@ class SweepStats:
     #                              distinct ghost label, ``ard.py``), summed
     #                              over sweeps and regions
     engine_launches: int | None = 0   # compute-program dispatches (2/iter
-    #                              unfused; fused: 1/chunk-trip pallas —
-    #                              batched over all regions of a parallel
-    #                              sweep — 1/iter xla)
+    #                              unfused, 1/iter fused)
     host_syncs: int = 0          # device->host transfers of the solve loop
     #                              (host loop: 1 + 1/sweep; device-resident:
     #                              1 per host_sync_every sweeps, 1 total by
@@ -181,8 +185,7 @@ class SweepStats:
     #                              vertices left (see MincutResult.diagnosis)
     degraded: list = dataclasses.field(default_factory=list)
     #                              engine degradations taken mid-solve
-    #                              (resilience ladder rungs, static VMEM
-    #                              fallbacks) — never silent
+    #                              (resilience ladder rungs) — never silent
 
 
 _STAT_KEYS = ("sweeps", "engine_iters", "ard_stages", "engine_launches",
@@ -208,10 +211,8 @@ def _d_inf(meta: GraphMeta, cfg: SweepConfig) -> int:
 def _discharge_all(meta: GraphMeta, state: FlowState, cfg: SweepConfig,
                    ghost_d: jax.Array, stage_cap):
     """Discharge all regions of a parallel sweep through the batched entry
-    points (``ard_discharge_batched``/``prd_discharge_batched``) — one
-    grid-over-regions kernel launch per engine chunk on the fused pallas
-    path instead of vmapping K per-region launch sequences.  Per-region
-    results are bit-identical to the vmapped scalar path;
+    points (``ard_discharge_batched``/``prd_discharge_batched``).
+    Per-region results are bit-identical to the vmapped scalar path;
     ``DischargeResult.engine_launches`` is the sweep's global dispatch
     count.
     """
@@ -596,10 +597,6 @@ def solve(meta: GraphMeta, state: FlowState, cfg: SweepConfig | None = None,
         state, stats = _solve_host(
             meta, state, cfg, ex, on_sweep=on_sweep, fp=fp,
             checkpoint=checkpoint, ckpt=ckpt)
-    note = _res.vmem_fallback_note(cfg, state.cf.shape[1], state.cf.shape[2],
-                                   dtypes=meta.kernel_dtypes)
-    if note is not None and note not in stats.degraded:
-        stats.degraded.append(note)
     stats.num_boundary = meta.num_boundary
     if cfg.method != "ard":
         stats.ard_stages = None

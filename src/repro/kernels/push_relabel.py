@@ -25,21 +25,13 @@ mask semantics (``cross_pushable``/``emask``/``vmask``/``sink_open``); the
 ``backend="pallas"`` path of ``repro.core.engine.push_relabel`` calls it
 twice per iteration (pre-push for the deltas, post-push for the relabels).
 
-Region-resident fused kernel (interpret mode only)
---------------------------------------------------
-``fused_engine_run_batched`` is one ``grid=(K,)`` (or ``grid=(B, K)`` for a
-solve batch) ``pallas_call`` whose block is a *whole region*: each program
-instance advances its region up to ``iter_limit`` complete engine
-iterations — push split, intra-region apply, post-push relabel — with
-the state resident and a per-region early exit.  ``fused_engine_run`` is
-its single-region form.  Its body gathers ``lab[nbr]`` and the reverse
-arcs' pushes and takes a cumsum inside the kernel, which Mosaic refuses
-("Only 2D gather is supported" is the first refusal;
-tests/test_tpu_compile.py lowers it).  It
-therefore runs only under the Pallas interpreter, where it is bit-exact
-against the other engine routes; on a TPU ``core.engine`` refuses the route
-up front.  ``core.engine`` also falls back to the blocked path when a
-region exceeds the VMEM budget (``fused_region_fits_vmem``).
+Shared engine bodies
+--------------------
+``make_push_apply`` is the one formulation of the push application (every
+engine route applies through it), and ``make_fused_iteration`` is the whole
+iteration — push split, apply, post-push relabel — as one traced function:
+the body of the fused XLA engine (``engine_chunk_iters`` on
+``backend="xla"``).
 """
 
 from __future__ import annotations
@@ -48,7 +40,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import dtypes as _dt
@@ -174,47 +165,6 @@ def push_relabel_phase(lab, cf, sink_cf, excess, nbr, intra, pushable,
     return delta, new_lab
 
 
-# --------------------------------------------------------------------------
-# Region-resident fused discharge: k full iterations per kernel launch.
-# --------------------------------------------------------------------------
-
-# VMEM working set of one fused iteration, per value family.  [V, E]
-# arrays: cf, out_push, d_arc, pulled credits carry flow; nbr, rev_slot
-# are int32 indices; intra, pushable are masks; cross_lab carries labels.
-# caps/delta are flow-valued [V, 1+E].  [V] vectors: sink_cf, excess,
-# avail carry flow; lab, new_lab carry labels; vmask is a mask; plus two
-# int32 scalar/misc words per row.  The budget leaves headroom under the
-# ~16 MiB/core of TPU VMEM for double buffering and the scalar plumbing.
-FUSED_VMEM_BUDGET_BYTES = 12 * 2**20
-
-
-def fused_region_vmem_bytes(V: int, E: int,
-                            dtypes: _dt.KernelDtypes | None = None) -> int:
-    """Estimated VMEM bytes of the region-resident fused kernel's state.
-
-    Dtype-aware: each value family is costed at its own itemsize (the old
-    formula hard-coded 4-byte words for everything, so it over-estimated
-    the narrow configurations and would have kept them on the blocked
-    path).  With all-int32 dtypes this is exactly the historical
-    ``4 * (9*V*E + 2*V*(E+1) + 8*V)``.
-    """
-    kd = _dt.WIDE if dtypes is None else dtypes
-    fb = np.dtype(kd.flow).itemsize
-    lb = np.dtype(kd.label).itemsize
-    mb = np.dtype(kd.mask).itemsize
-    return (fb * (4 * V * E + 2 * V * (E + 1) + 3 * V)   # flow values
-            + 4 * (2 * V * E + 2 * V)                    # int32 indices/misc
-            + mb * (2 * V * E + V)                       # masks
-            + lb * (V * E + 2 * V))                      # labels
-
-
-def fused_region_fits_vmem(V: int, E: int,
-                           budget_bytes: int | None = None,
-                           dtypes: _dt.KernelDtypes | None = None) -> bool:
-    budget = FUSED_VMEM_BUDGET_BYTES if budget_bytes is None else budget_bytes
-    return fused_region_vmem_bytes(V, E, dtypes) <= budget
-
-
 def make_push_apply(*, nbr, rev_slot, intra):
     """Build the apply step of one engine iteration (everything but the sink).
 
@@ -256,17 +206,16 @@ def make_push_apply(*, nbr, rev_slot, intra):
 
 def make_fused_iteration(*, nbr, rev_slot, intra, pushable, cross_lab, vmask,
                          d_inf, sink_open: bool):
-    """Build the pure fused-iteration function shared by both backends.
+    """Build the pure fused-iteration function of the fused XLA engine.
 
     ``iteration(cf, sink_cf, excess, lab) -> (cf, sink_cf, excess, new_lab,
     d_cross, d_sink_total, relabel_inc)`` performs push compute (labels
     frozen), the push application (``make_push_apply``: reverse arcs and
     receiver excess pulled through ``rev_slot``) and the post-push relabel
-    in one function — the per-step unit of the region-resident kernel and of
-    the fused XLA engine body.  Defining it once is what makes the two
-    fused backends bit-exact by construction; ``kernels.ref.
-    fused_iteration_ref`` stays the independent oracle.  ``intra``/
-    ``pushable``/``vmask`` are bool, ``d_inf`` an i32 scalar.
+    in one function — one compute program per iteration where the unfused
+    engine dispatches two phase calls.  ``kernels.ref.fused_iteration_ref``
+    stays the independent oracle.  ``intra``/``pushable``/``vmask`` are
+    bool, ``d_inf`` an i32 scalar.
     """
     apply = make_push_apply(nbr=nbr, rev_slot=rev_slot, intra=intra)
     inf = _inf_for(cross_lab.dtype)
@@ -310,166 +259,6 @@ def make_fused_iteration(*, nbr, rev_slot, intra, pushable, cross_lab, vmask,
                 jnp.sum(d_sink, dtype=jnp.int32), relabel_inc)
 
     return iteration
-
-
-def _fused_region_loop(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
-                       pushable, cross_lab, vmask, d_inf, limit, *,
-                       sink_open: bool):
-    """Up to ``limit`` fused engine iterations on one region's arrays.
-
-    One iteration is bit-identical to one trip of the unfused engine loop
-    (push compute -> push apply -> post-push relabel); the while_loop
-    exits early once no vertex is active, so idle regions cost O(1).  This
-    is the shared in-kernel body of the single-region (``grid=()``) and the
-    grid-over-regions (``grid=(K,)``) fused kernels.
-    """
-    V, E = cf.shape
-    vmask = vmask != 0
-    d_inf = jnp.asarray(d_inf).astype(lab.dtype)
-    iteration = make_fused_iteration(
-        nbr=nbr, rev_slot=rev_slot, intra=intra != 0,
-        pushable=pushable != 0, cross_lab=cross_lab,
-        vmask=vmask, d_inf=d_inf, sink_open=sink_open)
-
-    def body(carry):
-        cf, sink_cf, excess, lab, out_push, sinkp, rls, it = carry
-        cf, sink_cf, excess, lab, d_cross, d_sink, rinc = iteration(
-            cf, sink_cf, excess, lab)
-        return (cf, sink_cf, excess, lab, out_push + d_cross,
-                sinkp + d_sink, rls + rinc, it + 1)
-
-    def cond(carry):
-        cf, sink_cf, excess, lab, out_push, sinkp, rls, it = carry
-        return (it < limit) & ((excess > 0) & (lab < d_inf) & vmask).any()
-
-    z = jnp.zeros((), jnp.int32)
-    init = (cf, sink_cf, excess, lab, jnp.zeros((V, E), cf.dtype), z, z, z)
-    return jax.lax.while_loop(cond, body, init)
-
-
-def _fused_kernel_grid(lab_ref, cf_ref, sink_cf_ref, excess_ref, nbr_ref,
-                       rev_ref, intra_ref, pushable_ref, cross_lab_ref,
-                       vmask_ref, scal_ref, cf_out, sink_out, exc_out,
-                       lab_out, push_out, sinkp_out, rls_out, it_out, *,
-                       sink_open: bool, nlead: int):
-    """Grid program instance: region ``pl.program_id(0)`` (``grid=(K,)``)
-    or region (``pl.program_id(0)``, ``pl.program_id(1)``) of a solve batch
-    (``grid=(B, K)``).
-
-    Every ref carries ``nlead`` leading block dimensions of 1 (one region's
-    tile); per-vertex ``[V]`` values ride as ``[1, V]`` rows and scalars as
-    ``[1, 1]`` so each block's last two dimensions are whole array
-    dimensions, as the TPU tiling requires.  ``scal_ref`` is this region's
-    (d_inf, iter_limit) row.  The in-kernel early exit makes an idle or
-    already-converged region cost O(1), so one launch can mix hot and idle
-    regions — and converged and running instances — freely.
-    """
-    z = (0,) * nlead
-    row = z + (0,)
-    scal = scal_ref[row]
-    cf, sink_cf, excess, lab, out_push, sinkp, rls, it = _fused_region_loop(
-        lab_ref[row], cf_ref[z], sink_cf_ref[row], excess_ref[row],
-        nbr_ref[z], rev_ref[z], intra_ref[z], pushable_ref[z],
-        cross_lab_ref[z], vmask_ref[row], scal[0], scal[1],
-        sink_open=sink_open)
-    cf_out[z] = cf
-    sink_out[row] = sink_cf
-    exc_out[row] = excess
-    lab_out[row] = lab
-    push_out[z] = out_push
-    for ref, val in ((sinkp_out, sinkp), (rls_out, rls), (it_out, it)):
-        ref[...] = jnp.full(ref.shape, val, ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("sink_open", "interpret"))
-def fused_engine_run(lab, cf, sink_cf, excess, nbr, rev_slot, intra, pushable,
-                     cross_lab, vmask, d_inf, iter_limit, *,
-                     sink_open: bool = True, interpret: bool = True):
-    """Run up to ``iter_limit`` fused engine iterations in one kernel launch.
-
-    Region-resident mode: ``block_v = V`` (the caller guarantees
-    ``fused_region_fits_vmem``).  Masks are int32 (0/1) for portable Pallas
-    lowering; ``iter_limit`` is dynamic so the driver can clamp the last
-    chunk to a ``max_iters`` cap.  The single-region convenience form of
-    ``fused_engine_run_batched`` (K = 1 grid, same kernel body).  Returns
-    the post-chunk region state plus this launch's accumulators:
-    ``(cf, sink_cf, excess, lab, out_push, sink_pushed, relabel_sum, iters)``.
-    """
-    one = lambda a: a[None]
-    outs = fused_engine_run_batched(
-        one(lab), one(cf), one(sink_cf), one(excess), one(nbr),
-        one(rev_slot), one(intra), one(pushable), one(cross_lab), one(vmask),
-        d_inf, jnp.reshape(jnp.asarray(iter_limit, jnp.int32), (1,)),
-        sink_open=sink_open, interpret=interpret)
-    return tuple(o[0] for o in outs)
-
-
-@functools.partial(jax.jit, static_argnames=("sink_open", "interpret"))
-def fused_engine_run_batched(lab, cf, sink_cf, excess, nbr, rev_slot, intra,
-                             pushable, cross_lab, vmask, d_inf, iter_limit, *,
-                             sink_open: bool = True, interpret: bool = True):
-    """All regions of a sweep — or of a solve batch — in ONE kernel launch.
-
-    The grid-over-regions variant of ``fused_engine_run``: with
-    ``[K, V, E]`` inputs the program is ``grid=(K,)`` and instance k owns
-    region k's ``[V, E]`` tile; with ``[B, K, V, E]`` inputs it is
-    ``grid=(B, K)`` and instance (b, k) owns region k of solve-batch
-    instance b.  Each advances its tile up to ``iter_limit[...]`` complete
-    fused engine iterations with per-region in-kernel early exit — an idle
-    region (or every region of a converged instance) costs O(1).
-    ``d_inf`` and ``iter_limit`` broadcast against the lead shape, so each
-    batch instance keeps its own label ceiling and iteration budget (the
-    driver's per-instance convergence flag is a zeroed budget).
-    Per-region results are bit-identical to separate ``fused_engine_run``
-    calls; what changes is the dispatch count: one launch instead of K
-    (resp. B*K).
-
-    Per-vertex values ride as ``[*lead, 1, V]`` rows and scalars as
-    ``[*lead, 1, 1]`` so each block's last two dimensions are whole array
-    dimensions, as the TPU tiling requires; Mosaic then reaches the body
-    and refuses it (see the module docstring).
-
-    Returns ``(cf, sink_cf, excess, lab, out_push, sink_pushed [lead],
-    relabel_sum [lead], iters [lead])`` where ``lead`` = ``(K,)`` or
-    ``(B, K)``.
-    """
-    lead = cf.shape[:-2]
-    V, E = cf.shape[-2:]
-    nlead = len(lead)
-    assert nlead in (1, 2), cf.shape
-    scal = jnp.stack(
-        [jnp.broadcast_to(jnp.asarray(d_inf, jnp.int32), lead),
-         jnp.broadcast_to(jnp.asarray(iter_limit, jnp.int32), lead)],
-        axis=-1)                                           # [*lead, 2]
-    blk = lambda *tail: pl.BlockSpec(
-        (1,) * nlead + tail, lambda *ids: ids + (0,) * len(tail))
-    vec, scl = blk(1, V), blk(1, 1)
-    mat = blk(V, E)
-    row = lambda a: a.reshape(lead + (1, V))
-    lab, sink_cf, excess, vmask = map(row, (lab, sink_cf, excess, vmask))
-    outs = pl.pallas_call(
-        functools.partial(_fused_kernel_grid, sink_open=sink_open,
-                          nlead=nlead),
-        grid=lead,
-        in_specs=[vec, mat, vec, vec, mat, mat, mat, mat, mat, vec,
-                  blk(1, 2)],
-        out_specs=[mat, vec, vec, vec, mat, scl, scl, scl],
-        out_shape=[
-            jax.ShapeDtypeStruct(lead + (V, E), cf.dtype),       # cf
-            jax.ShapeDtypeStruct(lead + (1, V), sink_cf.dtype),  # sink_cf
-            jax.ShapeDtypeStruct(lead + (1, V), excess.dtype),   # excess
-            jax.ShapeDtypeStruct(lead + (1, V), lab.dtype),      # lab
-            jax.ShapeDtypeStruct(lead + (V, E), cf.dtype),       # out_push
-            jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # sink_pushed
-            jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # relabel_sum
-            jax.ShapeDtypeStruct(lead + (1, 1), jnp.int32),   # iters
-        ],
-        interpret=interpret,
-    )(lab, cf, sink_cf, excess, nbr, rev_slot, intra, pushable, cross_lab,
-      vmask, scal.reshape(lead + (1, 2)))
-    cf, sink_cf, excess, lab, out_push, sinkp, rls, it = outs
-    vecs = (a.reshape(lead + (V,)) for a in (sink_cf, excess, lab))
-    return (cf, *vecs, out_push, *(a.reshape(lead) for a in (sinkp, rls, it)))
 
 
 def engine_phase(lab, cf, sink_cf, excess, *, nbr_local, intra, emask, vmask,

@@ -7,8 +7,7 @@
   push-relabel kernel (kernels/push_relabel.py).
 * ``fused_iteration_ref`` — pure-jnp oracle for one *complete* fused engine
   iteration (push compute + intra-region scatter + post-push relabel), the
-  unit the region-resident fused kernel advances per in-kernel step.  It
-  keeps the push-side scatter form of the apply; the engine pulls
+  unit the fused XLA engine advances per step.  It keeps the push-side scatter form of the apply; the engine pulls
   (``kernels.push_relabel.make_push_apply``), so the two agree exactly on
   layouts whose ``rev_slot`` pairs intra arcs.
 * ``attention_ref`` — pure-jnp oracle for the Pallas flash-attention kernel.
@@ -131,8 +130,8 @@ def fused_iteration_ref(cf, sink_cf, excess, lab, nbr, rev_slot, intra,
     push compute (labels frozen) -> scatter application of the deltas
     (reverse arcs + receiver excess for intra arcs; cross flow accumulated
     into ``out_push``) -> relabel on the post-push residual graph.  This is
-    the per-step unit of the region-resident fused kernel and of the fused
-    XLA engine body; both are tested bit-equal against it.
+    the per-step unit of the fused XLA engine body, which is tested
+    bit-equal against it.
 
     Returns ``(cf, sink_cf, excess, new_lab, out_push, sink_pushed,
     relabel_sum)``.

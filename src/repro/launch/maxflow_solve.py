@@ -12,8 +12,7 @@ Batched throughput mode solves a fleet of instances through the
 shape-bucketed batched driver (one grid=(B,K) device program per bucket):
 
     PYTHONPATH=src python -m repro.launch.maxflow_solve \
-        --batch 64x64,64x64,48x48 --regions 2x2 \
-        --engine-backend pallas --engine-chunk-iters 8
+        --batch 64x64,64x64,48x48 --regions 2x2 --engine-chunk-iters 8
 
 Each HxW entry becomes one synthetic instance (seeds --seed, --seed+1,
 ...); per-instance results are bit-identical to single solves.  DIMACS
@@ -104,14 +103,13 @@ def main():
     ap.add_argument("--engine-backend", choices=list(ENGINE_BACKENDS),
                     default="xla",
                     help="discharge-engine compute phase: dense XLA rows or "
-                         "the fused Pallas kernel (interpret mode off-TPU)")
+                         "the blocked Pallas kernel (interpret mode "
+                         "off-TPU)")
     ap.add_argument("--engine-chunk-iters", type=int, default=None,
                     metavar="K",
-                    help="region-resident fused engine: K complete "
-                         "iterations per compute-program launch (in-kernel "
-                         "early exit; falls back to the blocked path when "
-                         "the region exceeds the VMEM budget); default: "
-                         "unfused two-phase engine")
+                    help="fused XLA engine: up to K complete iterations per "
+                         "engine trip, one traced body each (xla backend "
+                         "only); default: unfused two-phase engine")
     ap.add_argument("--device-resident", action="store_true",
                     help="run the whole sweep loop in one lax.while_loop "
                          "on device: one host sync per solve instead of "
@@ -133,12 +131,6 @@ def main():
                          "to int8 when this instance's range bounds allow, "
                          "per-family int32 fallback), or narrow (forced; a "
                          "failed bound is a ProblemValidationError)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="resolve engine_chunk_iters through the "
-                         "VMEM-budget autotuner (core.autotune; JSON-cached "
-                         "per bucket dims/backend/dtypes — repeat keys cost "
-                         "zero search and zero retrace); an explicit "
-                         "--engine-chunk-iters wins over the tuner")
     ap.add_argument("--no-check", action="store_true",
                     help="skip the host-side cut-cost == flow assertion "
                          "(an extra device fetch + O(n*E) host reduction "
@@ -173,6 +165,16 @@ def main():
     from repro.data.grids import segmentation_seeds_volume, synthetic_grid
     from repro.launch.cache import enable_compile_cache
 
+    try:
+        cfg = SweepConfig(method=args.method,
+                          parallel=not (args.sequential or args.streaming),
+                          use_global_gap=not args.streaming,
+                          engine_backend=args.engine_backend,
+                          engine_chunk_iters=args.engine_chunk_iters,
+                          device_resident=args.device_resident,
+                          host_sync_every=args.host_sync_every)
+    except ValueError as exc:
+        ap.error(str(exc))
     enable_compile_cache()
     neighbours = {6: "faces", 26: "all"}[args.neighbours]
     regions = tuple(int(v) for v in (
@@ -194,13 +196,6 @@ def main():
         if not args.sequential:
             print("[maxflow] --streaming implies the sequential sweep "
                   "without the global gap heuristic (Alg. 1 staged order)")
-    cfg = SweepConfig(method=args.method,
-                      parallel=not (args.sequential or args.streaming),
-                      use_global_gap=not args.streaming,
-                      engine_backend=args.engine_backend,
-                      engine_chunk_iters=args.engine_chunk_iters,
-                      device_resident=args.device_resident,
-                      host_sync_every=args.host_sync_every)
 
     checkpoint = resume_from = None
     if args.resume and not args.checkpoint_dir:
@@ -254,7 +249,7 @@ def main():
 
         solver = Solver(SolverOptions.from_sweep_config(
             cfg, num_regions=num_regions, check=not args.no_check,
-            dtype_policy=args.dtype_policy, autotune=args.autotune))
+            dtype_policy=args.dtype_policy))
         t0 = time.time()
         results = solver.solve_many(probs, parts)
         dt = time.time() - t0
@@ -286,8 +281,7 @@ def main():
 
     solver = Solver(SolverOptions.from_sweep_config(
         cfg, num_regions=num_regions, check=not args.no_check,
-        dtype_policy=args.dtype_policy, autotune=args.autotune,
-        streaming=args.streaming,
+        dtype_policy=args.dtype_policy, streaming=args.streaming,
         max_resident_regions=args.max_resident_regions,
         spill_dir=args.spill_dir))
     handle = solver.prepare(prob, part)

@@ -1,7 +1,7 @@
 """Circuit breakers over the kernel degradation ladder.
 
-``core.resilience.run_with_degradation`` steps pallas -> xla-fused ->
-xla-unfused *within one solve* when a kernel faults.  A service replays
+``core.resilience.run_with_degradation`` steps pallas -> xla and
+xla-fused -> xla-unfused *within one solve* when a kernel faults.  A service replays
 that discovery on every request: a rung that is persistently broken (a
 driver wedged, VMEM exhausted by a cotenant) keeps faulting, and each
 fault costs a failed chunk launch before the ladder steps down.  The

@@ -35,7 +35,7 @@ The robustness layer, each with its typed outcome and counter:
   checkpointed (``resilience.snapshot_save``) and transparently resumed
   warm on their next request;
 * **circuit breaker** — kernel-class chunk failures walk the
-  pallas -> xla-fused -> xla-unfused ladder as usual, but a rung that
+  pallas -> xla / xla-fused -> xla-unfused ladder as usual, but a rung that
   keeps failing is *opened* and skipped at chunk entry for a cooldown
   (``serve.breaker``), so a wedged backend stops costing a failed launch
   per chunk;

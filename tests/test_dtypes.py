@@ -1,9 +1,7 @@
 """Dtype-narrowing correctness: policy selection, validation guards,
-byte accounting, bit-exactness vs the int32 oracle on every route, and
-autotuner determinism (PR 9)."""
+byte accounting and bit-exactness vs the int32 oracle on every route."""
 
 import dataclasses
-import json
 
 import numpy as np
 import pytest
@@ -12,14 +10,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import (Solver, SolverOptions, grid_partition, solve_mincut)
-from repro.core import autotune as _autotune
 from repro.core import dtypes as _dt
 from repro.core.graph import (ProblemValidationError, build,
                               validate_problem, validate_update_dtypes)
 from repro.core.sweep import SweepConfig, _page_and_msg_bytes
 from repro.data.grids import synthetic_grid
-from repro.kernels.push_relabel import (FUSED_VMEM_BUDGET_BYTES,
-                                        fused_region_vmem_bytes)
 
 
 def _small_problem(seed=1):
@@ -127,18 +122,6 @@ class TestUpdateGuard:
 # ------------------------------------------------------- byte accounting
 
 class TestByteAccounting:
-    def test_wide_vmem_matches_historical_formula(self):
-        for V, E in [(64, 4), (256, 8), (1024, 8), (4096, 16)]:
-            assert fused_region_vmem_bytes(V, E) \
-                == fused_region_vmem_bytes(V, E, _dt.WIDE) \
-                == 4 * (9 * V * E + 2 * V * (E + 1) + 8 * V)
-
-    def test_narrow_vmem_cut_at_least_40_percent_for_32sq_region(self):
-        V, E = 32 * 32, 8
-        wide = fused_region_vmem_bytes(V, E, _dt.WIDE)
-        narrow = fused_region_vmem_bytes(V, E, _dt.NARROW)
-        assert narrow <= 0.60 * wide, (narrow, wide)
-
     def test_page_and_msg_bytes_wide_matches_historical(self):
         p, part = _small_problem()
         meta, state, _ = build(p, part)
@@ -249,77 +232,6 @@ class TestCompileCacheKeys:
         assert len(packs) == 2            # same dims, different dtypes
 
 
-# -------------------------------------------------------------- autotune
-
-class TestAutotuner:
-    def test_same_key_same_config_and_cache_persistence(self, tmp_path):
-        cache = tmp_path / "at.json"
-        kd = _dt.NARROW
-        tc1 = _autotune.tune(256, 8, backend="pallas", dtypes=kd,
-                             cache=cache)
-        tc2 = _autotune.tune(256, 8, backend="pallas", dtypes=kd,
-                             cache=cache)
-        assert tc1 == tc2
-        stored = json.loads(cache.read_text())
-        key = _autotune.tune_key(256, 8, "pallas", kd)
-        assert key in stored
-        assert stored[key]["engine_chunk_iters"] == tc1.engine_chunk_iters
-
-    def test_tuned_config_within_budget(self, tmp_path):
-        for kd in (_dt.WIDE, _dt.NARROW):
-            tc = _autotune.tune(1024, 8, backend="pallas", dtypes=kd,
-                                cache=tmp_path / "at.json")
-            if tc.fused:
-                assert tc.vmem_bytes <= FUSED_VMEM_BUDGET_BYTES
-
-    def test_dtype_narrowing_extends_fused_range(self, tmp_path):
-        # a region over budget wide but in budget narrow must flip fused
-        V, E = 8192, 16
-        budget = fused_region_vmem_bytes(V, E, _dt.NARROW) + 1
-        tw = _autotune.tune(V, E, backend="pallas", dtypes=_dt.WIDE,
-                            vmem_budget_bytes=budget,
-                            cache=tmp_path / "a.json")
-        tn = _autotune.tune(V, E, backend="pallas", dtypes=_dt.NARROW,
-                            vmem_budget_bytes=budget,
-                            cache=tmp_path / "a.json")
-        assert not tw.fused and tn.fused
-
-    def test_user_pin_beats_tuner(self, tmp_path, monkeypatch):
-        monkeypatch.setenv(_autotune.CACHE_ENV,
-                           str(tmp_path / "at.json"))
-        cfg = SweepConfig(engine_chunk_iters=3, engine_backend="pallas")
-        p, part = _small_problem()
-        meta, _, _ = build(p, part)
-        assert _autotune.tuned_sweep_config(cfg, meta) is cfg
-
-    def test_zero_retrace_on_repeat_key(self, monkeypatch, tmp_path,
-                                        fresh_compile_cache):
-        monkeypatch.setenv(_autotune.CACHE_ENV,
-                           str(tmp_path / "at.json"))
-        p, part = _small_problem()
-        s = Solver(SolverOptions(autotune=True, engine_backend="pallas",
-                                 dtype_policy="narrow"))
-        h1 = s.prepare(p, part)
-        r1 = h1.solve()
-        traces_after_first = s.cache_info().traces
-        h2 = s.prepare(p, part)
-        r2 = h2.solve()
-        assert s.cache_info().traces == traces_after_first
-        assert r1.flow_value == r2.flow_value
-
-    def test_solve_results_unchanged_by_autotune(self, monkeypatch,
-                                                 tmp_path):
-        monkeypatch.setenv(_autotune.CACHE_ENV,
-                           str(tmp_path / "at.json"))
-        p, part = _small_problem()
-        base = Solver(SolverOptions()).prepare(p, part).solve()
-        tuned = Solver(SolverOptions(autotune=True)) \
-            .prepare(p, part).solve()
-        assert tuned.flow_value == base.flow_value
-        assert tuned.stats.sweeps == base.stats.sweeps
-        assert tuned.stats.engine_iters == base.stats.engine_iters
-
-
 # --------------------------------------------------------------- CLI/API
 
 class TestFrontEnds:
@@ -329,7 +241,7 @@ class TestFrontEnds:
         assert res.meta.kernel_dtypes == _dt.WIDE
 
     def test_options_roundtrip(self):
-        o = SolverOptions(dtype_policy="auto", autotune=True)
+        o = SolverOptions(dtype_policy="auto", check=False)
         assert o.sweep_config() == SweepConfig()     # session knobs only
         o2 = dataclasses.replace(o, dtype_policy="int32")
-        assert o2.autotune is True
+        assert o2.check is False

@@ -9,9 +9,7 @@ import jax.numpy as jnp
 
 from repro.core.engine import push_relabel, push_relabel_batched
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.push_relabel import (engine_phase, fused_engine_run,
-                                        fused_engine_run_batched,
-                                        push_relabel_phase)
+from repro.kernels.push_relabel import engine_phase, push_relabel_phase
 from repro.kernels.ref import (attention_ref, fused_iteration_ref,
                                push_relabel_iteration_ref)
 
@@ -58,7 +56,9 @@ def test_flash_attention_block_shape_independence(block_q, block_k):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
-PR_SHAPES = [(16, 4), (33, 5), (64, 8), (128, 3)]
+# the last shape is a region of the seg3d-26c.cold cell: 16^3 voxels under
+# 4x2x2 regions, 26 neighbours
+PR_SHAPES = [(16, 4), (33, 5), (64, 8), (128, 3), (256, 26)]
 
 
 @pytest.mark.parametrize("V,E", PR_SHAPES, ids=[str(s) for s in PR_SHAPES])
@@ -167,16 +167,17 @@ def _engine_kwargs(r, V, **over):
     return kw
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-@pytest.mark.parametrize("V,E", [(16, 4), (33, 5)],
-                         ids=["(16,4)", "(33,5)"])
-def test_fused_iteration_matches_ref_oracle(V, E, backend):
+ORACLE_SHAPES = [(16, 4), (33, 5), (64, 8), (256, 26)]
+
+
+@pytest.mark.parametrize("V,E", ORACLE_SHAPES,
+                         ids=[str(s) for s in ORACLE_SHAPES])
+def test_fused_iteration_matches_ref_oracle(V, E):
     """One fused engine iteration (push + intra apply + post-push relabel)
     is bit-equal to the kernels/ref.py fused-iteration oracle."""
     r = _random_region(V, E, seed=11 * V + E)
     es = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
-                      backend=backend, chunk_iters=1, max_iters=1,
-                      **_engine_kwargs(r, V))
+                      chunk_iters=1, max_iters=1, **_engine_kwargs(r, V))
     want = fused_iteration_ref(
         r["cf"], r["sink_cf"], r["excess"], r["lab"], r["nbr_local"],
         r["rev_slot"], r["intra"], r["emask"], r["vmask"], r["cross_lab"],
@@ -192,12 +193,11 @@ def test_fused_iteration_matches_ref_oracle(V, E, backend):
 
 
 @pytest.mark.parametrize("chunk", [1, 8])
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
 @pytest.mark.parametrize("V,E", PR_SHAPES, ids=[str(s) for s in PR_SHAPES])
-def test_fused_engine_matches_unfused(V, E, backend, chunk):
-    """The chunked fused driver (k iterations per launch) is bit-identical
-    to the unfused two-phase engine — every state field including iteration
-    counts — on both backends.  max_iters=16 is a whole number of chunks
+def test_fused_engine_matches_unfused(V, E, chunk):
+    """The chunked fused XLA driver (k iterations per trip) is
+    bit-identical to the unfused two-phase engine — every state field
+    including iteration counts.  max_iters=16 is a whole number of chunks
     at chunk=8 (the mid-chunk early exit is covered on a consistent network
     by test_fused_early_exit_convergence; random regions need an iteration
     cap because their labeling can be permanently invalid)."""
@@ -206,20 +206,18 @@ def test_fused_engine_matches_unfused(V, E, backend, chunk):
     a = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
                      backend="xla", **kw)
     b = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
-                     backend=backend, chunk_iters=chunk, **kw)
+                     chunk_iters=chunk, **kw)
     for name, x, y in zip(a._fields, a, b):
         if name == "launches":
             continue
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
                                       err_msg=f"field {name}")
     # launch accounting: unfused = 2 phase programs per iteration; fused
-    # pallas = exactly ceil(iters / chunk) kernel launches (the early exit
-    # never pays for an extra empty launch); fused xla = one traced
-    # compute body per iteration (2x fewer programs, not chunked)
+    # = one traced compute body per iteration (2x fewer programs, not
+    # chunked)
     iters = int(a.iters)
     assert int(a.launches) == 2 * iters
-    want = -(-iters // chunk) if backend == "pallas" else iters
-    assert int(b.launches) == want
+    assert int(b.launches) == iters
 
 
 def _consistent_region(n, m, seed):
@@ -243,12 +241,11 @@ def _consistent_region(n, m, seed):
     ), meta.num_vertices
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_fused_early_exit_convergence(backend):
+def test_fused_early_exit_convergence():
     """On a consistent network the fused driver runs to convergence with an
-    in-kernel early exit: identical final state and iteration count as the
-    unfused engine, and exactly ceil(iters/chunk) launches — the early exit
-    stops mid-chunk instead of padding to a chunk multiple."""
+    early exit: identical final state and iteration count as the unfused
+    engine, and one launch per iteration — the early exit stops mid-chunk
+    instead of padding to a chunk multiple."""
     r, n = _consistent_region(12, 24, seed=4)
     kw = dict(nbr_local=r["nbr_local"], rev_slot=r["rev_slot"],
               intra=r["intra"], emask=r["emask"], vmask=r["vmask"],
@@ -262,67 +259,22 @@ def test_fused_early_exit_convergence(backend):
     assert not bool(((a.excess > 0) & (a.lab < n) & r["vmask"]).any())
     for chunk in (8, iters + 5):     # mid-chunk exit / single-launch exit
         b = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
-                         backend=backend, chunk_iters=chunk, **kw)
+                         chunk_iters=chunk, **kw)
         for name, x, y in zip(a._fields, a, b):
             if name == "launches":
                 continue
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
                                           err_msg=f"field {name}")
-        want = -(-iters // chunk) if backend == "pallas" else iters
-        assert int(b.launches) == want
-
-
-def test_fused_pallas_vmem_fallback():
-    """A region over the VMEM budget must fall back to the blocked two-phase
-    path (launch accounting shows 2/iteration) and stay bit-exact."""
-    V, E = 33, 5
-    r = _random_region(V, E, seed=7 * V + E)
-    kw = _engine_kwargs(r, V, max_iters=16)
-    a = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
-                     backend="pallas", block_v=8, **kw)
-    b = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
-                     backend="pallas", block_v=8, chunk_iters=8,
-                     vmem_budget_bytes=1, **kw)
-    for name, x, y in zip(a._fields, a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                      err_msg=f"field {name}")
-    assert int(b.launches) == 2 * int(b.iters)
-
-
-def test_fused_engine_run_batched_matches_scalar_kernel():
-    """The grid-over-regions kernel (grid=(K,)) is bit-identical, region by
-    region, to K separate single-region fused kernel launches — including a
-    region whose per-region iteration budget is exhausted (limit 0)."""
-    rng = np.random.RandomState(0)
-    K, V, E = 3, 16, 4
-    mk = lambda *s, hi=10: jnp.asarray(rng.randint(0, hi, s), jnp.int32)
-    lab, cf = mk(K, V, hi=8), mk(K, V, E, hi=50)
-    sink, exc = mk(K, V, hi=20), mk(K, V, hi=40)
-    nbr, rev = mk(K, V, E, hi=V), mk(K, V, E, hi=E)
-    intra = jnp.asarray(rng.rand(K, V, E) < 0.8, jnp.int32)
-    pushable = jnp.asarray(rng.rand(K, V, E) < 0.9, jnp.int32)
-    clab = mk(K, V, E, hi=6)
-    vmask = jnp.asarray(rng.rand(K, V) < 0.95, jnp.int32)
-    d_inf, limit = 18, jnp.asarray([5, 0, 9], jnp.int32)
-    got = fused_engine_run_batched(lab, cf, sink, exc, nbr, rev, intra,
-                                   pushable, clab, vmask, d_inf, limit,
-                                   interpret=True)
-    for k in range(K):
-        want = fused_engine_run(lab[k], cf[k], sink[k], exc[k], nbr[k],
-                                rev[k], intra[k], pushable[k], clab[k],
-                                vmask[k], d_inf, limit[k], interpret=True)
-        for i, (x, y) in enumerate(zip([o[k] for o in got], want)):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                          err_msg=f"region {k} output {i}")
+        assert int(b.launches) == iters
 
 
 @pytest.mark.parametrize("backend,chunk",
-                         [("xla", None), ("xla", 8), ("pallas", 8)],
-                         ids=["xla-unfused", "xla-fused", "pallas-fused"])
+                         [("xla", None), ("xla", 8), ("pallas", None)],
+                         ids=["xla-unfused", "xla-fused", "pallas-blocked"])
 def test_push_relabel_batched_matches_vmapped_scalar(backend, chunk):
     """The batched engine entry point is bit-identical, per region, to
-    jax.vmap of the scalar engine on every state field; only the launch
-    accounting becomes global (1 per chunk trip on fused pallas)."""
+    jax.vmap of the scalar engine on every state field; the launch count
+    is the sum over regions."""
     rng = np.random.RandomState(5)
     K, V, E = 3, 16, 4
     regions = [_random_region(V, E, seed=100 + k) for k in range(K)]
@@ -334,7 +286,8 @@ def test_push_relabel_batched_matches_vmapped_scalar(backend, chunk):
               max_iters=16)
     got = push_relabel_batched(stack("cf"), stack("sink_cf"),
                                stack("excess"), stack("lab"),
-                               backend=backend, chunk_iters=chunk, **kw)
+                               backend=backend, block_v=8, chunk_iters=chunk,
+                               **kw)
     launches = 0
     for k, r in enumerate(regions):
         want = push_relabel(r["cf"], r["sink_cf"], r["excess"], r["lab"],
@@ -344,22 +297,14 @@ def test_push_relabel_batched_matches_vmapped_scalar(backend, chunk):
                             cross_pushable=r["cross_pushable"],
                             cross_lab=r["cross_lab"], d_inf=V + 2,
                             sink_open=True, max_iters=16, backend=backend,
-                            chunk_iters=chunk)
+                            block_v=8, chunk_iters=chunk)
         launches += int(want.launches)
         for name, x, y in zip(want._fields, got, want):
             if name == "launches":
                 continue
             np.testing.assert_array_equal(np.asarray(x[k]), np.asarray(y),
                                           err_msg=f"region {k} field {name}")
-    if backend == "pallas" and chunk:
-        # grid-over-regions: one launch per chunk trip covers every region,
-        # so the dispatch count is the busiest region's ceil(iters/chunk)
-        # instead of the sum over regions
-        want_trips = max(-(-int(it) // chunk)
-                         for it in np.asarray(got.iters))
-        assert int(got.launches) == want_trips
-    else:
-        assert int(got.launches) == launches
+    assert int(got.launches) == launches
 
 
 def test_push_relabel_phase_respects_blocking():

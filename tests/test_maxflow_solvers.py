@@ -123,32 +123,26 @@ def test_engine_backend_full_solve_parity(method):
 
 @pytest.mark.parametrize("method", ["ard", "prd"])
 def test_fused_engine_full_solve_parity(method):
-    """The region-resident fused engine (chunk_iters=k) must be a drop-in
-    replacement on full solves: oracle flow value, identical labels and
-    sweep counts vs the unfused path, on both backends, with the expected
-    kernel-launch reduction."""
+    """The fused XLA engine (chunk_iters=k) must be a drop-in replacement
+    on full solves: oracle flow value, identical labels and sweep counts
+    vs the unfused path, with the expected launch reduction."""
     p = random_sparse(16, 32, seed=5)
     want, _ = maxflow_oracle(p)
     base = solve_mincut(p, num_regions=3,
                         config=SweepConfig(method=method))
     assert base.flow_value == want
-    for backend, chunk in [("xla", 1), ("xla", 8), ("pallas", 8)]:
-        cfg = SweepConfig(method=method, engine_backend=backend,
-                          engine_chunk_iters=chunk)
+    for chunk in (1, 8):
+        cfg = SweepConfig(method=method, engine_chunk_iters=chunk)
         res = solve_mincut(p, num_regions=3, config=cfg)
-        assert res.flow_value == want, (backend, chunk)
+        assert res.flow_value == want, chunk
         np.testing.assert_array_equal(np.asarray(res.state.d),
                                       np.asarray(base.state.d),
-                                      err_msg=f"{backend} chunk={chunk}")
+                                      err_msg=f"chunk={chunk}")
         assert res.stats.sweeps == base.stats.sweeps
         assert res.stats.engine_iters == base.stats.engine_iters
-        # fused pallas: one kernel launch per chunk (vs 2 programs per
-        # iteration) -> >= 4x fewer dispatches at chunk=8; fused xla: one
-        # traced body per iteration -> exactly 2x fewer
-        if backend == "pallas" and chunk > 1:
-            assert res.stats.engine_launches * 4 <= base.stats.engine_launches
-        elif backend == "xla":
-            assert res.stats.engine_launches * 2 == base.stats.engine_launches
+        # one traced body per iteration vs 2 phase programs: exactly 2x
+        # fewer dispatches
+        assert res.stats.engine_launches * 2 == base.stats.engine_launches
 
 
 def test_trivial_cases():

@@ -338,13 +338,17 @@ def test_corrupt_labels_caught_by_certificate():
 # --------------------------------------------------------------------------
 
 def test_degrade_config_walks_the_ladder():
-    top = SweepConfig(engine_backend="pallas", engine_chunk_iters=64)
-    assert res.config_rung(top) == "pallas-fused"
-    mid = res.degrade_config(top)
-    assert res.config_rung(mid) == "xla-fused"
-    bot = res.degrade_config(mid)
-    assert res.config_rung(bot) == "xla-unfused"
-    assert res.degrade_config(bot) is None
+    # two chains, one rung each: pallas-unfused -> xla-unfused and
+    # xla-fused -> xla-unfused
+    for top, rung in ((SweepConfig(engine_backend="pallas"),
+                       "pallas-unfused"),
+                      (SweepConfig(engine_chunk_iters=64), "xla-fused")):
+        assert res.config_rung(top) == rung
+        bot = res.degrade_config(top)
+        assert res.config_rung(bot) == "xla-unfused"
+        assert res.degrade_config(bot) is None
+    assert set(res.KERNEL_LADDER) == {"pallas-unfused", "xla-fused",
+                                      "xla-unfused"}
     assert res.is_kernel_failure(res.VmemOverflowError("x"))
     assert res.is_kernel_failure(ValueError("RESOURCE_EXHAUSTED: vmem"))
     assert not res.is_kernel_failure(res.InjectedFault("x"))
@@ -364,7 +368,7 @@ def test_lowering_refusal_is_not_ladder_eligible(exc):
     """A kernel the compiler refuses, or an API that is gone, must raise:
     stepping down would hide that the requested route never ran."""
     assert not res.is_kernel_failure(exc)
-    cfg = SweepConfig(engine_backend="pallas", engine_chunk_iters=8)
+    cfg = SweepConfig(engine_backend="pallas")
 
     def run(c):
         raise exc
@@ -376,35 +380,32 @@ def test_lowering_refusal_is_not_ladder_eligible(exc):
 
 
 def test_fused_pallas_on_tpu_raises_up_front(monkeypatch):
-    """On a TPU (steered here: the engine asks ``jax.default_backend``) a
-    fused pallas solve raises the typed refusal instead of degrading."""
-    from repro.core import UnsupportedFeatureError
-
-    p, part = _instance()
-    h = Solver(SolverOptions(method="prd", engine_backend="pallas",
-                             engine_chunk_iters=8)).prepare(p, part)
-    jax.clear_caches()            # the engine resolves the backend at trace
+    """On a TPU (steered here through ``jax.default_backend``) as anywhere
+    else, pallas with engine_chunk_iters is refused when the options are
+    built — before a handle, a trace or a ladder exists — by an error
+    naming both knobs."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    try:
-        with pytest.raises(UnsupportedFeatureError, match="fused_pallas"):
-            h.solve()
-    finally:
-        monkeypatch.undo()
-        jax.clear_caches()
+    with pytest.raises(ValueError, match="engine_backend='pallas'.*"
+                                         "engine_chunk_iters"):
+        SolverOptions(method="prd", engine_backend="pallas",
+                      engine_chunk_iters=8)
 
 
-def test_vmem_overflow_degrades_one_rung():
+@pytest.mark.parametrize("engine,rung", [
+    (dict(engine_chunk_iters=64), "xla-fused"),
+    (dict(engine_backend="pallas"), "pallas-unfused"),
+], ids=["xla-fused", "pallas-unfused"])
+def test_vmem_overflow_degrades_one_rung(engine, rung):
     """A kernel-class failure mid-solve re-runs one rung down; the result
     is bit-correct and the degradation is recorded, never silent."""
     p, part = _instance()
     want = maxflow_oracle(p)[0]
-    h = Solver(SolverOptions(method="prd", engine_chunk_iters=64)).prepare(
-        p, part)
+    h = Solver(SolverOptions(method="prd", **engine)).prepare(p, part)
     with fault_injection(FaultPlan("vmem_overflow", at_sweep=1)):
         got = h.solve()
     assert got.flow_value == want and got.converged
     assert len(got.stats.degraded) == 1
-    assert got.stats.degraded[0].startswith("xla-fused -> xla-unfused")
+    assert got.stats.degraded[0].startswith(f"{rung} -> xla-unfused")
 
 
 def test_ladder_bottoms_out():

@@ -61,7 +61,7 @@ def test_service_mixed_stream_matches_oracle():
     assert svc.healthy() and svc.ready()
     rep = svc.report()
     assert rep["completed"] == len(probs) and rep["healthy"]
-    assert set(rep["breaker"]) == {"pallas-fused", "xla-fused",
+    assert set(rep["breaker"]) == {"pallas-unfused", "xla-fused",
                                    "xla-unfused"}
 
 
@@ -275,8 +275,7 @@ def test_lru_eviction_and_warm_resume(tmp_path):
 # circuit breaker over the degradation ladder
 # --------------------------------------------------------------------------
 
-PALLAS_OPTS = SolverOptions(num_regions=4, engine_backend="pallas",
-                            engine_chunk_iters=8)
+PALLAS_OPTS = SolverOptions(num_regions=4, engine_backend="pallas")
 
 
 def test_breaker_trip_cooldown_recovery():
@@ -300,7 +299,7 @@ def test_breaker_trip_cooldown_recovery():
     assert svc.stats.faults == 1
     assert svc.stats.degradations == 1
     assert svc.stats.breaker_trips == 1
-    assert svc.board["pallas-fused"].state == "open"
+    assert svc.board["pallas-unfused"].state == "open"
 
     # while open: chunks enter one rung down, skipping the broken rung
     p2 = _grid(seed=1)
@@ -312,13 +311,13 @@ def test_breaker_trip_cooldown_recovery():
 
     # cooldown elapses: half-open lets one probe through; success closes
     clk.advance(31.0)
-    assert svc.board["pallas-fused"].state == "half-open"
+    assert svc.board["pallas-unfused"].state == "half-open"
     p3 = _grid(seed=2)
     t3 = svc.submit(SolveRequest(problem=p3))
     svc.run_until_idle()
     assert t3.outcome().flow_value == maxflow_oracle(p3)[0]
-    assert svc.board["pallas-fused"].state == "closed"
-    assert svc.report()["breaker"]["pallas-fused"] == "closed"
+    assert svc.board["pallas-unfused"].state == "closed"
+    assert svc.report()["breaker"]["pallas-unfused"] == "closed"
 
 
 # --------------------------------------------------------------------------

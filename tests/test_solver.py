@@ -7,6 +7,7 @@ their own suite (tests/test_warmstart.py), legacy-shim equivalence its own
 """
 
 import dataclasses
+import sys
 
 import numpy as np
 import pytest
@@ -26,7 +27,7 @@ def _instance(g=10, seed=0):
 
 def test_options_absorb_sweep_config():
     cfg = SweepConfig(method="prd", engine_backend="pallas",
-                      engine_chunk_iters=4, device_resident=True,
+                      engine_max_iters=4, device_resident=True,
                       host_sync_every=3)
     opts = SolverOptions.from_sweep_config(cfg, num_regions=9, check=False)
     assert opts.sweep_config() == cfg
@@ -43,6 +44,31 @@ def test_options_validation():
         SolverOptions(warm_labels="sometimes")
     with pytest.raises(AssertionError):
         SolverOptions(exchange="psum")
+
+
+@pytest.mark.parametrize("front_end",
+                         ["SweepConfig", "SolverOptions", "maxflow_solve"])
+def test_pallas_with_chunk_iters_refused(front_end, monkeypatch, capsys):
+    """The fused chunked engine is XLA only: every front end refuses the
+    blocked Pallas kernel with engine_chunk_iters, naming both knobs."""
+    if front_end == "maxflow_solve":
+        from repro.launch import maxflow_solve
+
+        monkeypatch.setattr(sys, "argv", [
+            "maxflow_solve", "--engine-backend", "pallas",
+            "--engine-chunk-iters", "8"])
+        with pytest.raises(SystemExit) as ei:
+            maxflow_solve.main()
+        assert ei.value.code == 2               # argparse's usage error
+        said = capsys.readouterr().err
+    else:
+        options = {"SweepConfig": SweepConfig,
+                   "SolverOptions": SolverOptions}[front_end]
+        with pytest.raises(ValueError) as ei:
+            options(engine_backend="pallas", engine_chunk_iters=8)
+        said = str(ei.value)
+    assert "engine_backend='pallas'" in said
+    assert "engine_chunk_iters" in said
     with pytest.raises(AssertionError):
         SolverOptions(method="bfs")
 

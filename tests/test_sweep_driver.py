@@ -74,21 +74,6 @@ def test_stats_ring_overflow_keeps_tail():
         host.stats.active_curve[sweeps - ring:sweeps] + [0]
 
 
-def test_prd_pallas_single_launch_per_sweep():
-    """The acceptance headline: with the grid-over-regions kernel and a
-    chunk larger than any discharge, a device-resident PRD solve dispatches
-    exactly ONE kernel launch per parallel sweep (vs K per-region launch
-    chains) and syncs to the host exactly once."""
-    p, part = _instance()
-    want, _ = maxflow_oracle(p)
-    cfg = SweepConfig(method="prd", engine_backend="pallas",
-                      engine_chunk_iters=1 << 20, device_resident=True)
-    res = solve_mincut(p, part=part, config=cfg)
-    assert res.flow_value == want
-    assert res.stats.engine_launches == res.stats.sweeps
-    assert res.stats.host_syncs == 1
-
-
 def test_device_resident_converged_at_entry():
     """A problem with no active vertex solves in zero sweeps and one sync,
     with the same degenerate curves as the host loop."""

@@ -1,5 +1,5 @@
 """AOT compiles for a described TPU v5e topology: what the chip's compiler
-accepts and refuses, checked without a chip.
+accepts, checked without a chip.
 
 The topology is described inside a module fixture (only the worker that
 runs this file loads the TPU compiler), and every test compiles in this
@@ -100,20 +100,6 @@ def test_blocked_kernel_compiles_padded_rows(one_chip):
             s((V, E), jnp.bool_), s((V, E), jnp.bool_), s((V, E)), s(()))
     fn = lambda *a: pr.push_relabel_phase(*a, interpret=False, mode="both")
     assert _has_kernel(jax.jit(fn).lower(*args).compile())
-
-
-def test_fused_kernel_refused_by_mosaic(one_chip):
-    """Mosaic refuses the fused body; its first refusal is the in-kernel
-    ``lab[nbr]`` gather.  (The engine therefore refuses the fused pallas
-    route up front on a TPU; should this lowering ever pass, that refusal
-    and this test are out of date.)"""
-    s = lambda *shape: _sds(one_chip, shape, jnp.int32)
-    K, V = 16, 4096
-    args = (s(K, V), s(K, V, E), s(K, V), s(K, V), s(K, V, E), s(K, V, E),
-            s(K, V, E), s(K, V, E), s(K, V, E), s(K, V), s(), s(K))
-    fn = lambda *a: pr.fused_engine_run_batched(*a, interpret=False)
-    with pytest.raises(Exception, match="Only 2D gather is supported"):
-        jax.jit(fn).lower(*args).compile()
 
 
 def _grid_state(n, sharding):

@@ -28,6 +28,9 @@ VOLUMES = {
 ROUTES = {
     "host": dict(),
     "device": dict(device_resident=True),
+    # the two engine routes besides the default, on the host loop
+    "xla-fused": dict(engine_chunk_iters=8),
+    "pallas-blocked": dict(engine_backend="pallas"),
 }
 
 
@@ -123,6 +126,22 @@ def test_solver_cuts_volume_to_oracle(volume, route, method):
     p_, part, k = _volume(volume)
     meta = Solver(SolverOptions(num_regions=k)).prepare(p_, part).meta
     assert_sweep_bound(meta, res.stats, ard=method == "ard", where=volume)
+
+
+@pytest.mark.parametrize("method", ["ard", "prd"])
+@pytest.mark.parametrize("route", ["xla-fused", "pallas-blocked"])
+def test_engine_routes_cut_26c_volume_to_oracle(route, method):
+    """E = 26 on the fused XLA engine and the blocked Pallas kernel: the
+    oracle's flow and cut, and the default route's sweeps and engine
+    iterations to the count."""
+    res, p = _solve("26c-6x6x6", method, route)
+    base, _ = _solve("26c-6x6x6", method, "host")
+    want, source = maxflow_oracle(p)
+    assert res.flow_value == want
+    np.testing.assert_array_equal(res.source_side, source)
+    assert (res.stats.sweeps, res.stats.engine_iters,
+            res.stats.ard_stages) == \
+        (base.stats.sweeps, base.stats.engine_iters, base.stats.ard_stages)
 
 
 @pytest.mark.parametrize("volume", list(VOLUMES))

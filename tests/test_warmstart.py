@@ -6,7 +6,8 @@ perturbed problem — the Kohli-Torr reparameterization of
 ``graph.apply_update`` plus the ``warm_labels`` policy are pure
 performance devices.  Checked across perturbation classes
 (increase-only / decrease-only / mixed; p in {1%, 10%}) on 16^2/24^2
-grids, across ard/prd x xla/pallas x host-loop/device-resident drivers,
+grids, across ard/prd x xla/xla-fused/pallas x host-loop/device-resident
+drivers,
 and on the 64^2 interactive-segmentation instance where the warm solve
 must also use strictly fewer sweeps than the cold one.  The preflow and
 label invariants of ``test_discharge_invariants.py`` are asserted
@@ -73,12 +74,16 @@ def test_warm_resolve_matches_cold(g, kind, p):
 DRIVER_MATRIX = [
     ("ard", "xla", None, False),
     ("ard", "xla", None, True),
-    ("ard", "pallas", 8, False),
-    ("ard", "pallas", 8, True),
+    ("ard", "xla", 8, False),
+    ("ard", "xla", 8, True),
+    ("ard", "pallas", None, False),
+    ("ard", "pallas", None, True),
     ("prd", "xla", None, False),
     ("prd", "xla", None, True),
-    ("prd", "pallas", 8, False),
-    ("prd", "pallas", 8, True),
+    ("prd", "xla", 8, False),
+    ("prd", "xla", 8, True),
+    ("prd", "pallas", None, False),
+    ("prd", "pallas", None, True),
 ]
 DRIVER_IDS = [f"{m}-{b}{'-fused' if c else ''}-{'dr' if d else 'host'}"
               for m, b, c, d in DRIVER_MATRIX]
